@@ -60,6 +60,7 @@ re-checks it at apply time (defense in depth)."""
 from __future__ import annotations
 
 import json
+import logging
 import re
 import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -71,6 +72,8 @@ from ..control.events import (
     MetadataControlEvent,
     OperationControlEvent,
 )
+
+_LOG = logging.getLogger(__name__)
 
 
 def _json_safe(obj):
@@ -507,13 +510,19 @@ class QueryControlService:
         if self.admission is None:
             return None, None
         from ..control.plane import ControlRejected
+        from ..query.lexer import SiddhiQLError
 
         try:
             return self.admission(cql, plan_id), None
         except ControlRejected as e:
             rules, findings = e.rules, e.findings
-        except Exception as e:  # noqa: BLE001 — unparsable CQL etc.
+        except SiddhiQLError as e:
             rules, findings = ["CQL000"], [f"{type(e).__name__}: {e}"]
+        except Exception as e:  # noqa: BLE001 — recorded, never hidden
+            # a compiler or device error is not a bad query: its own
+            # rule id (as Job._compile_admitted), traceback at ERROR
+            _LOG.exception("admission gate: engine error on %s", plan_id)
+            rules, findings = ["ENG000"], [f"{type(e).__name__}: {e}"]
         job = self._live_job()
         if job is not None:
             job._record_rejection(

@@ -55,8 +55,8 @@ class EngineConfig:
     # wire predicate pushdown: host-evaluable predicates (single-chain /
     # single-select plans) are computed on the ingest host with numpy and
     # ship as ONE BIT per event, dropping their raw columns off the wire
-    # — on a tunneled device the host->device wire is the throughput
-    # ceiling. Host predicates see f64 where the device sees f32
+    # — fewer bytes over the host->device link per event. Host
+    # predicates see f64 where the device sees f32
     # (strictly closer to the reference's double semantics). Opt-in like
     # lazy_projection: a pushed plan keeps its own runtime (it cannot
     # fold into a recompile-free dynamic chain group, whose tape carries

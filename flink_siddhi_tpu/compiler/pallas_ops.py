@@ -1,67 +1,45 @@
-"""Pallas TPU kernels for the engine's hot scan primitives.
+"""The engine's one Pallas TPU kernel: fused reverse cummin.
 
-Three kernel families live here:
+The chain matcher's "next match at/after position p" indexes are
+reverse cumulative minimums over the event axis, one per pattern
+element (nfa.py:_chain_core). XLA compiles each as its own pass over
+HBM; up to 8 channels are fused into ONE blocked Pallas pass: the grid
+walks the event axis right-to-left, each step does a log-width
+shift-min sweep over its (8, 1024) tile in VMEM and threads the running
+minimum through a VMEM carry.
 
-* **reverse cummin** — the chain matcher's "next match at/after
-  position p" indexes are reverse cumulative minimums over the event
-  axis, one per pattern element (nfa.py:_chain_core). XLA compiles
-  each as its own pass over HBM; at micro-batch sizes per-kernel
-  launch overhead dominates, so up to 8 channels are fused into ONE
-  blocked Pallas pass: the grid walks the event axis right-to-left,
-  each step does a log-width shift-min sweep over its (8, 1024) tile
-  in VMEM and threads the running minimum through a VMEM carry.
-* **chain advance** — the slot-NFA transition inner loop
-  (nfa.py:_chain_core's per-step advance over K positive elements,
-  absence guards, and the `within` expiry). XLA lowers it as K-1
-  separate gather+select passes over the whole candidate axis; the
-  kernel fuses all steps into one blocked pass with the next-match
-  table resident in VMEM, emitting the per-step match-position matrix
-  the caller needs for capture gathers.
-* **unique window fold** — the per-event sequential slot-table update
-  of ``#window.unique`` (scan_windows.py). The lax.scan form carries
-  the whole buffer through HBM every event; the kernel walks the
-  event axis in blocks with the slot table held in VMEM, folding
-  events and computing per-event aggregates in one pass.
+The XLA form (``lax.cummin`` per row) is reached only where Pallas
+cannot apply BY CONSTRUCTION, and ``mode()`` says which:
 
-Every kernel falls back transparently to its XLA form when Pallas is
-unavailable (non-TPU backend, odd shapes, vmapped/stacked callers) —
-set ``FST_NO_PALLAS=1`` to force the fallback. ``warmup()`` probes
-each kernel against a numpy reference before any traced caller may
-use it; a probe failure disables that kernel only (the others stay
-usable). ``FST_PALLAS_INTERPRET=1`` runs the kernels under the Pallas
-interpreter on any backend — the CPU-lane equivalence tests' mode.
+* a non-TPU backend (``FST_PALLAS_INTERPRET=1`` runs the kernel under
+  the Pallas interpreter on any backend — the CPU-lane equivalence
+  tests' mode);
+* ``FST_NO_PALLAS=1`` (the operator switch);
+* shapes the kernel never claims (more than 8 channels, an event axis
+  that is not a multiple of 1024).
 
-Honest boundary: the chain-advance and unique-fold kernels build one
-``pallas_call`` per pattern/window SHAPE, lazily at trace time, and
-``warmup()`` probes a representative member of each family — so a
-Mosaic lowering failure on a shape the probe family does not cover
-surfaces at jit-compile time in the caller rather than falling back
-(the same boundary ``warmup_shard`` documents for the shard_map
-configuration). ``FST_NO_PALLAS=1`` is the operator escape hatch; the
-reverse-cummin kernel is immune (it only ever runs the exact probed
-executable).
+Everywhere else the kernel is used, and a kernel that fails to build,
+compile or match its oracle RAISES — from ``warmup()`` /
+``warmup_shard()`` for the probed executable, or from the caller's jit
+compile. It is never logged and skipped: an engine that quietly runs
+its XLA form on the chip is a different program from the one measured.
 """
 
 from __future__ import annotations
 
-import contextlib
-import logging
+import functools
 import os
-import threading
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from ..utils.jax_compat import shard_map as _shard_map_compat
-
-_LOG = logging.getLogger(__name__)
 
 _BLOCK = 1024  # lanes per grid step (bounded VMEM sweep)
 _SUB = 8  # sublane tile for int32
 _INF = 2 ** 30
 
 
+@functools.cache
 def _build():
     from jax.experimental import pallas as pl
 
@@ -93,8 +71,6 @@ def _build():
         o_ref[...] = out
         carry_ref[..., :1] = out[..., :1]
 
-    interpret = bool(os.environ.get("FST_PALLAS_INTERPRET"))
-
     def run(x2d):
         n_blocks = x2d.shape[1] // _BLOCK
         out, _carry = pl.pallas_call(
@@ -117,121 +93,99 @@ def _build():
                 jax.ShapeDtypeStruct(x2d.shape, jnp.int32),
                 jax.ShapeDtypeStruct((_SUB, 128), jnp.int32),
             ],
-            interpret=interpret,
+            interpret=bool(os.environ.get("FST_PALLAS_INTERPRET")),
         )(x2d)
         return out
 
     return run
 
 
-_RUN = None
-_FAILED = False
-_TLS = threading.local()  # per-thread force-fallback flag
+# (mode(), under shard_map) pairs whose probe matched its oracle
+_PROBED: set = set()
 
 
-@contextlib.contextmanager
-def force_fallback():
-    """Disable the Pallas path while tracing runs inside this context
-    (e.g. under shard_map, a lowering configuration warmup() never
-    probed). Trace-time only: wrap the function BODY that builds the
-    jaxpr, not the jit call site."""
-    prev = getattr(_TLS, "disabled", False)
-    _TLS.disabled = True
-    try:
-        yield
-    finally:
-        _TLS.disabled = prev
-
-
-def warmup() -> bool:
-    """Build + probe every kernel eagerly. MUST be called from host
-    code (never inside a jit trace): lowering/Mosaic failures and
-    numerical mismatches surface here, so traced callers can rely on a
-    kernel that is known-good — or silently use the XLA fallback. Each
-    kernel family probes independently (a chain-advance failure does
-    not disable the reverse cummin). Returns whether the baseline
-    (reverse-cummin) Pallas path is active; ``chain_kernel_active()``
-    / ``fold_kernel_active()`` report the other two."""
-    global _RUN, _FAILED
-    if not available():
-        # NOT latched: availability is environmental (backend, FST_NO_PALLAS)
-        # and may change — e.g. a CPU-pinned dryrun in a TPU process must not
-        # permanently disable the kernel for later TPU plans
-        return False
-    if _RUN is None and not _FAILED:
-        try:
-            run = _build()
-            # probe spans FOUR grid blocks with random data so both the
-            # in-block sweep and the cross-block carry are validated
-            rng = np.random.default_rng(0)
-            probe = rng.integers(
-                0, 2 ** 29, (_SUB, 4 * _BLOCK)
-            ).astype(np.int32)
-            out = np.asarray(jax.jit(run)(jnp.asarray(probe)))
-            ref = np.minimum.accumulate(
-                probe[:, ::-1], axis=1
-            )[:, ::-1]
-            if not np.array_equal(out, ref):
-                raise RuntimeError("probe mismatch")
-            _RUN = run
-        except Exception as e:  # pallas unavailable on this backend
-            _LOG.info("pallas reverse-cummin unavailable: %s", e)
-            _FAILED = True
-    _warmup_chain()
-    _warmup_fold()
-    return _RUN is not None
+def mode() -> str:
+    """How reverse cummins run in this process: ``compiled`` (Mosaic, on
+    the TPU), ``interpret`` (the Pallas interpreter), or ``xla (...)``
+    naming why Pallas cannot apply. Environmental, so NOT latched (the
+    environment may change between plans)."""
+    if os.environ.get("FST_NO_PALLAS"):
+        return "xla (FST_NO_PALLAS)"
+    if os.environ.get("FST_PALLAS_INTERPRET"):
+        return "interpret"  # any backend (tests)
+    backend = jax.default_backend()
+    return "compiled" if backend == "tpu" else f"xla ({backend} backend)"
 
 
 def available() -> bool:
-    if os.environ.get("FST_NO_PALLAS"):
+    return not mode().startswith("xla")
+
+
+def _probe_once(sharded: bool, make_fn) -> None:
+    """Run ``make_fn()`` on random data once per (mode, lowering) and
+    raise unless it equals the numpy oracle. The probe spans FOUR grid
+    blocks so both the in-block sweep and the cross-block carry are
+    validated."""
+    key = (mode(), sharded)
+    if key in _PROBED:
+        return
+    probe = np.random.default_rng(int(sharded)).integers(
+        0, 2 ** 29, (_SUB, 4 * _BLOCK)
+    ).astype(np.int32)
+    out = np.asarray(make_fn()(jnp.asarray(probe)))
+    if not np.array_equal(
+        out, np.minimum.accumulate(probe[:, ::-1], axis=1)[:, ::-1]
+    ):
+        raise RuntimeError(
+            "pallas reverse-cummin probe does not match its oracle"
+            + (" under shard_map" if sharded else "")
+        )
+    _PROBED.add(key)
+
+
+def warmup() -> bool:
+    """Probe the kernel eagerly. MUST be called from host code (never
+    inside a jit trace: pallas has no op-by-op eval rule). Returns
+    False where Pallas cannot apply by construction (``available()``);
+    otherwise the kernel builds, compiles and equals its numpy oracle,
+    or this raises."""
+    if not available():
         return False
-    if os.environ.get("FST_PALLAS_INTERPRET"):
-        return True  # interpreter mode: any backend (tests)
-    return jax.default_backend() == "tpu"
-
-
-_SHARD_OK = None
+    _probe_once(False, lambda: jax.jit(_build()))
+    return True
 
 
 def warmup_shard() -> bool:
-    """Probe the kernel under a shard_map lowering (a configuration the
-    plain warmup() never exercises). MUST be called from host code. A
-    passing probe lets the sharded step keep the fused kernel instead of
-    blanket-falling back to XLA cummins."""
-    global _SHARD_OK
-    if _SHARD_OK is None:
-        if not warmup():
-            _SHARD_OK = False
-            return False
-        try:
-            from jax.sharding import PartitionSpec as P
+    """``warmup()`` plus a probe of the kernel under a shard_map
+    lowering (a configuration the plain probe never exercises). MUST be
+    called from host code. Same contract: False where Pallas cannot
+    apply, True once the probe matched, raises otherwise."""
+    if not warmup():
+        return False
 
-            mesh = jax.make_mesh((1,), ("@pallas_probe",))
-            rng = np.random.default_rng(1)
-            probe = rng.integers(
-                0, 2 ** 29, (1, _SUB, 4 * _BLOCK)
-            ).astype(np.int32)
-            # check_vma=False matches the engine's sharded step: the
-            # kernel's out_shape carries no vma annotation, and the
-            # per-shard body uses no collectives the checker would guard
-            f = jax.jit(
-                _shard_map_compat(
-                    lambda x: _RUN(x[0])[None],
-                    mesh=mesh,
-                    in_specs=P("@pallas_probe"),
-                    out_specs=P("@pallas_probe"),
-                    check_vma=False,
-                )
+    def sharded():
+        from jax.sharding import AxisType, PartitionSpec as P
+
+        mesh = jax.make_mesh(
+            (1,), ("@pallas_probe",), axis_types=(AxisType.Auto,)
+        )
+        run = _build()
+        # check_vma=False matches the engine's sharded step: the
+        # kernel's out_shape carries no vma annotation, and the
+        # per-shard body uses no collectives the checker would guard
+        f = jax.jit(
+            jax.shard_map(
+                lambda x: run(x[0])[None],
+                mesh=mesh,
+                in_specs=P("@pallas_probe"),
+                out_specs=P("@pallas_probe"),
+                check_vma=False,
             )
-            out = np.asarray(f(jnp.asarray(probe)))[0]
-            ref = np.minimum.accumulate(
-                probe[0, :, ::-1], axis=1
-            )[:, ::-1]
-            _SHARD_OK = bool(np.array_equal(out, ref))
-        except Exception as e:
-            _LOG.info("pallas under shard_map unavailable: %s", e)
-            _SHARD_OK = False
-    return _SHARD_OK
+        )
+        return lambda probe: f(probe[None])[0]
+
+    _probe_once(True, sharded)
+    return True
 
 
 def multi_reverse_cummin(rows):
@@ -240,491 +194,16 @@ def multi_reverse_cummin(rows):
     ``rows``: list of (E,) int32 arrays with values < 2**30 (the kernel's
     carry/padding sentinel — larger values would clamp to it; the chain
     matcher's inputs are tape positions <= E, far below). Returns the
-    same. Falls back to per-row ``lax.cummin`` whenever the kernel can't
-    apply."""
+    same. Per-row ``lax.cummin`` where Pallas cannot apply by
+    construction (module docstring); a kernel that does not lower
+    fails the caller's jit compile."""
     E = rows[0].shape[0]
-    # only a warmup()-probed kernel is used: building/probing inside a
-    # jit trace is impossible (pallas has no op-by-op eval rule)
-    usable = (
-        _RUN is not None
-        and not getattr(_TLS, "disabled", False)
-        and available()
-        and 0 < len(rows) <= _SUB
-        and E % _BLOCK == 0
-    )
-    if usable:
+    if available() and 0 < len(rows) <= _SUB and E % _BLOCK == 0:
         pad = [jnp.full(E, _INF, jnp.int32)] * (_SUB - len(rows))
         x = jnp.stack([r.astype(jnp.int32) for r in rows] + pad)
-        out = _RUN(x)  # ONE fused pass for all channels
+        out = _build()(x)  # ONE fused pass for all channels
         return [out[i] for i in range(len(rows))]
     return [
         jax.lax.cummin(r.astype(jnp.int32), axis=0, reverse=True)
         for r in rows
     ]
-
-
-# --------------------------------------------------------------------------
-# Chain advance: the slot-NFA transition inner loop as ONE fused pass
-# --------------------------------------------------------------------------
-# nfa._chain_core advances every candidate partial through the pattern's
-# K-1 remaining positive elements; each step is a gather into a
-# next-match table plus guard/within selects over the V-sized candidate
-# axis — K-1 separate HBM passes under XLA. The kernel holds the whole
-# next-match table (R rows x E+1 positions) in VMEM and runs all steps
-# over one candidate block per grid step, writing the per-step match
-# positions (jmat) so the caller can do capture gathers in XLA.
-
-_CHAIN_RUNS: dict = {}
-_CHAIN_OK = None  # None = unprobed; warmup() sets True/False
-# next-match table VMEM budget: R rows x padded width x 4B must leave
-# room for the candidate blocks and outputs in ~16MB of VMEM
-_CHAIN_VMEM_BUDGET = 8 << 20
-
-
-def _chain_key(positive, guards, has_within, E, Ep, Vp):
-    K = len(positive)
-    # rows: positives 1..K-1 first, then each step's guards in order —
-    # STATIC per pattern shape, baked into the kernel
-    guard_rows = []
-    r = K - 1
-    for k in range(1, K):
-        rows_k = tuple(range(r, r + len(guards[k])))
-        guard_rows.append(rows_k)
-        r += len(guards[k])
-    return (K, tuple(guard_rows), bool(has_within), E, Ep, Vp, r)
-
-
-def _build_chain(key):
-    from jax.experimental import pallas as pl
-
-    K, guard_rows, has_within, E, Ep, Vp, R = key
-    Km1 = K - 1
-    n_blocks = Vp // _BLOCK
-    interpret = bool(os.environ.get("FST_PALLAS_INTERPRET"))
-
-    def kernel(wv_ref, nxt_ref, tsp_ref, act_ref, step_ref, pos_ref,
-               start_ref, oact_ref, ostep_ref, opos_ref, jmat_ref):
-        act = act_ref[0, :]
-        step = step_ref[0, :]
-        pos = pos_ref[0, :]
-        start = start_ref[0, :]
-        wv = wv_ref[0, 0]
-        nxt = nxt_ref[...]
-        tsp = tsp_ref[0, :]
-        for k in range(1, K):
-            # mirror nfa._chain_core's advance EXACTLY (the fallback is
-            # the oracle): candidates at step k gather their next match,
-            # absence guards kill on an earlier-or-equal guard match,
-            # `within` expires late completions
-            at_k = (act == 1) & (step == k)
-            idx = jnp.clip(pos, 0, E)
-            j = jnp.take(nxt[k - 1, :], idx)
-            found = at_k & (j < E)
-            for g in guard_rows[k - 1]:
-                jg = jnp.take(nxt[g, :], idx)
-                violated = at_k & (jg <= j) & (jg < E)
-                act = jnp.where(violated, 0, act)
-                found = found & ~violated
-            ts_j = jnp.take(tsp, j)
-            if has_within:
-                ok = (ts_j - start) <= wv
-                dead = found & ~ok
-                found = found & ok
-                act = jnp.where(dead, 0, act)
-            jmat_ref[k - 1, :] = jnp.where(found, j, E)
-            step = jnp.where(found, k + 1, step)
-            pos = jnp.where(found, j + 1, pos)
-        oact_ref[0, :] = act
-        ostep_ref[0, :] = step
-        opos_ref[0, :] = pos
-
-    def run(wv, nxt, tsp, act, step, pos, start):
-        return pl.pallas_call(
-            kernel,
-            grid=(n_blocks,),
-            in_specs=[
-                pl.BlockSpec((1, 1), lambda i: (0, 0)),
-                pl.BlockSpec((R, Ep), lambda i: (0, 0)),
-                pl.BlockSpec((1, Ep), lambda i: (0, 0)),
-                pl.BlockSpec((1, _BLOCK), lambda i: (0, i)),
-                pl.BlockSpec((1, _BLOCK), lambda i: (0, i)),
-                pl.BlockSpec((1, _BLOCK), lambda i: (0, i)),
-                pl.BlockSpec((1, _BLOCK), lambda i: (0, i)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, _BLOCK), lambda i: (0, i)),
-                pl.BlockSpec((1, _BLOCK), lambda i: (0, i)),
-                pl.BlockSpec((1, _BLOCK), lambda i: (0, i)),
-                pl.BlockSpec((Km1, _BLOCK), lambda i: (0, i)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((1, Vp), jnp.int32),
-                jax.ShapeDtypeStruct((1, Vp), jnp.int32),
-                jax.ShapeDtypeStruct((1, Vp), jnp.int32),
-                jax.ShapeDtypeStruct((Km1, Vp), jnp.int32),
-            ],
-            interpret=interpret,
-        )(wv, nxt, tsp, act, step, pos, start)
-
-    return run
-
-
-def chain_kernel_active() -> bool:
-    return bool(_CHAIN_OK) and available() and not getattr(
-        _TLS, "disabled", False
-    )
-
-
-def chain_advance(positive, guards, has_within, nxt, ts_pad,
-                  active, step, pos, start, within):
-    """Fused slot-NFA advance for one micro-batch. ``nxt`` maps element
-    index -> int32[E+1] next-match-at/after table (position E = "no
-    match"); ``active``/``step``/``pos``/``start`` are the V-sized
-    candidate rows. Returns ``(active bool[V], step, pos,
-    jmat int32[K-1, V])`` where ``jmat[k-1]`` is the tape position each
-    candidate matched positive step k at this batch (E = did not
-    advance) — the caller replays capture/emit-ts gathers off it in
-    XLA. Returns None whenever the kernel cannot apply (unprobed,
-    disabled, VMEM-oversized table); callers then run the unfused XLA
-    advance loop, which is also the kernel's correctness oracle."""
-    if not chain_kernel_active():
-        return None
-    K = len(positive)
-    if K < 2:
-        return None
-    V = int(active.shape[0])
-    E = int(ts_pad.shape[0]) - 1
-    rows = list(positive[1:]) + [
-        g for k in range(1, K) for g in guards[k]
-    ]
-    R = len(rows)
-    Ep = -((E + 1) // -128) * 128
-    if R * Ep * 4 > _CHAIN_VMEM_BUDGET:
-        return None
-    Vp = -(V // -_BLOCK) * _BLOCK
-    key = _chain_key(positive, guards, has_within, E, Ep, Vp)
-    run = _CHAIN_RUNS.get(key)
-    if run is None:
-        run = _CHAIN_RUNS[key] = _build_chain(key)
-
-    def padw(row, fill):
-        return jnp.concatenate(
-            [row, jnp.full(Ep - row.shape[0], fill, jnp.int32)]
-        ) if row.shape[0] < Ep else row
-
-    nxt_mat = jnp.stack([padw(nxt[e].astype(jnp.int32), E)
-                         for e in rows])
-    tsp = padw(ts_pad.astype(jnp.int32), 0)[None, :]
-
-    def padv(v):
-        v = v.astype(jnp.int32)
-        if V < Vp:
-            v = jnp.concatenate([v, jnp.zeros(Vp - V, jnp.int32)])
-        return v[None, :]
-
-    oact, ostep, opos, jmat = run(
-        jnp.asarray(within, jnp.int32).reshape(1, 1),
-        nxt_mat, tsp, padv(active), padv(step), padv(pos), padv(start),
-    )
-    return (
-        oact[0, :V].astype(bool),
-        ostep[0, :V],
-        opos[0, :V],
-        jmat[:, :V],
-    )
-
-
-def _ref_chain_advance(positive, guards, has_within, nxt, tsp,
-                       act, step, pos, start, wv):
-    """Numpy oracle for the probe: the literal nfa advance loop."""
-    K = len(positive)
-    E = len(tsp) - 1
-    act, step, pos = act.copy(), step.copy(), pos.copy()
-    jmat = np.full((K - 1, len(act)), E, np.int32)
-    for k in range(1, K):
-        at_k = act & (step == k)
-        j = nxt[positive[k]][np.clip(pos, 0, E)]
-        found = at_k & (j < E)
-        for g in guards[k]:
-            jg = nxt[g][np.clip(pos, 0, E)]
-            violated = at_k & (jg <= j) & (jg < E)
-            act = act & ~violated
-            found = found & ~violated
-        ts_j = tsp[j]
-        if has_within:
-            ok = (ts_j - start) <= wv
-            dead = found & ~ok
-            found = found & ok
-            act = act & ~dead
-        jmat[k - 1] = np.where(found, j, E)
-        step = np.where(found, k + 1, step)
-        pos = np.where(found, j + 1, pos)
-    return act, step, pos, jmat
-
-
-def _warmup_chain() -> bool:
-    """Probe the chain-advance kernel on a representative config (3
-    positive steps, one mid-chain guard, within) against the numpy
-    oracle. A pass admits the kernel FAMILY — per-pattern shapes build
-    lazily at trace time from the same primitive mix."""
-    global _CHAIN_OK
-    if _CHAIN_OK is not None:
-        return _CHAIN_OK
-    try:
-        rng = np.random.default_rng(3)
-        E, P = 2 * _BLOCK, 64
-        V = P + E
-        positive = (0, 1, 3)
-        guards = ((), (), (2,))
-        nxt = {}
-        for e in (1, 2, 3):
-            hits = np.sort(
-                rng.choice(E, size=E // 7, replace=False)
-            ).astype(np.int32)
-            row = np.full(E + 1, E, np.int32)
-            idx = np.full(E, E, np.int32)
-            idx[hits] = hits
-            row[:E] = np.minimum.accumulate(idx[::-1])[::-1]
-            nxt[e] = row
-        tsp = np.concatenate(
-            [np.sort(rng.integers(0, 1 << 20, E)).astype(np.int32),
-             np.zeros(1, np.int32)]
-        )
-        act = rng.random(V) < 0.5
-        step = rng.integers(1, 3, V).astype(np.int32)
-        pos = rng.integers(0, E + 1, V).astype(np.int32)
-        start = rng.integers(0, 1 << 20, V).astype(np.int32)
-        wv = np.int32(1 << 18)
-        ref = _ref_chain_advance(
-            positive, guards, True, nxt, tsp, act, step, pos, start, wv
-        )
-        _CHAIN_OK = True  # chain_advance() checks the flag; set to probe
-        try:
-            got = chain_advance(
-                positive, guards, True,
-                {e: jnp.asarray(v) for e, v in nxt.items()},
-                jnp.asarray(tsp), jnp.asarray(act),
-                jnp.asarray(step), jnp.asarray(pos),
-                jnp.asarray(start), wv,
-            )
-            if got is None:
-                raise RuntimeError("probe declined")
-            for g, r in zip(got, ref):
-                if not np.array_equal(np.asarray(g), r):
-                    raise RuntimeError("probe mismatch")
-        except Exception:
-            _CHAIN_OK = False
-            raise
-    except Exception as e:
-        _LOG.info("pallas chain-advance unavailable: %s", e)
-        _CHAIN_OK = False
-    return _CHAIN_OK
-
-
-# --------------------------------------------------------------------------
-# Unique-window fold: the per-event slot-table update in one blocked pass
-# --------------------------------------------------------------------------
-# scan_windows.ScanWindowArtifact (kind == 'unique') folds each event
-# into a C-slot latest-value table and recomputes the aggregates per
-# event — a lax.scan whose carry round-trips the whole table through
-# HBM every event. The kernel keeps the table in VMEM across a blocked
-# walk of the event axis (revisited-output carry, as the cummin kernel)
-# and emits the per-event aggregate rows in the same pass.
-
-_FOLD_RUNS: dict = {}
-_FOLD_OK = None
-_FOLD_MAX_C = 1 << 14  # slot table must stay VMEM-resident
-
-
-def _build_fold(key):
-    from jax.experimental import pallas as pl
-
-    slots, A, C, B, E = key
-    S = len(slots)
-    n_blocks = E // B
-    interpret = bool(os.environ.get("FST_PALLAS_INTERPRET"))
-
-    def kernel(mask_ref, code_ref, vals_ref, v0_ref, b0_ref,
-               out_ref, valid_ref, buf_ref):
-        i = pl.program_id(0)
-
-        @pl.when(i == 0)
-        def _init():  # adopt the carried-in state on the first block
-            valid_ref[...] = v0_ref[...]
-            buf_ref[...] = b0_ref[...]
-
-        mask = mask_ref[0, :]
-        code = code_ref[0, :]
-        vals = vals_ref[...]
-
-        def body(t, carry):
-            valid, buf, out = carry
-            active = mask[t] == 1
-            c = jnp.clip(code[t], 0, C - 1)
-            valid = jnp.where(active, valid.at[c].set(1), valid)
-            buf = jnp.where(active, buf.at[:, c].set(vals[:, t]), buf)
-            vm = valid == 1
-            cnt = jnp.sum(vm.astype(jnp.float32))
-            row = []
-            for kind, ai in slots:
-                if kind == "count":
-                    row.append(cnt)
-                elif kind in ("sum", "avg"):
-                    s = jnp.sum(jnp.where(vm, buf[ai], jnp.float32(0)))
-                    row.append(
-                        s if kind == "sum"
-                        else s / jnp.maximum(cnt, jnp.float32(1))
-                    )
-                elif kind == "min":
-                    row.append(
-                        jnp.min(jnp.where(vm, buf[ai], jnp.inf))
-                    )
-                else:  # max
-                    row.append(
-                        jnp.max(jnp.where(vm, buf[ai], -jnp.inf))
-                    )
-            out = out.at[:, t].set(jnp.stack(row))
-            return valid, buf, out
-
-        valid, buf, out = jax.lax.fori_loop(
-            0, B, body,
-            (valid_ref[0, :], buf_ref[...],
-             jnp.zeros((S, B), jnp.float32)),
-        )
-        out_ref[...] = out
-        valid_ref[0, :] = valid
-        buf_ref[...] = buf
-
-    def run(mask, code, vals, v0, b0):
-        return pl.pallas_call(
-            kernel,
-            grid=(n_blocks,),
-            in_specs=[
-                pl.BlockSpec((1, B), lambda i: (0, i)),
-                pl.BlockSpec((1, B), lambda i: (0, i)),
-                pl.BlockSpec((A, B), lambda i: (0, i)),
-                pl.BlockSpec((1, C), lambda i: (0, 0)),
-                pl.BlockSpec((A, C), lambda i: (0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((S, B), lambda i: (0, i)),
-                pl.BlockSpec((1, C), lambda i: (0, 0)),
-                pl.BlockSpec((A, C), lambda i: (0, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((S, E), jnp.float32),
-                jax.ShapeDtypeStruct((1, C), jnp.int32),
-                jax.ShapeDtypeStruct((A, C), jnp.float32),
-            ],
-            interpret=interpret,
-        )(mask, code, vals, v0, b0)
-
-    return run
-
-
-def fold_kernel_active() -> bool:
-    return bool(_FOLD_OK) and available() and not getattr(
-        _TLS, "disabled", False
-    )
-
-
-def unique_window_fold(mask, codes, arg_cols, valid0, bufs0, slots):
-    """Blocked #window.unique fold. ``mask``/``codes``: bool/int32[E];
-    ``arg_cols``: list of float32[E] slot-value columns; ``valid0``:
-    bool[C] table occupancy; ``bufs0``: list of float32[C] retained
-    columns; ``slots``: static ``(kind, arg_idx)`` per aggregate slot
-    (count/sum/avg/min/max). Returns ``(new_valid bool[C], new_bufs,
-    slot_rows float32[S, E])`` or None when the kernel cannot apply
-    (the lax.scan fold in scan_windows.py is the fallback AND the
-    oracle)."""
-    if not fold_kernel_active():
-        return None
-    E = int(mask.shape[0])
-    C = int(valid0.shape[0])
-    B = min(_BLOCK, E)
-    if E % B or C > _FOLD_MAX_C or not slots:
-        return None
-    A = max(len(arg_cols), 1)
-    key = (tuple(slots), A, C, B, E)
-    run = _FOLD_RUNS.get(key)
-    if run is None:
-        run = _FOLD_RUNS[key] = _build_fold(key)
-    vals = (
-        jnp.stack([c.astype(jnp.float32) for c in arg_cols])
-        if arg_cols
-        else jnp.zeros((1, E), jnp.float32)
-    )
-    b0 = (
-        jnp.stack([b.astype(jnp.float32) for b in bufs0])
-        if bufs0
-        else jnp.zeros((1, C), jnp.float32)
-    )
-    out, valid, buf = run(
-        mask.astype(jnp.int32)[None, :],
-        codes.astype(jnp.int32)[None, :],
-        vals,
-        valid0.astype(jnp.int32)[None, :],
-        b0,
-    )
-    new_bufs = [buf[j] for j in range(len(bufs0))]
-    return valid[0].astype(bool), new_bufs, out
-
-
-def _warmup_fold() -> bool:
-    """Probe the unique-fold kernel (two value columns, all five
-    aggregate kinds, three grid blocks) against a numpy oracle running
-    the literal per-event fold."""
-    global _FOLD_OK
-    if _FOLD_OK is not None:
-        return _FOLD_OK
-    try:
-        rng = np.random.default_rng(5)
-        E, C = 3 * _BLOCK, 128
-        mask = rng.random(E) < 0.7
-        codes = rng.integers(0, C, E).astype(np.int32)
-        a0 = rng.random(E).astype(np.float32) * 100
-        a1 = rng.random(E).astype(np.float32) * 10
-        slots = (("count", -1), ("sum", 0), ("avg", 0),
-                 ("min", 1), ("max", 1))
-        valid = np.zeros(C, bool)
-        bufs = [np.zeros(C, np.float32), np.zeros(C, np.float32)]
-        ref = np.zeros((len(slots), E), np.float32)
-        for t in range(E):
-            if mask[t]:
-                c = codes[t]
-                valid[c] = True
-                bufs[0][c] = a0[t]
-                bufs[1][c] = a1[t]
-            cnt = np.float32(valid.sum())
-            s = np.float32(np.where(valid, bufs[0], 0).sum())
-            ref[0, t] = cnt
-            ref[1, t] = s
-            ref[2, t] = s / max(cnt, np.float32(1))
-            ref[3, t] = np.where(valid, bufs[1], np.inf).min()
-            ref[4, t] = np.where(valid, bufs[1], -np.inf).max()
-        _FOLD_OK = True  # unique_window_fold checks the flag; probe
-        try:
-            got = unique_window_fold(
-                jnp.asarray(mask), jnp.asarray(codes),
-                [jnp.asarray(a0), jnp.asarray(a1)],
-                jnp.zeros(C, bool),
-                [jnp.zeros(C, jnp.float32), jnp.zeros(C, jnp.float32)],
-                slots,
-            )
-            if got is None:
-                raise RuntimeError("probe declined")
-            gv, gb, rows = got
-            if not np.array_equal(np.asarray(gv), valid):
-                raise RuntimeError("probe mismatch: valid")
-            for g, r in zip(gb, bufs):
-                if not np.allclose(np.asarray(g), r):
-                    raise RuntimeError("probe mismatch: buffer")
-            if not np.allclose(np.asarray(rows), ref, equal_nan=True):
-                raise RuntimeError("probe mismatch: aggregates")
-        except Exception:
-            _FOLD_OK = False
-            raise
-    except Exception as e:
-        _LOG.info("pallas unique-fold unavailable: %s", e)
-        _FOLD_OK = False
-    return _FOLD_OK

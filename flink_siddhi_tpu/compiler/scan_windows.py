@@ -165,47 +165,6 @@ class ScanWindowArtifact:
             )
         return out
 
-    def _fused_unique(self, state, mask, env, arg_cols):
-        """Pallas fast path for the unpartitioned unique fold. Returns
-        ``(new_buf, slot_rows)`` matching the lax.scan fold exactly, or
-        None when the kernel cannot apply (non-TPU backend, non-f32
-        slot values, unsupported aggregate) — gating mirrors
-        pallas_ops.available()/force_fallback()."""
-        from . import pallas_ops
-
-        if not pallas_ops.fold_kernel_active():
-            return None
-        if not all(
-            np.dtype(t.device_dtype) == np.float32
-            for t in self.arg_types
-        ):
-            return None
-        if not all(
-            a.kind in ("count", "sum", "avg", "min", "max")
-            for a in self.aggs
-        ):
-            return None
-        slots = tuple(
-            (a.kind, -1 if a.kind == "count" else a.arg_idx)
-            for a in self.aggs
-        )
-        bufs0 = [state[f"a{j}"] for j in range(len(self.arg_types))]
-        res = pallas_ops.unique_window_fold(
-            mask, env[self.code_key].astype(jnp.int32), arg_cols,
-            state["valid"], bufs0, slots,
-        )
-        if res is None:
-            return None
-        new_valid, new_bufs, rows = res
-        new_buf = {"valid": new_valid}
-        for j, b in enumerate(new_bufs):
-            new_buf[f"a{j}"] = b
-        slot_rows = {
-            a.slot: rows[s].astype(a.out_type.device_dtype)
-            for s, a in enumerate(self.aggs)
-        }
-        return new_buf, slot_rows
-
     # fst:hotpath device=state,tape
     def step(self, state: Dict, tape) -> Tuple[Dict, Tuple]:
         env: ColumnEnv = dict(tape.cols)
@@ -290,19 +249,8 @@ class ScanWindowArtifact:
                 valid = valid & (nb["pc"] == p)
             return nb, self._agg_rows(nb, valid, slice(None))
 
-        # the unpartitioned unique fold has a fused Pallas form: slot
-        # table resident in VMEM across a blocked walk of the event
-        # axis (pallas_ops.unique_window_fold). The lax.scan below
-        # remains the fallback AND the oracle (kernel-vs-fallback
-        # equivalence is probed at warmup and pinned by tests).
-        fused = None
-        if self.kind == "unique" and self.part_key is None:
-            fused = self._fused_unique(state, mask, env, arg_cols)
-        if fused is not None:
-            new_buf, slot_rows = fused
-        else:
-            body = body_sort if self.kind == "sort" else body_unique
-            new_buf, slot_rows = lax.scan(body, buf0, xs)
+        body = body_sort if self.kind == "sort" else body_unique
+        new_buf, slot_rows = lax.scan(body, buf0, xs)
         for slot, rows in slot_rows.items():
             env[slot] = rows
         cols = tuple(

@@ -36,8 +36,8 @@ class SelectArtifact:
     With lazy projection applied (``apply_lazy_select``), projection-only
     columns never ship to the device at all: their output rows carry the
     event's ordinal instead, resolved against the host-retained batch at
-    decode time. For a tunneled accelerator this drops the stateless-query
-    wire to the predicate columns + timestamp deltas."""
+    decode time. This drops the stateless-query wire to the predicate
+    columns + timestamp deltas."""
 
     name: str
     output_schema: OutputSchema

@@ -195,7 +195,11 @@ class WarmStartStore:
 
     where each ``.exe`` file is the pickled
     ``(serialized_bytes, in_tree, out_tree)`` triple of
-    ``jax.experimental.serialize_executable.serialize``. Writes are
+    ``jax.experimental.serialize_executable.serialize`` plus the ids of
+    the devices the executable was compiled for (it is loaded for
+    exactly those — ``deserialize_and_load`` otherwise loads it for
+    every device of the backend, and an executable compiled for one
+    device then rejects its inputs on a several-device host). Writes are
     atomic (tmp + rename), reads that fail to unpickle or load are
     counted errors and degrade to a miss."""
 
@@ -281,7 +285,13 @@ class WarmStartStore:
         if os.path.exists(path):
             return False
         try:
-            payload = pickle.dumps(se.serialize(compiled))
+            device_ids = [
+                d.id
+                for d in compiled.runtime_executable().local_devices()
+            ]
+            payload = pickle.dumps(
+                (*se.serialize(compiled), device_ids)
+            )
         except Exception as e:  # noqa: BLE001 — best-effort persist
             _LOG.warning(
                 "could not serialize %s/%s (%s: %s)",
@@ -302,8 +312,12 @@ class WarmStartStore:
         path = os.path.join(self.key_dir(key), sig_file)
         try:
             with open(path, "rb") as f:
-                blob, in_tree, out_tree = pickle.load(f)
-            return se.deserialize_and_load(blob, in_tree, out_tree)
+                blob, in_tree, out_tree, device_ids = pickle.load(f)
+            by_id = {d.id: d for d in jax.devices()}
+            return se.deserialize_and_load(
+                blob, in_tree, out_tree,
+                execution_devices=[by_id[i] for i in device_ids],
+            )
         except Exception as e:  # noqa: BLE001 — degrade to a miss
             _LOG.warning(
                 "warm store entry %s unreadable (%s: %s); cold path",
@@ -323,12 +337,12 @@ class WarmStartStore:
             )
 
     def _entry_readable(self, path: str) -> bool:
-        """Cheap validity probe: the pickled triple unpickles and its
+        """Cheap validity probe: the pickled tuple unpickles and its
         first element is the serialized-executable byte blob. Does NOT
         deserialize the XLA executable (that is the load path's job)."""
         try:
             with open(path, "rb") as f:
-                blob, _in_tree, _out_tree = pickle.load(f)
+                blob = pickle.load(f)[0]
             return isinstance(blob, (bytes, bytearray))
         except Exception:  # noqa: BLE001 — any failure = corrupt
             return False

@@ -2,9 +2,10 @@
 
 The hot host path — newline-delimited JSON / CSV bytes -> columnar numpy
 arrays with dictionary-interned strings — runs in C++ (fast_decode.cpp),
-built on first use with the in-tree Makefile. Everything degrades to a
-pure-Python decoder when no C++ toolchain is available (``available()``
-tells you which path you are on).
+built (or rebuilt, when the source is newer) through the in-tree
+Makefile at first use in every process. Everything degrades to a
+pure-Python decoder, with a warning, when the build fails
+(``available()`` tells you which path you are on).
 
 String-code consistency: query compilation interns string constants into
 the Python ``StringTable`` (schema/strings.py) and predicates compare
@@ -45,6 +46,10 @@ _tried = False
 
 
 def _build() -> bool:
+    """``make libfastdecode.so``: builds it when missing, rebuilds it
+    when older than fast_decode.cpp, a no-op otherwise (the Makefile's
+    own rule). Every load goes through here, so a loaded library always
+    comes from the source next to it."""
     try:
         subprocess.run(
             ["make", "-s", "libfastdecode.so"],
@@ -55,7 +60,11 @@ def _build() -> bool:
         )
         return True
     except Exception as e:  # toolchain missing / build failure
-        _LOG.info("native decode build unavailable: %s", e)
+        _LOG.warning(
+            "native decode build failed; using the pure-Python decoder: "
+            "%s %s", e,
+            (getattr(e, "stderr", None) or b"").decode(errors="replace"),
+        )
         return False
 
 
@@ -65,12 +74,12 @@ def _load():
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_SO) and not _build():
+        if not _build():
             return None
         try:
             lib = ctypes.CDLL(_SO)
         except OSError as e:
-            _LOG.info("native decode load failed: %s", e)
+            _LOG.warning("native decode load failed: %s", e)
             return None
         lib.fd_interner_new.restype = ctypes.c_void_p
         lib.fd_interner_free.argtypes = [ctypes.c_void_p]

@@ -13,7 +13,7 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 SHARD_AXIS = "shards"
 
@@ -31,6 +31,10 @@ def make_cep_mesh(
         raise ValueError(
             f"requested {n_shards} shards but only {len(devices)} devices"
         )
+    # Auto axis: the engine shards by NamedSharding + shard_map and
+    # never types an array's sharding (jax.make_mesh's own default axis
+    # type has changed between releases, so it is stated)
     return jax.make_mesh(
-        (n_shards,), (SHARD_AXIS,), devices=devices[:n_shards]
+        (n_shards,), (SHARD_AXIS,), axis_types=(AxisType.Auto,),
+        devices=devices[:n_shards],
     )

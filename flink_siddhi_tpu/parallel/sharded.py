@@ -27,9 +27,9 @@ import logging
 
 import time
 
+from ..compiler import pallas_ops
 from ..compiler.plan import CompiledPlan
 from ..runtime.executor import Job, _PlanRuntime, _staging_allow
-from ..utils.jax_compat import shard_map as _shard_map_compat
 from ..runtime.tape import build_tape, bucket_size
 from ..schema.batch import EventBatch
 from ..telemetry import LatencyHistogram
@@ -52,15 +52,6 @@ def _shapes(tree) -> List[Tuple]:
     return [np.shape(leaf) for leaf in jax.tree.leaves(tree)]
 
 
-def _shard_kernel_ok() -> bool:
-    """Whether the pallas kernel passed its shard_map lowering probe
-    (host-side, cached). When it did, the sharded step keeps the fused
-    TPU kernel instead of the XLA fallback (VERDICT round-1 #9)."""
-    from ..compiler import pallas_ops
-
-    return pallas_ops.warmup_shard()
-
-
 def make_sharded_step(plan: CompiledPlan, mesh) -> callable:
     """jit(shard_map(plan.step)) over the ``shards`` mesh axis.
 
@@ -69,22 +60,19 @@ def make_sharded_step(plan: CompiledPlan, mesh) -> callable:
     single-device compile path and the sharded path share all kernels.
     """
 
-    use_kernel = _shard_kernel_ok()
+    # host-side, before the trace: where Pallas applies the sharded step
+    # uses the same fused kernel as the single-device step, and a kernel
+    # that does not survive the shard_map lowering raises here
+    pallas_ops.warmup_shard()
 
     def local(states, tape):
-        from ..compiler import pallas_ops
-
         states = jax.tree.map(lambda x: x[0], states)
         tape = jax.tree.map(lambda x: x[0], tape)
-        if use_kernel:
-            new_states, outputs = plan.step(states, tape, SHARD_AXIS)
-        else:
-            with pallas_ops.force_fallback():
-                new_states, outputs = plan.step(states, tape, SHARD_AXIS)
+        new_states, outputs = plan.step(states, tape, SHARD_AXIS)
         expand = lambda t: jax.tree.map(lambda x: jnp.asarray(x)[None], t)
         return expand(new_states), expand(outputs)
 
-    smapped = _shard_map_compat(
+    smapped = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
@@ -105,27 +93,19 @@ def make_sharded_step_acc(
     the bare shard_map'd callable for callers that embed it in a larger
     program (the sharded bounded-replay scan)."""
 
-    use_kernel = _shard_kernel_ok()
+    pallas_ops.warmup_shard()  # as make_sharded_step
 
     def local(states, acc, tape):
-        from ..compiler import pallas_ops
-
         states = jax.tree.map(lambda x: x[0], states)
         acc = jax.tree.map(lambda x: x[0], acc)
         tape = jax.tree.map(lambda x: x[0], tape)
-        if use_kernel:
-            new_states, new_acc = plan.step_acc(
-                states, acc, tape, SHARD_AXIS
-            )
-        else:
-            with pallas_ops.force_fallback():
-                new_states, new_acc = plan.step_acc(
-                    states, acc, tape, SHARD_AXIS
-                )
+        new_states, new_acc = plan.step_acc(
+            states, acc, tape, SHARD_AXIS
+        )
         expand = lambda t: jax.tree.map(lambda x: jnp.asarray(x)[None], t)
         return expand(new_states), expand(new_acc)
 
-    smapped = _shard_map_compat(
+    smapped = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS)),

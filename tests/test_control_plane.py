@@ -361,6 +361,29 @@ def test_unparsable_cql_refused_not_fatal():
     assert job.control_rejections[bad_id]["rules"] == ["CQL000"]
 
 
+def test_engine_error_on_add_is_not_reported_as_bad_cql():
+    # a compiler/device failure on a dynamic add is recorded under its
+    # own rule id — never as the "unparsable query" CQL000
+    def broken_compiler(cql, plan_id):
+        raise RuntimeError("RESOURCE_EXHAUSTED: out of device memory")
+
+    src = CallbackSource("S", SCHEMA)
+    ctrl = ControlQueueSource()
+    job = Job(
+        [], [src], batch_size=64, time_mode="processing",
+        control_sources=[ctrl], plan_compiler=broken_compiler,
+    )
+    b = MetadataControlEvent.builder()
+    pid = b.add_execution_plan(chain_cql(1, 2))
+    ctrl.push(b.build())
+    feed(src, 0, 4)
+    job.run_cycle()  # the other tenants keep running
+    assert pid not in job.plan_ids
+    rej = job.control_rejections[pid]
+    assert rej["rules"] == ["ENG000"]
+    assert "RESOURCE_EXHAUSTED" in rej["findings"][0]
+
+
 def test_gate_rejects_before_event_ever_pushed():
     ctrl = ControlQueueSource()
     plane = ControlPlane(

@@ -447,6 +447,9 @@ def test_standalone_dynamic_plan_restores_warm_from_store(tmp_path):
     job2.run_cycle()
     job2.drain_outputs()
     assert store2.stats()["misses"] == 0
+    # a loaded executable that rejected its inputs would have been
+    # counted here and served by the jit wrapper instead
+    assert store2.stats()["errors"] == 0
     assert len(job2.results("out")) > 0
 
 

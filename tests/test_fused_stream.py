@@ -21,9 +21,9 @@ tapes. These tests pin the contract:
   dispatches the pending partial segment (the supervised-crash
   exactly-once case lives in tests/test_faults.py).
 
-All tier-1, CPU lane; on this lane the Pallas kernels fall back to
-their XLA forms (the kernel-vs-fallback equivalence runs under the
-Pallas interpreter in tests/test_pallas_ops.py subprocesses).
+All tier-1, CPU lane; on this lane reverse cummins run in their XLA
+form (the kernel-vs-XLA equivalence runs under the Pallas interpreter
+in tests/test_pallas_ops.py subprocesses).
 """
 
 import numpy as np

@@ -2,9 +2,8 @@
 columns never ship to the device — the chain matcher emits event
 ordinals and decode resolves them from host-retained batches.
 
-On a remote/tunneled accelerator the wire is the throughput ceiling
-(README); this cuts the headline pattern's wire to the predicate column
-+ timestamp deltas. Values decode at full host precision (float64),
+Every event crosses the host->device link once; this cuts the headline
+pattern's bytes over it to the predicate column + timestamp deltas. Values decode at full host precision (float64),
 strictly better than the device's float32 round-trip.
 """
 

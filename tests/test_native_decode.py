@@ -299,3 +299,36 @@ def test_sink_streams_skip_retention_when_disabled():
     # sink consumed every row; host retention skipped, counter still live
     assert job.results("out") == []
     assert job.emitted_counts["out"] == 25
+
+
+def test_library_is_built_from_the_source_next_to_it(tmp_path, monkeypatch):
+    """Every load goes through ``make``: a checkout with no
+    libfastdecode.so builds it on first use, and a library older than
+    fast_decode.cpp is rebuilt instead of loaded as found."""
+    import os
+    import shutil
+
+    from flink_siddhi_tpu import native
+
+    for name in ("Makefile", "fast_decode.cpp"):
+        shutil.copy(os.path.join(native._DIR, name), tmp_path / name)
+    so = tmp_path / "libfastdecode.so"
+    monkeypatch.setattr(native, "_DIR", str(tmp_path))
+    monkeypatch.setattr(native, "_SO", str(so))
+
+    def fresh_load():
+        monkeypatch.setattr(native, "_lib", None)
+        monkeypatch.setattr(native, "_tried", False)
+        return native._load()
+
+    assert not so.exists()
+    assert fresh_load() is not None and so.exists()
+    # stale: the library predates its source
+    src_mtime = os.path.getmtime(tmp_path / "fast_decode.cpp")
+    os.utime(so, (src_mtime - 100, src_mtime - 100))
+    assert fresh_load() is not None
+    assert os.path.getmtime(so) > src_mtime
+    # up to date: left alone
+    built = os.path.getmtime(so)
+    assert fresh_load() is not None
+    assert os.path.getmtime(so) == built
