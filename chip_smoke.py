@@ -39,10 +39,13 @@ Data is made from ``--seed`` in the bench generator's shapes: ``id``
 uniform over 50 ids (1,000 for the window phase), one interned ``name``,
 ``price`` in [0, 100), timestamps 1 ms apart.
 
-It exits non-zero, printing no result, when the platform is not ``tpu``.
-The last line of standard output is one JSON object with ``ok``, the
-device as JAX reports it, and per-phase events / rows / seconds — the
-seconds are information about a cold start, not a measurement.
+It exits non-zero, printing nothing on standard output, when the platform
+is not ``tpu`` or when the package is not beside it. On success the line
+before last is ``[chip_smoke] summary: {...}`` — per-phase events / rows /
+seconds (information about a cold start, not a measurement), the compile
+cache's hits and which kernels ran compiled — and the last line of standard
+output is exactly ``{"ok": true, "device": {"platform": ..., "kind": ...,
+"count": ...}}``, the device as JAX reports it.
 """
 
 from __future__ import annotations
@@ -618,6 +621,13 @@ def phase_four_chips(sizes: Sizes, seed: int, n_shards: int = 4):
 
 
 # -- main --------------------------------------------------------------------
+def report(device: dict, summary: str) -> None:
+    """The details, then the last line: exactly ``ok`` and ``device``
+    (``platform``, ``kind``, ``count``) — the driver reads nothing else."""
+    print(f"[chip_smoke] summary: {summary}")
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=7)
@@ -626,6 +636,10 @@ def main(argv=None) -> int:
         help="4: the four-chip phase is required, not optional",
     )
     args = ap.parse_args(argv)
+
+    # alone in a directory (no package beside it) this raises before
+    # anything is written to standard output
+    import flink_siddhi_tpu  # noqa: F401
 
     dev = jax.devices()[0]
     device = {
@@ -666,9 +680,7 @@ def main(argv=None) -> int:
     if device["count"] >= 4:  # required by --chips 4, checked above
         phases["four_chips"] = phase_four_chips(sizes, args.seed)
     # every phase raised on a mismatch; reaching here is the result
-    print(json.dumps({
-        "ok": True,
-        "device": device,
+    summary = json.dumps({
         "jax": jax.__version__,
         "seed": args.seed,
         "total_s": round(time.perf_counter() - t0, 1),
@@ -681,7 +693,8 @@ def main(argv=None) -> int:
             "peak_bytes_in_use"
         ),
         "phases": phases,
-    }))
+    })
+    report(device, summary)
     return 0
 
 

@@ -8,6 +8,7 @@ and that their reference comparisons still hold; that they hold on the
 chip at deployment size is what ``python chip_smoke.py`` is for.
 """
 
+import json
 import os
 import subprocess
 import sys
@@ -67,6 +68,13 @@ def test_four_chips_phase():
     out = chip_smoke.phase_four_chips(TINY, seed=7)
     assert out["shards"] == 4
     assert all(n > 0 for n in out["rows"].values())
+
+
+def test_last_line_is_ok_and_device_only(capsys):
+    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    chip_smoke.report(device, json.dumps({"phases": {}}))
+    last = json.loads(capsys.readouterr().out.splitlines()[-1])
+    assert last == {"ok": True, "device": device}
 
 
 def test_script_fails_without_an_accelerator():
