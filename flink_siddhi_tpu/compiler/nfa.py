@@ -965,6 +965,7 @@ class _ChainCfg:
         )
 
 
+@jax.named_scope("fst.pattern_scan")
 # fst:hotpath device=state,preds,cap_srcs,within_val,ts,valid,tfor_val,batch_max
 def _chain_core(
     cfg: _ChainCfg,
@@ -3468,6 +3469,10 @@ class SlotNFAArtifact:
                 ]
             return (new_st, new_buf), None
 
+        @jax.named_scope("fst.pattern_scan")
+        def scan(carry, xs_):
+            return jax.lax.scan(body, carry, xs_)
+
         xcols = {_skey("src", *pair): cap_srcs[pair] for pair in pairs}
         for key in spec.evt_keys:
             xcols[f"evt:{key}"] = tape.cols[key]
@@ -3501,14 +3506,12 @@ class SlotNFAArtifact:
             )
             (new_state, buf), _ = jax.lax.cond(
                 cnt <= R,
-                lambda carry: jax.lax.scan(body, carry, xs_c),
-                lambda carry: jax.lax.scan(body, carry, xs),
+                lambda carry: scan(carry, xs_c),
+                lambda carry: scan(carry, xs),
                 (state, buf_init),
             )
         else:
-            (new_state, buf), _ = jax.lax.scan(
-                body, (state, buf_init), xs
-            )
+            (new_state, buf), _ = scan((state, buf_init), xs)
 
         emit_env = _emit_env(
             spec,

@@ -275,6 +275,14 @@ class CompiledPlan:
                  axis_name: Optional[str] = None) -> Tuple[Dict, Dict]:
         """step() + on-device append of every emission into ``acc``."""
         new_states, outputs = self.step(states, tape, axis_name)
+        return self._append_outputs(new_states, acc, outputs)
+
+    @jax.named_scope("fst.acc_append")
+    # fst:hotpath device=new_states,acc,outputs
+    def _append_outputs(self, new_states: Dict, acc: Dict,
+                        outputs: Dict) -> Tuple[Dict, Dict]:
+        """The accumulator write: every artifact's emission block is
+        appended to ``acc`` on the device."""
         buf = acc["buf"]
         cap = buf.shape[1]
         ns, over = acc["meta"][0], acc["meta"][1]

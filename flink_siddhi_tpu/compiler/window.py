@@ -389,6 +389,7 @@ class SlidingWindowArtifact:
                 return False
         return True
 
+    @jax.named_scope("fst.window_fold")
     # fst:hotpath device=state,tape
     def step(self, state: Dict, tape) -> Tuple[Dict, Tuple]:
         if self._blocked():
@@ -1128,6 +1129,7 @@ class CumulativeAggArtifact:
             out["@gv"], out["@gc"] = self._chained_tables(need)
         return out
 
+    @jax.named_scope("fst.window_fold")
     # fst:hotpath device=state,tape
     def step(self, state: Dict, tape) -> Tuple[Dict, Tuple]:
         env: ColumnEnv = dict(tape.cols)
@@ -1398,6 +1400,7 @@ class BatchWindowArtifact:
             return E // self.length + 2
         return self.batch_slots + 1
 
+    @jax.named_scope("fst.window_fold")
     # fst:hotpath device=state,tape
     def step(self, state: Dict, tape) -> Tuple[Dict, Tuple]:
         env: ColumnEnv = dict(tape.cols)
@@ -2238,6 +2241,7 @@ class ExpiredWindowArtifact:
         ai = jnp.clip(idx - P0, 0, arr_col.shape[0] - 1)
         return jnp.where(idx < P0, from_ring, arr_col[ai])
 
+    @jax.named_scope("fst.window_fold")
     # fst:hotpath device=state,tape
     def step(self, state: Dict, tape) -> Tuple[Dict, Tuple]:
         env: ColumnEnv = dict(tape.cols)
@@ -2518,6 +2522,7 @@ class PerKeyWindowArtifact:
             )
         return out
 
+    @jax.named_scope("fst.window_fold")
     # fst:hotpath device=state,tape
     def step(self, state: Dict, tape) -> Tuple[Dict, Tuple]:
         env: ColumnEnv = dict(tape.cols)

@@ -240,8 +240,6 @@ class ShardedJob(Job):
         router = self._routers[plan.plan_id]
         with tel.span("route"):
             shards = router.route_all(involved)
-        for b in involved:
-            self.tracer.mark(b.timestamps, "route")
         # per-shard placement visibility: a skewed key distribution
         # shows up here long before it shows up as one hot shard
         tel.gauge(
@@ -288,8 +286,6 @@ class ShardedJob(Job):
             rt.acc_dirty = True
             if rt.dirty_since is None:
                 rt.dirty_since = time.monotonic()
-        for b in involved:
-            self.tracer.mark(b.timestamps, "dispatch")
         # shared no-overflow contract (Job._update_drain_hint); strip the
         # leading shard axis via shape metadata only
         self._update_drain_hint(
@@ -337,31 +333,38 @@ class ShardedJob(Job):
         t_dirty = rt.dirty_since
         rt.acc_dirty = False
         rt.dirty_since = None
+        tel = self.telemetry
         t_req = time.monotonic()
-        meta = np.asarray(rt.acc["meta"])  # (shards, 2, A) — one fetch
-        counts, overflow = meta[:, 0], meta[:, 1]
-        seen = getattr(rt, "_overflow_seen", None)
-        already = 0 if seen is None else int(np.sum(seen))
-        total = int(overflow.sum())
-        if total > already:  # log new drops once, not per check
-            _LOG.warning(
-                "%s: %d emissions dropped across shards (accumulator "
-                "full; raise EngineConfig.acc_budget_bytes or drain "
-                "more often)", rt.plan.plan_id, total - already,
-            )
-        rt._overflow_seen = overflow
-        max_n = int(counts.max()) if counts.size else 0
-        if max_n == 0:
-            return
-        # bucketed fetch width: stable slice shapes (see Job._drain_plan)
-        fetch_n = min(bucket_size(max_n, minimum=1024),
-                      rt.plan.acc_capacity())
-        data = np.asarray(
-            rt.acc["buf"][:, :, :fetch_n]
-        )[:, :, :max_n]  # fetch two
+        # the drain's legs, each a profiler annotation and a histogram:
+        # drain.fetch (request -> both fetches done), drain.decode (the
+        # per-shard decodes, summed), drain.emit (merge, emit, sinks)
+        with tel.annotate("fst.drain.fetch"):
+            meta = np.asarray(rt.acc["meta"])  # (shards, 2, A) — one fetch
+            counts, overflow = meta[:, 0], meta[:, 1]
+            seen = getattr(rt, "_overflow_seen", None)
+            already = 0 if seen is None else int(np.sum(seen))
+            total = int(overflow.sum())
+            if total > already:  # log new drops once, not per check
+                _LOG.warning(
+                    "%s: %d emissions dropped across shards (accumulator "
+                    "full; raise EngineConfig.acc_budget_bytes or drain "
+                    "more often)", rt.plan.plan_id, total - already,
+                )
+                tel.inc("faults.emissions_dropped", total - already)
+            rt._overflow_seen = overflow
+            max_n = int(counts.max()) if counts.size else 0
+            if max_n == 0:
+                return
+            # bucketed fetch width: stable slice shapes (see
+            # Job._drain_plan)
+            fetch_n = min(bucket_size(max_n, minimum=1024),
+                          rt.plan.acc_capacity())
+            data = np.asarray(
+                rt.acc["buf"][:, :, :fetch_n]
+            )[:, :, :max_n]  # fetch two
+        tel.record_seconds("drain.fetch", time.monotonic() - t_req)
         rt.acc = rt.jitted_init_acc()
         rt._overflow_seen = None  # counters reset with the accumulator
-        tel = self.telemetry
         # per-shard decode-time histograms, kept PER SHARD on the
         # runtime and folded into the job registry after the sweep —
         # the mergeable-across-shards histogram contract in production
@@ -385,13 +388,15 @@ class ShardedJob(Job):
         # merge each output's per-shard (already time-ordered) rows by
         # timestamp so sinks observe near-monotonic time across shards
         per_schema = {}
+        decode_s = 0.0
         for s in range(self.n_shards):
-            t0 = time.perf_counter()
-            decoded = rt.plan.drain_decode(counts[s], data[s])
+            with tel.annotate("fst.drain.decode", shard=s):
+                t0 = time.perf_counter()
+                decoded = rt.plan.drain_decode(counts[s], data[s])
+                dt = time.perf_counter() - t0
+            decode_s += dt
             if shard_hists is not None:
-                shard_hists[s].record_seconds(
-                    time.perf_counter() - t0
-                )
+                shard_hists[s].record_seconds(dt)
             for a in rt.plan.artifacts:
                 for schema, rows in decoded.get(a.name) or []:
                     if (
@@ -413,26 +418,31 @@ class ShardedJob(Job):
                     per_schema.setdefault(
                         schema.stream_id, (schema, [])
                     )[1].append(rows)
-        for schema, shard_rows in per_schema.values():
-            if self._sinks.get(schema.stream_id):
-                # sinks observe emission order: merge shards by timestamp
-                rows = list(
-                    heapq.merge(*shard_rows, key=lambda p: p[0])
+        tel.record_seconds("drain.decode", decode_s)
+        t_emit = time.monotonic()
+        with tel.annotate("fst.drain.emit"):
+            for schema, shard_rows in per_schema.values():
+                if self._sinks.get(schema.stream_id):
+                    # sinks observe emission order: merge shards by
+                    # timestamp
+                    rows = list(
+                        heapq.merge(*shard_rows, key=lambda p: p[0])
+                    )
+                else:
+                    # collectors re-sort on read; skip the per-row merge
+                    rows = [r for sh in shard_rows for r in sh]
+                # traces already completed per shard above, except for
+                # rate-limited streams (completed post-limiter here)
+                self._emit_rows(
+                    schema, rows,
+                    trace=schema.stream_id in self._rate_limiters,
                 )
-            else:
-                # collectors re-sort on read; skip the per-row merge
-                rows = [r for sh in shard_rows for r in sh]
-            # traces already completed per shard above, except for
-            # rate-limited streams (completed post-limiter here)
-            self._emit_rows(
-                schema, rows,
-                trace=schema.stream_id in self._rate_limiters,
-            )
         if tel.enabled:
             # same semantics as Job's drain.total: meta check -> rows
             # emitted (the timestamp merge and sink delivery included),
             # so the metric is comparable across job kinds
             now = time.monotonic()
+            tel.record_seconds("drain.emit", now - t_emit)
             tel.record_seconds("drain.total", now - t_req)
             stale = None
             if t_dirty is not None and self._has_consumers(rt):

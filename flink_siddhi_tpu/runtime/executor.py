@@ -32,6 +32,7 @@ from ..telemetry import MetricsRegistry
 from ..telemetry import compile_events
 from ..telemetry.attribution import limiting_leg as _attr_limiting_leg
 from ..telemetry.flightrec import FlightRecorder
+from ..telemetry.legs import SegmentRecord, record_legs
 from ..telemetry.slo import SLOWatchdog
 from ..telemetry.tracing import TraceSampler
 from .sources import Source
@@ -168,6 +169,12 @@ class _PlanRuntime:
     # keys off it (next drain due = dirty_since + drain_interval_ms)
     # and drain.staleness records it per completed drain
     dirty_since: Optional[float] = None
+    # latency legs (telemetry/legs.py): the records of the segments
+    # dispatched since the last accumulator swap (the drain request
+    # that takes the accumulator takes them with it), and those whose
+    # ticket the host has not yet seen ready, oldest first
+    seg_open: List = field(default_factory=list)
+    seg_inflight: deque = field(default_factory=deque)
 
 
 class _LazyRing:
@@ -837,6 +844,15 @@ class Job:
         # per-stream clock re-armed each cycle would report
         # milliseconds of residency while rows actually wait seconds
         self._pending_t: Dict[str, List[Tuple[float, int]]] = {}
+        # latency legs: the earliest arrival among the source batches
+        # behind each stream's newest released batch, and the ordinals
+        # that the spans of one segment and of one drain share
+        # fst:ephemeral per-cycle monotonic stamps of the gate's last release; a restored job releases anew
+        self._ready_arrival: Dict[str, float] = {}
+        # fst:ephemeral span ordinals of this process's profiler traces and leg records
+        self._seg_ordinal = 0
+        # fst:ephemeral span ordinals of this process's profiler traces and leg records
+        self._drain_ordinal = 0
         # fault visibility: sources that can report state/transport
         # faults (KafkaSource retry counters, _DecodedLinesSource
         # degraded positions) mirror them into this job's registry
@@ -1630,6 +1646,8 @@ class Job:
                 rt for rt in list(self._plans.values())
                 if rt.enabled and mid in rt.plan.spec.stream_codes
             ]
+            # mid rows arrived when the oldest of them was buffered
+            self._ready_arrival[mid] = pending[2]
             for i in range(0, len(pairs), limit):
                 part = pairs[i:i + limit]
                 batch = EventBatch.from_records(
@@ -2181,6 +2199,8 @@ class Job:
             rt.dirty_since = None
             rt.seg_pending = []
             rt.tickets.clear()
+            rt.seg_open = []
+            rt.seg_inflight.clear()
             if getattr(rt, "lazy", None) is not None:
                 rt.lazy = _LazyRing(rt.lazy.budget)
                 rt.lazy_base = None
@@ -2269,9 +2289,11 @@ class Job:
         finished: a fresh (non-donated) jit output derived from the
         smallest state leaf — safe to hold across cycles."""
         if cls._noop_jit is None:
-            cls._noop_jit = jax.jit(
-                lambda x: jnp.asarray(x).ravel()[:1] * 0
-            )
+            # a named function: the program is jit_ticket in a trace
+            def ticket(x):
+                return jnp.asarray(x).ravel()[:1] * 0
+
+            cls._noop_jit = jax.jit(ticket)
         leaves = jax.tree.leaves(states)
         leaf = min(leaves, key=lambda x: getattr(x, "size", 1 << 30))
         return cls._noop_jit(leaf)
@@ -2508,6 +2530,11 @@ class Job:
         t_dirty = rt.dirty_since
         rt.dirty_since = None
         want = self._has_consumers(rt)
+        # the segments dispatched since the last swap left their
+        # emissions in this accumulator: their records go with it
+        # (plans nobody observes record no legs)
+        segs, rt.seg_open = rt.seg_open, []
+        self._drain_ordinal += 1
         # no-consumer entries (want=False) fetch counts only — the data
         # phase AND the host decode are skipped entirely; the swap
         # itself still happens (overflow accounting)
@@ -2515,6 +2542,8 @@ class Job:
             {
                 "acc": old,
                 "want": want,
+                "segs": segs if want else (),
+                "ord": self._drain_ordinal,
                 # which output streams decode columnar (all consumers
                 # opted in): resolved at request time so a sink attached
                 # mid-flight (add_sink drains first) cannot race
@@ -2530,7 +2559,10 @@ class Job:
         )
         self._advance_ready(rt)
         if len(rt.drain_q) > self.MAX_PENDING_DRAINS:
-            self._drain_poll(rt, block=True, limit=1)
+            # the run loop blocked on the fetch thread's backlog (the
+            # drain side's backpressure_wait; nested under "drain")
+            with self.telemetry.span("drain.backlog_wait"):
+                self._drain_poll(rt, block=True, limit=1)
 
     def _columnar_streams(self, rt: _PlanRuntime) -> frozenset:
         """Output streams of this plan whose rows never need to exist:
@@ -2586,12 +2618,16 @@ class Job:
                 continue
             if not entry["acc"]["meta"].is_ready():
                 break
-            entry["t_ready"] = time.monotonic()
+            t_ready = entry["t_ready"] = time.monotonic()
+            for rec in entry["segs"]:
+                # meta readiness implies the segment's work retired
+                if rec.complete is None:
+                    rec.complete, rec.ticket = t_ready, None
             entry["stages"] = {}
             entry["fut"] = self._fetch_pool.submit(
                 self._fetch_acc, rt, entry.pop("acc"),
                 entry.pop("want"), entry.pop("columnar"),
-                entry["stages"],
+                entry["stages"], self.telemetry, entry["ord"],
             )
 
     @property
@@ -2615,7 +2651,7 @@ class Job:
     # fst:thread-root name=drain-fetch
     def _fetch_acc(rt: _PlanRuntime, acc: Dict, want: bool,
                    columnar: frozenset,
-                   stages: Optional[Dict] = None):
+                   stages: Dict, tel: MetricsRegistry, drain: int):
         """Fetch-thread body — the TWO-PHASE count-prefix fetch. Phase
         one transfers the tiny meta array (per-artifact counts +
         overflow). Phase two, only when matches exist and a consumer
@@ -2626,38 +2662,52 @@ class Job:
         widths keep the pack-program count to a handful of shapes (a
         distinct shape per drain would compile a fresh program every
         time). Decode also happens here
-        so the run loop only emits."""
-        if stages is not None:
+        so the run loop only emits.
+
+        Each phase is a profiler annotation (``fst.drain.fetch``,
+        ``fst.drain.decode``, with the drain's ordinal) and is booked
+        into ``drain.fetch`` / ``drain.decode`` HERE, as it ends: the
+        busy share of this thread then counts work in the second it was
+        done, not at the run loop's next poll. ``stages`` takes the
+        stamps the run loop's own legs need."""
+        with tel.annotate("fst.drain.fetch", drain=drain):
             stages["t_fetch0"] = time.monotonic()
-        meta = np.asarray(acc["meta"])  # phase one: the count prefix
-        counts, overflow = meta[0], meta[1]
-        max_n = int(counts.max()) if counts.size else 0
-        if stages is not None:
+            meta = np.asarray(acc["meta"])  # phase one: the count prefix
+            counts, overflow = meta[0], meta[1]
+            max_n = int(counts.max()) if counts.size else 0
             stages["t_meta"] = time.monotonic()
-        if not want or max_n == 0:
-            # stamp the leg ends: falling back to the run-loop poll
-            # time would record idle poll latency as transfer time in
-            # the drain.fetch / drain.transport histograms
-            if stages is not None:
-                stages["t_dec0"] = stages["t_fetch1"] = time.monotonic()
-            return counts, overflow, None
-        width = min(
-            bucket_size(max_n, minimum=Job.MIN_FETCH_WIDTH),
-            rt.plan.acc_capacity(),
-        )
-        # phase two: count-sized data slice (pack dispatch + transfer)
-        data = np.asarray(Job._pack_data(rt, acc, width))[:, :max_n]
-        if stages is not None:
+            data = None
+            if want and max_n:
+                width = min(
+                    bucket_size(max_n, minimum=Job.MIN_FETCH_WIDTH),
+                    rt.plan.acc_capacity(),
+                )
+                # phase two: count-sized data slice (pack dispatch +
+                # transfer)
+                data = np.asarray(
+                    Job._pack_data(rt, acc, width)
+                )[:, :max_n]
             stages["t_dec0"] = time.monotonic()
-        lazy = getattr(rt, "lazy", None)
-        decoded = rt.plan.drain_decode(
-            counts, data,
-            lookup=lazy.lookup if lazy is not None else None,
-            columnar_streams=columnar,
-            lookup_np=lazy.lookup_np if lazy is not None else None,
+        tel.record_seconds(
+            "drain.fetch", stages["t_dec0"] - stages["t_fetch0"]
         )
-        if stages is not None:
+        decoded = None
+        with tel.annotate("fst.drain.decode", drain=drain):
+            if data is not None:
+                lazy = getattr(rt, "lazy", None)
+                decoded = rt.plan.drain_decode(
+                    counts, data,
+                    lookup=lazy.lookup if lazy is not None else None,
+                    columnar_streams=columnar,
+                    lookup_np=lazy.lookup_np if lazy is not None else None,
+                )
+            # an empty or unwanted drain stamps its leg ends too:
+            # falling back to the run-loop poll time would record idle
+            # poll latency as transfer time in drain.transport
             stages["t_fetch1"] = time.monotonic()
+        tel.record_seconds(
+            "drain.decode", stages["t_fetch1"] - stages["t_dec0"]
+        )
         return counts, overflow, decoded
 
     def _drain_poll(
@@ -2704,11 +2754,12 @@ class Job:
                 t_f0 = st.get("t_fetch0", t_rdy)
                 t_f1 = st.get("t_fetch1", now)
                 t_d0 = st.get("t_dec0", t_f1)
+                # drain.fetch (d2h only: meta + data phase) and
+                # drain.decode (host decode only) are booked by the
+                # fetch thread as each ends (_fetch_acc)
                 legs = {
                     "wait_ready": t_rdy - t_req,
                     "queue": t_f0 - t_rdy,
-                    "fetch": t_d0 - t_f0,  # d2h only: meta + data phase
-                    "decode": t_f1 - t_d0,  # host decode only
                     "emit_lag": now - t_f1,
                     "total": now - t_req,
                 }
@@ -2732,7 +2783,7 @@ class Job:
                 # (readiness round trip + d2h transfer, decode excluded)
                 tel.record_seconds(
                     "drain.transport",
-                    legs["wait_ready"] + legs["fetch"],
+                    legs["wait_ready"] + (t_d0 - t_f0),
                 )
                 tel.inc("drains.completed")
                 # plan-scoped twins of total/staleness: each plan this
@@ -2748,6 +2799,7 @@ class Job:
                         "raise EngineConfig.acc_budget_bytes or drain "
                         "more often)", a.name, int(overflow[ai]),
                     )
+                    tel.inc("faults.emissions_dropped", int(overflow[ai]))
             # the only place the engine degrades instead of failing
             # loudly: a lazy-projected value older than the ring budget
             # decodes as None in user rows — surface it (round-5 verdict
@@ -2763,6 +2815,7 @@ class Job:
                         "results more often)",
                         rt.plan.plan_id, lazy.missed - warned,
                     )
+                    tel.inc("faults.lazy_evicted", lazy.missed - warned)
                     rt._lazy_miss_warned = lazy.missed
             if decoded is not None:
                 from ..compiler.output import ColumnBatch
@@ -2802,6 +2855,14 @@ class Job:
                             if sc is not None:
                                 sc.inc("rows_emitted", c)
                                 sc.inc("matches", c)
+            if done_entry["segs"]:
+                # the drain's last emission has returned from the
+                # sinks: close the latency legs of every segment whose
+                # emissions this accumulator held
+                record_legs(
+                    tel, done_entry["segs"], done_entry["t_req"],
+                    time.monotonic(),
+                )
             done += 1
             if limit and done >= limit:
                 return
@@ -3032,6 +3093,7 @@ class Job:
         # advance any in-flight drain fetches (never blocks the host)
         with tel.span("drain"):
             for rt in self._plans.values():
+                self._stamp_complete(rt)
                 self._drain_poll(rt)
         if self.fused_segment_len and self.fused_segment_len > 1:
             # a partial segment must not wait forever for a slow source
@@ -3357,6 +3419,13 @@ class Job:
                 for bs in self._pending.values()
                 if bs
             ]
+            # a released batch carries the earliest arrival of the
+            # source batches it holds (latency legs)
+            for sid in self._pending:
+                entries = self._pending_t.get(sid)
+                self._ready_arrival[sid] = (
+                    entries[0][0] if entries else time.monotonic()
+                )
             self._pending.clear()
             self._pending_t.clear()
             return ready
@@ -3410,6 +3479,9 @@ class Job:
             entries = self._pending_t.get(sid)
             if n_ready:
                 ready.append(merged.slice(0, n_ready))
+                # a released batch carries the earliest arrival of
+                # the source batches it holds (latency legs)
+                self._ready_arrival[sid] = entries[0][0] if entries else now
                 if entries and tel.enabled:
                     # buffer age of the oldest batch still pending at
                     # this release: rows within a batch arrived
@@ -3742,24 +3814,17 @@ class Job:
         if rt.seg_pending and rt.seg_pending[0]["sig"] != sig:
             self._dispatch_segment(rt)
         with self.telemetry.span("tape_build"):
-            # the sampling mask is computed once per batch; the tiny
-            # sampled subset serves both the "staged" mark here and
-            # the "dispatch" mark later
-            sampled = [
-                self.tracer.sampled_subset(b.timestamps)
-                for b in involved
-            ]
+            now = time.monotonic()
             rt.seg_pending.append(
                 {
                     "tape": tape,
                     "sig": sig,
-                    "ts": sampled,
-                    "t": time.monotonic(),
+                    "t": now,  # staged
+                    "arrival": self._arrival_of(involved, now),
+                    "events": sum(len(b) for b in involved),
                 }
             )
             self.telemetry.inc("fusion.batches")
-            for s in sampled:
-                self.tracer.mark(s, "staged", presampled=True)
         if len(rt.seg_pending) >= self._fused_k(rt):
             self._dispatch_segment(rt)
 
@@ -3784,7 +3849,13 @@ class Job:
         while len(wires) < k_full:
             wires.append(_empty_wire_like(wires[-1]))
         tel = self.telemetry
-        with tel.span("stage.h2d_overlap"):
+        rec, seg_id = self._open_segment(
+            rt,
+            [e["arrival"] for e in pending],
+            [e["t"] for e in pending],
+            [e["events"] for e in pending],
+        )
+        with tel.span("stage.h2d_overlap", seg=seg_id):
             # overlap proof: the upload is issued while the device is
             # still busy with the previous segment — counted, not
             # asserted. The NEWEST ticket is the previous segment's
@@ -3797,7 +3868,7 @@ class Job:
         if busy:
             tel.inc("fusion.h2d_overlapped")
         plan = rt.plan
-        with self._compile_scope(rt), tel.span("dispatch"):
+        with self._compile_scope(rt), tel.span("dispatch", seg=seg_id):
             t0 = time.monotonic()
             # host interning during staging may have discovered new
             # group keys: grow once per segment, before the scanned
@@ -3825,28 +3896,82 @@ class Job:
                 tel.record_seconds("dispatch.segment", dt)
                 tel.record_seconds("dispatch.enqueue", dt)
                 tel.inc("fusion.dispatches")
-        # ticket creation OUTSIDE the attribution scope: the one-shot
-        # helper jit (_make_ticket's _noop_jit) is process-wide harness
-        # plumbing shared by every plan — attributing its single
-        # lowering to whichever plan happened to dispatch first would
-        # misattribute it, and would break the fleet bootstrap's
-        # zero-new-lowerings pin (metrics()["compiles"], docs/fleet.md)
-        rt.tickets.append(self._make_ticket(rt.states))
-        for e in pending:
-            for t in e["ts"]:
-                self.tracer.mark(t, "dispatch", presampled=True)
-        while rt.tickets and rt.tickets[0].is_ready():
-            rt.tickets.popleft()
-        if len(rt.tickets) > self.max_inflight_cycles:
-            with tel.span("backpressure_wait"):
-                jax.block_until_ready(rt.tickets.popleft())
-            while rt.tickets and rt.tickets[0].is_ready():
-                rt.tickets.popleft()
+        # outside the compile-attribution scope (see _ticket_window)
+        self._ticket_window(rt, rec, seg_id)
         if plan.has_flush and (
             rt.flush_warm is None
             or rt.flush_warm[0] != self._state_sig(rt.states)
         ):
             self._warm_flush(rt)
+
+    def _arrival_of(self, involved: List[EventBatch], now: float) -> float:
+        """The earliest arrival (source pull) behind these released
+        batches, as _release_ready left it."""
+        return min(
+            self._ready_arrival.get(b.stream_id, now) for b in involved
+        )
+
+    def _open_segment(
+        self, rt: _PlanRuntime, arrival: List[float],
+        staged: List[float], events: List[int],
+    ) -> Tuple[Optional[SegmentRecord], int]:
+        """Number the segment about to be dispatched and, with
+        telemetry on, open its latency-leg record (the ``dispatch``
+        stamp is taken here, before the upload). Returns the record
+        (None with telemetry off) and the ordinal."""
+        self._seg_ordinal += 1
+        seg = self._seg_ordinal
+        if not self.telemetry.enabled:
+            return None, seg
+        rec = SegmentRecord(seg, arrival, staged, events, time.monotonic())
+        rt.seg_open.append(rec)
+        rt.seg_inflight.append(rec)
+        return rec, seg
+
+    def _stamp_complete(self, rt: _PlanRuntime) -> None:
+        """Stamp ``complete`` on every segment whose ticket the host
+        now sees ready, oldest first: one ``is_ready()`` on the oldest
+        unretired ticket, no thread and no blocking call. Called once a
+        run cycle and at every dispatch, which is the resolution of
+        the stamp."""
+        inflight = rt.seg_inflight
+        if not inflight:
+            return
+        now = time.monotonic()
+        while inflight and inflight[0].poll_complete(now):
+            inflight.popleft()
+
+    def _ticket_window(
+        self, rt: _PlanRuntime, rec: Optional[SegmentRecord], seg_id: int
+    ) -> None:
+        """Sliding-window backpressure: a tiny non-donated "ticket" is
+        derived from the new state at each dispatch; completed tickets
+        retire via is_ready polling (free), and only when the device
+        is a full window behind does the host genuinely block. Holding
+        tickets (fresh jit outputs) never blocks state-buffer
+        donation. The ticket is created OUTSIDE the compile-attribution
+        scope: the one-shot helper jit (_make_ticket's _noop_jit) is
+        process-wide harness plumbing shared by every plan —
+        attributing its single lowering to whichever plan happened to
+        dispatch first would misattribute it, and would break the fleet
+        bootstrap's zero-new-lowerings pin (metrics()["compiles"],
+        docs/fleet.md)."""
+        tel = self.telemetry
+        ticket = self._make_ticket(rt.states)
+        rt.tickets.append(ticket)
+        if rec is not None:
+            rec.ticket = ticket
+        while rt.tickets and rt.tickets[0].is_ready():
+            rt.tickets.popleft()
+        # the depth of the queue the device works through, this segment
+        # included: over fusion.dispatches, its mean
+        tel.inc("segments.inflight_sum", len(rt.tickets))
+        if len(rt.tickets) > self.max_inflight_cycles:
+            with tel.span("backpressure_wait", seg=seg_id):
+                jax.block_until_ready(rt.tickets.popleft())
+            while rt.tickets and rt.tickets[0].is_ready():
+                rt.tickets.popleft()
+        self._stamp_complete(rt)
 
     def _step_plan_window(
         self, rt: _PlanRuntime, involved: List[EventBatch]
@@ -3859,12 +3984,18 @@ class Job:
         plan = rt.plan
         tape = self._stage_tape(rt, involved)
         tel = self.telemetry
+        # a segment of one batch: same record, same legs (fill ~ 0)
+        staged = time.monotonic()
+        rec, seg_id = self._open_segment(
+            rt, [self._arrival_of(involved, staged)], [staged],
+            [sum(len(b) for b in involved)],
+        )
         # host interning may have discovered new group keys: re-bucket
         # state tables before the jit call (shape change -> one-off
         # retrace; host-driven re-bucketing = staging-class work)
         with _staging_allow():
             rt.states = plan.grow_state(rt.states)
-        with self._compile_scope(rt), tel.span("dispatch"):
+        with self._compile_scope(rt), tel.span("dispatch", seg=seg_id):
             t0 = time.monotonic()
             # NO device->host fetch here: emissions append to the
             # on-device accumulator and are drained in bulk
@@ -3885,26 +4016,8 @@ class Job:
                 tel.record_seconds(
                     "dispatch.enqueue", time.monotonic() - t0
                 )
-        # sliding-window backpressure: a tiny non-donated "ticket" is
-        # derived from the new state each cycle; completed tickets
-        # retire via is_ready polling (free), and only when the device
-        # is a full window behind does the host genuinely block.
-        # Holding tickets (fresh jit outputs) never blocks state-buffer
-        # donation. Created OUTSIDE the attribution scope: the helper
-        # jit is process-wide plumbing, not a plan compile (see
-        # _stage_fused and the fleet zero-lowering pin, docs/fleet.md).
-        rt.tickets.append(self._make_ticket(rt.states))
-        # sampled events' ingest->dispatch leg (dispatch is async: this
-        # marks the point work for the event was HANDED to the device)
-        for b in involved:
-            self.tracer.mark(b.timestamps, "dispatch")
-        while rt.tickets and rt.tickets[0].is_ready():
-            rt.tickets.popleft()
-        if len(rt.tickets) > self.max_inflight_cycles:
-            with tel.span("backpressure_wait"):
-                jax.block_until_ready(rt.tickets.popleft())
-            while rt.tickets and rt.tickets[0].is_ready():
-                rt.tickets.popleft()
+        # outside the compile-attribution scope (see _ticket_window)
+        self._ticket_window(rt, rec, seg_id)
         self._update_drain_hint(
             plan, tape.capacity, lambda name: rt.states.get(name)
         )
@@ -3967,6 +4080,9 @@ class Job:
                     _LOG.warning(
                         "%s: %d emissions dropped (stacked emission "
                         "buffer overflow)", a.name, int(out[2]),
+                    )
+                    self.telemetry.inc(
+                        "faults.emissions_dropped", int(out[2])
                     )
                 if int(count) == 0:
                     continue

@@ -150,13 +150,6 @@ class ResidentReplay:
         if self._staged:
             with tel.span("stage.prewarm"):
                 self.job.prewarm_drains()
-        # per-event trace legs: sampled events were stamped at source
-        # pull (job._pull_sources above); mark the end of staging so a
-        # replay trace decomposes into ingest->staged (tape build + h2d
-        # + compile) and staged->emit (scan + drain + decode)
-        for ready in ready_sets:
-            for b in ready:
-                job.tracer.mark(b.timestamps, "staged")
         self.stage_seconds = time.perf_counter() - t0
 
     def _segment_cycles(self, rt: _PlanRuntime, capacity: int) -> int:
@@ -351,9 +344,6 @@ class ResidentReplay:
             if staged:
                 with tel.span("stage.prewarm"):
                     job.prewarm_drains()
-            for ready in ready_sets:
-                for b in ready:
-                    job.tracer.mark(b.timestamps, "staged")
             for pid, st in staged.items():
                 rt = job._plans.get(pid)
                 if rt is None:

@@ -97,20 +97,38 @@ class LatencyHistogram:
     def record(self, value: int, count: int = 1) -> None:
         self.record_many(np.asarray([value], dtype=np.int64), count)
 
-    def record_many(
-        self, values: Sequence, weight: int = 1
-    ) -> None:
+    def record_many(self, values: Sequence, weight=1) -> None:
+        """``weight`` is one int for every value, or an int array of
+        the values' shape (a batch's leg, weighted by its events)."""
         v = np.asarray(values, dtype=np.int64)
         if v.size == 0:
             return
-        idx = self._indices(v)
+        w = np.broadcast_to(np.asarray(weight, dtype=np.int64), v.shape)
+        self._add(self._indices(v), v, w)
+
+    def _add(self, idx: np.ndarray, v: np.ndarray, w: np.ndarray) -> None:
         with self._lock:
-            np.add.at(self.counts, idx, weight)
-            self._count += int(v.size) * weight
-            self._sum += int(v.sum()) * weight
+            np.add.at(self.counts, idx, w)
+            self._count += int(w.sum())
+            self._sum += int((v * w).sum())
             lo, hi = int(v.min()), int(v.max())
             self._min = lo if self._min is None else min(self._min, lo)
             self._max = hi if self._max is None else max(self._max, hi)
+
+    @staticmethod
+    def record_rows(
+        hists: Sequence["LatencyHistogram"], values, weight=1
+    ) -> None:
+        """Row k of the matrix ``values`` into ``hists[k]`` (histograms
+        of one geometry), every row under the same ``weight``: the
+        index arithmetic runs once over the whole matrix."""
+        v = np.asarray(values, dtype=np.int64)
+        if v.size == 0:
+            return
+        idx = hists[0]._indices(v)
+        w = np.broadcast_to(np.asarray(weight, dtype=np.int64), v.shape[1:])
+        for k, h in enumerate(hists):
+            h._add(idx[k], v[k], w)
 
     def record_seconds(self, seconds: float) -> None:
         self.record(int(max(seconds, 0.0) * 1e6))
@@ -127,6 +145,12 @@ class LatencyHistogram:
     def count(self) -> int:
         with self._lock:
             return self._count
+
+    @property
+    def sum(self) -> int:
+        """All recorded values times their weights, in native units."""
+        with self._lock:
+            return self._sum
 
     def percentile(self, q: float) -> Optional[float]:
         """Nearest-rank percentile (q in [0, 100]) in native units, or
@@ -193,13 +217,43 @@ class LatencyHistogram:
         out._count, out._sum, out._min, out._max = count, total, lo, hi
         return out
 
+    @classmethod
+    def from_snapshots(
+        cls, after: Dict, before: Optional[Dict] = None, **geometry
+    ) -> "LatencyHistogram":
+        """The histogram of the samples recorded between two
+        ``snapshot()`` calls on one histogram (``before`` None: since it
+        was made). Bucket counts are exact; the extremes of a window
+        are not known, so its sum and percentiles come from mid-bucket
+        values, within the quantization bound."""
+        out = cls(**geometry)
+        for snap, sign in ((after, 1), (before or {}, -1)):
+            for i, c in snap.get("buckets") or ():
+                out.counts[i] += sign * c
+        if (out.counts < 0).any():
+            raise ValueError("snapshots are not of one histogram, in order")
+        nz = np.flatnonzero(out.counts)
+        out._count = int(out.counts.sum())
+        out._sum = int(
+            sum(out.value_at(i) * int(out.counts[i]) for i in nz)
+        )
+        return out
+
     def snapshot(self) -> Dict[str, object]:
         """JSON-safe summary (milliseconds for the default us unit)."""
         with self._lock:
             if self._count == 0:
-                return {"count": 0, "unit": self.unit}
+                return {"count": 0, "unit": self.unit, "buckets": []}
+            nz = np.flatnonzero(self.counts)
             return {
                 "count": int(self._count),
+                # exact, in native units (the means below are rounded)
+                "sum": int(self._sum),
+                # the non-zero [index, count] pairs: what from_snapshots
+                # differences into the histogram of a window
+                "buckets": [
+                    [int(i), int(c)] for i, c in zip(nz, self.counts[nz])
+                ],
                 "unit": self.unit,
                 "min_ms": round(self._min / 1e3, 3),
                 "max_ms": round(self._max / 1e3, 3),

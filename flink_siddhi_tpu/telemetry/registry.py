@@ -23,6 +23,8 @@ from __future__ import annotations
 import threading
 from typing import Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 from .histogram import LatencyHistogram
 from .spans import NULL_SPAN, StageTimes
 
@@ -133,10 +135,17 @@ class MetricsRegistry:
         return out
 
     # -- spans / stage time -------------------------------------------------
-    def span(self, name: str):
+    def span(self, name: str, **stats):
         if not self.enabled:
             return NULL_SPAN
-        return self.stages.span(name)
+        return self.stages.span(name, **stats)
+
+    def annotate(self, name: str, **stats):
+        """A profiler annotation alone (no stage time): for work off the
+        run loop, whose time a histogram already carries."""
+        if not self.enabled:
+            return NULL_SPAN
+        return TraceAnnotation(name, **stats)
 
     def add_time(self, name: str, seconds: float) -> None:
         if self.enabled:

@@ -16,16 +16,20 @@ summable against a wall clock:
   attribution sums only the run-loop lane's stage names
   (``TOP_LEVEL_STAGES`` in the package root).
 
-A bounded ring of recently-closed spans (name, end-monotonic, seconds)
-is kept for debugging; it never grows past ``ring_capacity``.
+Every span is also a ``jax.profiler.TraceAnnotation`` named
+``fst.<name>``, so a profiler session shows the program's host spans on
+the clock of the device planes (plane ``/host:CPU``, line ``python``),
+with the keyword stats the caller gave (``seg=<ordinal>``). With no
+session open an annotation costs under a microsecond.
 """
 
 from __future__ import annotations
 
 import threading
 import time
-from collections import deque
-from typing import Dict, List, Tuple
+from typing import Dict
+
+from jax.profiler import TraceAnnotation
 
 
 class _NullSpan:
@@ -44,39 +48,42 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_st", "_name", "_t0", "_nested")
+    __slots__ = ("_st", "_name", "_t0", "_nested", "_ann")
 
-    def __init__(self, st: "StageTimes", name: str) -> None:
+    def __init__(self, st: "StageTimes", name: str, stats: Dict) -> None:
         self._st = st
         self._name = name
+        self._ann = TraceAnnotation("fst." + name, **stats)
 
     def __enter__(self):
         tls = self._st._tls
         depth = getattr(tls, "depth", 0)
         self._nested = depth > 0
         tls.depth = depth + 1
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dt = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
         self._st._tls.depth -= 1
         self._st.add(self._name, dt, nested=self._nested)
         return False
 
 
 class StageTimes:
-    """Thread-safe per-stage time accumulator + recent-span ring."""
+    """Thread-safe per-stage time accumulator."""
 
-    def __init__(self, ring_capacity: int = 512) -> None:
+    def __init__(self) -> None:
         self._lock = threading.Lock()
         self._totals: Dict[str, float] = {}
         self._counts: Dict[str, int] = {}
-        self._ring: deque = deque(maxlen=ring_capacity)
         self._tls = threading.local()
 
-    def span(self, name: str) -> _Span:
-        return _Span(self, name)
+    def span(self, name: str, **stats) -> _Span:
+        """``stats`` go to the profiler annotation only."""
+        return _Span(self, name, stats)
 
     def add(
         self,
@@ -92,15 +99,10 @@ class StageTimes:
         with self._lock:
             self._totals[key] = self._totals.get(key, 0.0) + seconds
             self._counts[key] = self._counts.get(key, 0) + count
-            self._ring.append((key, time.monotonic(), seconds))
 
     def total(self, name: str) -> float:
         with self._lock:
             return self._totals.get(name, 0.0)
-
-    def recent(self, n: int = 50) -> List[Tuple[str, float, float]]:
-        with self._lock:
-            return list(self._ring)[-n:]
 
     def snapshot(self) -> Dict[str, Dict[str, float]]:
         with self._lock:

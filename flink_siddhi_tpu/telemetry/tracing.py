@@ -5,11 +5,11 @@ reconstructed from per-leg percentiles (dispatch p99 + drain p99 is NOT
 an end-to-end p99 — tails don't add). This module measures the real
 thing the way Dapper does (Sigelman et al.; PAPERS.md): a deterministic
 1-in-N sample of *events* is stamped with a host ingest time at source
-pull, optionally marked at intermediate legs (route, dispatch, staged),
-and completed when a row carrying the event's timestamp surfaces to a
-collector/sink. Each completed trace records one sample into a
-``LatencyHistogram`` — so ``trace.e2e``'s p99 is a per-event
-ingest→emit quantile that *includes* reorder-buffer queue time, device
+pull and completed when a row carrying the event's timestamp surfaces
+to a collector/sink (the legs in between are measured for every event,
+per batch, by telemetry/legs.py). Each completed trace records one
+sample into a ``LatencyHistogram`` — so ``trace.e2e``'s p99 is a
+per-event ingest→emit quantile that *includes* reorder-buffer queue time, device
 backlog, drain staleness, and host decode (the queue-time-inclusive
 event-time latency Karimov et al. argue is the only number a user
 experiences).
@@ -45,14 +45,12 @@ import numpy as np
 from .histogram import LatencyHistogram
 from .registry import MetricsRegistry
 
-_EMPTY_TS = np.zeros(0, dtype=np.int64)
-
 
 class TraceSampler:
     """Deterministic 1-in-N per-event trace sampler for one Job.
 
     All mutators are called from the run-loop thread (stamp at source
-    pull, mark at route/dispatch, complete at row emission); the lock
+    pull, complete at row emission); the lock
     exists so an off-thread metrics/REST reader can ``snapshot()``
     concurrently.
     """
@@ -120,47 +118,6 @@ class TraceSampler:
                 self._order = deque(
                     k for k in self._order if k in self._pending
                 )
-
-    # -- intermediate legs -------------------------------------------------
-    def sampled_subset(self, timestamps) -> np.ndarray:
-        """The sampled events of a batch, as a (usually tiny) array —
-        compute the vectorized sampling mask ONCE per batch and feed
-        the result to several :meth:`mark` calls (the fused streaming
-        path marks each batch at staging AND at dispatch; recomputing
-        a full-batch mod per mark was measurable on the hot loop)."""
-        if not self.enabled:
-            return _EMPTY_TS
-        ts = np.asarray(timestamps)
-        if ts.size == 0:
-            return _EMPTY_TS
-        return ts[self._mask(ts)]
-
-    def mark(self, timestamps, leg: str, presampled: bool = False) -> None:
-        """Record (now - ingest) for sampled pending events into the
-        ``trace.ingest_to_<leg>`` histogram. The stamp stays pending —
-        only a row emission completes a trace. ``presampled=True``:
-        ``timestamps`` is already a :meth:`sampled_subset` result (the
-        sampling mask is skipped)."""
-        if not self.enabled:
-            return
-        ts = np.asarray(timestamps)
-        if ts.size == 0:
-            return
-        hits = ts if presampled else ts[self._mask(ts)]
-        if hits.size == 0:
-            return
-        now = time.monotonic()
-        deltas: List[float] = []
-        with self._lock:
-            if not self._pending:
-                return
-            for t in np.unique(hits).tolist():
-                t0 = self._pending.get(int(t))
-                if t0 is not None:
-                    deltas.append(now - t0)
-        if deltas:
-            h = self.registry.histogram(f"trace.ingest_to_{leg}")
-            h.record_many_seconds(deltas)
 
     # -- completion --------------------------------------------------------
     def complete_rows(

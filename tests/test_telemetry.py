@@ -16,6 +16,7 @@ from flink_siddhi_tpu.telemetry import (
     TOP_LEVEL_STAGES,
     TraceSampler,
 )
+from flink_siddhi_tpu.telemetry.legs import SegmentRecord, record_legs
 
 
 # -- histogram percentile correctness ------------------------------------
@@ -177,11 +178,46 @@ def test_disabled_registry_is_inert():
     assert snap["counters"].get("c", 0) == 0
 
 
-def test_stage_ring_is_bounded():
-    st = StageTimes(ring_capacity=8)
+def test_stage_times_hold_totals_only():
+    """No per-span state: a hundred spans leave one total and one
+    count (a span's keyword stats go to the profiler annotation)."""
+    st = StageTimes()
     for i in range(100):
         st.add("s", 0.001)
-    assert len(st.recent(1000)) == 8
+    with st.span("s", seg=7):
+        pass
+    snap = st.snapshot()
+    assert list(snap) == ["s"] and snap["s"]["count"] == 101
+    assert snap["s"]["seconds"] == pytest.approx(0.1, abs=0.01)
+
+
+def test_histogram_of_a_window_from_two_snapshots():
+    """snapshot() carries the non-zero buckets; the difference of two
+    snapshots rebuilds the histogram of what was recorded in between,
+    and its percentiles are those samples' (the earlier ones, far
+    larger here, do not show)."""
+    rng = np.random.default_rng(3)
+    h = LatencyHistogram()
+    h.record_many(rng.integers(400_000, 900_000, 5_000))
+    before = h.snapshot()
+    window = rng.lognormal(8, 1, 20_000).astype(np.int64)
+    h.record_many(window[:10_000])
+    h.record_many(window[10_000:], np.full(10_000, 3))  # weighted
+    after = h.snapshot()
+    json.dumps(after)
+    assert sum(c for _i, c in after["buckets"]) == after["count"]
+    w = LatencyHistogram.from_snapshots(after, before)
+    assert w.count == 10_000 + 3 * 10_000
+    want = np.concatenate([window[:10_000], np.repeat(window[10_000:], 3)])
+    for q in (50, 95, 99):
+        assert w.percentile(q) == pytest.approx(
+            float(np.percentile(want, q)), rel=0.02, abs=2.0
+        )
+    assert w.sum == pytest.approx(int(want.sum()), rel=0.01)
+    # since it was made: no earlier snapshot
+    assert LatencyHistogram.from_snapshots(before).count == 5_000
+    with pytest.raises(ValueError):
+        LatencyHistogram.from_snapshots(before, after)
 
 
 # -- per-event trace sampling (telemetry/tracing.py) ----------------------
@@ -230,8 +266,15 @@ def test_trace_completion_first_wins_and_marks_legs():
     ts = np.arange(0, 64, dtype=np.int64)
     tr.stamp_ingest(ts)
     assert tr.sampled == 16
-    tr.mark(ts, "dispatch")
-    assert reg.histogram("trace.ingest_to_dispatch").count == 16
+    # the legs between ingest and emit are every event's, per batch
+    # (telemetry/legs.py), not the sampler's
+    rec = SegmentRecord(1, [10.0], [10.001], [64], 10.002)
+    rec.complete = 10.005
+    record_legs(reg, [rec], requested=10.004, delivered=10.008)
+    assert reg.histogram("leg.device").count == 64
+    assert reg.histogram("leg.device").sum == 64 * 3_000
+    assert reg.histogram("leg.drain_wait").sum == 0  # requested first
+    assert reg.histogram("leg.total").sum == 64 * 8_000
     rows = [(int(t), ()) for t in ts]
     tr.complete_rows(0, rows)
     assert tr.completed == 16
@@ -279,13 +322,17 @@ def test_trace_disabled_is_inert():
     tr = TraceSampler(MetricsRegistry(), sample_every=0)
     assert not tr.enabled
     tr.stamp_ingest(np.arange(100, dtype=np.int64))
-    tr.mark(np.arange(100, dtype=np.int64), "dispatch")
     tr.complete_rows(0, [(0, ())])
     assert tr.sampled == 0 and tr.completed == 0
-    # and when the whole registry is off, sampling is off too
+    # and when the whole registry is off, sampling is off too, and a
+    # closed segment record leaves no leg histogram
     reg = MetricsRegistry(enabled=False)
     tr2 = TraceSampler(reg, sample_every=1)
     assert not tr2.enabled
+    rec = SegmentRecord(1, [10.0], [10.001], [64], 10.002)
+    rec.complete = 10.005
+    record_legs(reg, [rec], requested=10.004, delivered=10.008)
+    assert reg.get_histogram("leg.total") is None
 
 
 def test_trace_sampling_overhead_within_noise():
