@@ -28,6 +28,7 @@ import logging
 import time
 
 from ..compiler import pallas_ops
+from ..compiler.output import ColumnBatch
 from ..compiler.plan import CompiledPlan
 from ..runtime.executor import Job, _PlanRuntime, _staging_allow
 from ..runtime.tape import build_tape, bucket_size
@@ -300,13 +301,18 @@ class ShardedJob(Job):
         )
 
     def prewarm_drains(self, widths=None) -> None:
-        # the packed-drain programs are a single-device optimization;
-        # sharded drains read per-shard meta/slices directly
+        # no-op: Job's packed-drain programs (jit_pack, one per fetch
+        # width) serve its fetch thread; a sharded drain still slices
+        # the stacked accumulator directly, on the run loop
         return
 
     def drain_outputs(self, wait: bool = True) -> None:
-        # sharded drains stay synchronous for now (the wait=False fast
-        # path is a single-device pipeline optimization)
+        # still synchronous, on the run loop: request, two blocking
+        # fetches, decode, merge and emit before the next cycle (Job's
+        # wait=False fetch thread and ticketed readiness are not wired
+        # here). What IS shared with Job is the host side after the
+        # fetch: drain_decode's columnar lane, ColumnBatch and
+        # _emit_columns (see _drain_plan_body)
         for rt in self._plans.values():
             self._drain_plan(rt)
 
@@ -337,7 +343,16 @@ class ShardedJob(Job):
         t_req = time.monotonic()
         # the drain's legs, each a profiler annotation and a histogram:
         # drain.fetch (request -> both fetches done), drain.decode (the
-        # per-shard decodes, summed), drain.emit (merge, emit, sinks)
+        # per-shard decodes, summed), drain.emit (merge, emit, sinks).
+        # The lane is picked per stream from the sinks, by Job's own
+        # rule: a stream in ``columnar`` (retention off, every sink has
+        # accept_columns, no snapshot limiter) decodes to one
+        # ColumnBatch a shard, merges with one argsort and leaves
+        # through _emit_columns — no Python row exists between the
+        # chips and the sink. Every other stream takes the row lane
+        # below (decode to tuples, heapq.merge, _emit_rows), which is
+        # also the oracle the tests hold the columnar lane to.
+        columnar = self._columnar_streams(rt)
         with tel.annotate("fst.drain.fetch"):
             meta = np.asarray(rt.acc["meta"])  # (shards, 2, A) — one fetch
             counts, overflow = meta[:, 0], meta[:, 1]
@@ -378,35 +393,45 @@ class ShardedJob(Job):
         # (merged by metrics() — the same cross-shard fold as the decode
         # hists). Rate-limited streams are excluded: their rows may be
         # thinned at emission, and a thinned row must not stop the
-        # clock — those complete post-limiter in _emit_rows instead
-        # (into the base trace.e2e, without per-shard attribution).
+        # clock — those complete post-limiter in _emit_rows or
+        # _emit_columns instead (into the base trace.e2e, without
+        # per-shard attribution).
         shard_trace = getattr(rt, "_shard_trace_hists", None)
         if shard_trace is None and self.tracer.enabled:
             shard_trace = rt._shard_trace_hists = [
                 LatencyHistogram() for _ in range(self.n_shards)
             ]
-        # merge each output's per-shard (already time-ordered) rows by
-        # timestamp so sinks observe near-monotonic time across shards
+        # merge each output's per-shard (already time-ordered) payloads
+        # by timestamp so sinks observe near-monotonic time across shards
         per_schema = {}
         decode_s = 0.0
+        epoch = self._epoch_ms or 0
         for s in range(self.n_shards):
             with tel.annotate("fst.drain.decode", shard=s):
                 t0 = time.perf_counter()
-                decoded = rt.plan.drain_decode(counts[s], data[s])
+                decoded = rt.plan.drain_decode(
+                    counts[s], data[s], columnar_streams=columnar
+                )
                 dt = time.perf_counter() - t0
             decode_s += dt
             if shard_hists is not None:
                 shard_hists[s].record_seconds(dt)
             for a in rt.plan.artifacts:
-                for schema, rows in decoded.get(a.name) or []:
+                # a payload is a ColumnBatch (columnar lane) or a list
+                # of (ts, row) pairs; len() counts rows of either
+                for schema, payload in decoded.get(a.name) or []:
                     if (
                         shard_trace is not None
                         and schema.stream_id not in self._rate_limiters
                     ):
-                        self.tracer.complete_rows(
-                            self._epoch_ms or 0, rows,
-                            hist=shard_trace[s],
-                        )
+                        if isinstance(payload, ColumnBatch):
+                            self.tracer.complete_ts(
+                                epoch, payload.ts, hist=shard_trace[s]
+                            )
+                        else:
+                            self.tracer.complete_rows(
+                                epoch, payload, hist=shard_trace[s]
+                            )
                     if tel.enabled:
                         # pre-rate-limit match attribution, summed
                         # across shards (same scope the single-device
@@ -414,14 +439,35 @@ class ShardedJob(Job):
                         # view falls out of one registry)
                         sc = self._attr_scope(schema)
                         if sc is not None:
-                            sc.inc("matches", len(rows))
+                            sc.inc("matches", len(payload))
                     per_schema.setdefault(
                         schema.stream_id, (schema, [])
-                    )[1].append(rows)
+                    )[1].append(payload)
         tel.record_seconds("drain.decode", decode_s)
         t_emit = time.monotonic()
+        n_rows = n_columnar = 0  # handed to the emit tails, pre-limiter
         with tel.annotate("fst.drain.emit"):
-            for schema, shard_rows in per_schema.values():
+            for schema, parts in per_schema.values():
+                n = sum(len(p) for p in parts)
+                n_rows += n
+                if all(isinstance(p, ColumnBatch) for p in parts):
+                    # heapq.merge's order exactly, ties included (equal
+                    # timestamps: the lower shard first). Traces of an
+                    # unlimited stream completed per shard above, so
+                    # _emit_columns' own completion finds none pending;
+                    # a rate-limited one completes there, post-limiter
+                    n_columnar += n
+                    self._emit_columns(
+                        schema, ColumnBatch.merge_by_ts(parts)
+                    )
+                    continue
+                # a stream that decoded rows anywhere (a stacked group
+                # writes rows into a stream a plain artifact writes
+                # columns into) stays whole on the row lane
+                shard_rows = [
+                    p.rows() if isinstance(p, ColumnBatch) else p
+                    for p in parts
+                ]
                 if self._sinks.get(schema.stream_id):
                     # sinks observe emission order: merge shards by
                     # timestamp
@@ -453,6 +499,10 @@ class ShardedJob(Job):
                 stale = now - t_dirty
                 tel.record_seconds("drain.staleness", stale)
             tel.inc("drains.completed")
+            # how often the columnar lane engages: rows this drain
+            # handed to _emit_columns over all rows it handed on
+            tel.inc("drain.rows", n_rows)
+            tel.inc("drain.rows_columnar", n_columnar)
             self._scoped_drain_record(rt, now - t_req, stale)
 
     def flush(self) -> None:

@@ -6,8 +6,13 @@ The per-row ``decode_buffered``/``decode_packed_block`` path is the
 compatibility ORACLE (ISSUE 5): every columnar product must carry
 identical values, order, and counts. The parametrized job-level test
 covers all three device emission layouts (aligned select, buffered
-pattern, packed lazy-chain ordinals) plus a rate-limited stream.
+pattern, packed lazy-chain ordinals) plus a rate-limited stream; its
+sharded twin holds ShardedJob's columnar lane (ISSUE 25) to its row
+lane across a 4-shard CPU mesh.
 """
+
+import functools
+import heapq
 
 import numpy as np
 import pytest
@@ -348,6 +353,123 @@ def test_columnar_and_row_sinks_observe_identical_data(case):
 
     assert col_fast.rows, case  # the query actually emitted
     assert col_fast.rows == col_mixed.rows == row_rows, case
+
+
+# -- ShardedJob: the same lane across shards --------------------------------
+
+SHARDED_CASES = {
+    # (a) keyed group-by: every group's state on one shard, one row out
+    # per event, merged across shards by timestamp
+    "keyed_group_by": dict(
+        cql="from s select id, sum(price) as total, count() as cnt "
+        "group by id insert into out",
+    ),
+    # (b) four events share each timestamp (no timestamp spans two
+    # batches) and land on different shards: ties keep shard order, as
+    # heapq.merge gives
+    "equal_timestamps": dict(
+        cql="from s select id, sum(price) as total, count() as cnt "
+        "group by id insert into out",
+        ts_div=4,
+    ),
+    # (c) events-mode limiter: feed_columns thins the merged batch
+    "rate_limited": dict(
+        cql="from s select id, sum(price) as total group by id "
+        "output last every 7 events insert into out",
+    ),
+}
+
+
+@functools.lru_cache(maxsize=None)  # the columnar-only run, once a case
+def _run_sharded(case, columnar_sink, row_sink):
+    from flink_siddhi_tpu.parallel import ShardedJob, make_cep_mesh
+
+    spec = SHARDED_CASES[case]
+    schema = _schema()
+    plan = compile_plan(spec["cql"], {"s": schema})
+    batches = _make_batches(schema, n_ids=16)
+    div = spec.get("ts_div")
+    if div:
+        for b in batches:
+            b.timestamps[:] = 1_000 + (b.timestamps - 1_000) // div
+    job = ShardedJob(
+        [plan],
+        [BatchSource("s", schema, iter(batches))],
+        mesh=make_cep_mesh(4),
+        batch_size=1000,
+        retain_results=False,
+    )
+    job.drain_every_cycles = 1  # a drain a batch: chunks span drains
+    # ShardedJob.add_plan does not register the plan's output rates
+    # (Job.add_plan does; ROADMAP queue 1 item 11): install them as Job
+    # would, so the drain's limiter paths run on both lanes
+    for sid, rate in plan.output_rates.items():
+        job._rate_limiters[sid] = _OutputRateLimiter(
+            rate, plan.snapshot_keys.get(sid, ())
+        )
+    col_sink = _Recorder(plan.output_streams()["out"][0].field_names)
+    row_rows = []
+    if columnar_sink:
+        job.add_sink("out", col_sink)
+    if row_sink:
+        job.add_sink(
+            "out", lambda ts, row: row_rows.append((ts, tuple(row)))
+        )
+    job.run()
+    m = job.metrics()["telemetry"]
+    routed = m["gauges"]["route.cumulative_per_shard"][plan.plan_id]
+    assert sum(1 for r in routed if r) >= 3, routed  # really sharded
+    c = m["counters"]
+    assert c["drains.completed"] >= 4
+    return col_sink.rows, row_rows, (
+        c.get("drain.rows_columnar", 0), c.get("drain.rows", 0),
+        m["trace"]["completed"],
+    )
+
+
+@pytest.mark.parametrize("sinks", ["row_sink", "mixed_sinks"])
+@pytest.mark.parametrize("case", sorted(SHARDED_CASES))
+def test_sharded_columnar_and_row_lanes_deliver_identical_data(case, sinks):
+    """ShardedJob's drain takes the columnar lane for exactly the
+    streams Job would (ISSUE 25): the same timestamps, columns and order
+    as the row lane (decode to tuples, heapq.merge, _emit_rows), which
+    any non-columnar consumer keeps the stream on — (d), and
+    ``drain.rows_columnar`` says which lane ran — (e)."""
+    fast, _, (fast_col, fast_all, traced) = _run_sharded(case, True, False)
+    assert fast, case  # the query actually emitted
+    # (e) every row of the columnar-only job left through _emit_columns
+    assert fast_col == fast_all > 0
+    assert fast_all == 4000  # one row per event, counted pre-limiter
+    assert len(fast) == (4000 if case != "rate_limited" else 4000 // 7)
+    ts = [t for t, _ in fast]
+    assert ts == sorted(ts)
+    if case == "equal_timestamps":
+        assert len(set(ts)) < len(ts) / 2
+
+    mixed, rows, (slow_col, slow_all, slow_traced) = _run_sharded(
+        case, sinks == "mixed_sinks", True
+    )
+    # (d) a callable sink anywhere on the stream: the row lane, whole
+    assert slow_col == 0 and slow_all == fast_all
+    # sampled events' traces complete the same on either lane
+    assert traced == slow_traced
+    assert (traced > 0) == (case != "rate_limited")  # thinned: no stop
+    assert rows == fast, case
+    if sinks == "mixed_sinks":
+        assert mixed == fast, case
+
+
+def test_merge_by_ts_is_heapq_merge_ties_included():
+    rng = np.random.default_rng(7)
+    parts, row_parts = [], []
+    for s in range(4):
+        ts = np.sort(rng.integers(0, 40, 100 + s)).astype(np.int64)
+        v = np.arange(ts.size, dtype=np.int64) + 1000 * s
+        parts.append(ColumnBatch(ts, {"v": v}))
+        row_parts.append(parts[-1].rows())
+    want = list(heapq.merge(*row_parts, key=lambda p: p[0]))
+    assert ColumnBatch.merge_by_ts(parts).rows() == want
+    assert ColumnBatch.merge_by_ts(parts[:1]) is parts[0]
 
 
 def test_columnar_lane_requires_all_columnar_consumers():
