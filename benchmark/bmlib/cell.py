@@ -17,7 +17,7 @@ import time
 import numpy as np
 
 from . import compare, estimate
-from .data import STREAM, CyclingSource, PacedSource, Pool, make_schema
+from .data import CyclingSource, PacedSource, make_schema, stream_name
 from .sink import DeliverySink, SampleRanges
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -47,6 +47,13 @@ def load_cell(workload: str, overrides=None, root: str = HERE):
     return cell, cfg, params
 
 
+def make_pool(cfg, seed, n, root: str = HERE):
+    """The configuration's stream: ``n`` events' worth of draws from
+    ``seed``, by the generator its file names."""
+    return load_module("generators", cfg["generator"], root).make_pool(
+        seed, n, cfg)
+
+
 class _DropCounter(logging.Handler):
     """The program reports dropped emissions only as a WARNING; count
     them (``%d emissions dropped``: the number is the record's)."""
@@ -67,7 +74,7 @@ def build_job(cfg, params, source, sink):
     from flink_siddhi_tpu.runtime.executor import Job
 
     plan = compile_plan(
-        cfg["cql"], {STREAM: source.schema}, plan_id=cfg["name"],
+        cfg["cql"], {source.stream_id: source.schema}, plan_id=cfg["name"],
         config=EngineConfig(**cfg["engine_config"]),
     )
     kw = dict(
@@ -109,8 +116,8 @@ def _snapshot(job):
 
 
 def _decide_correct(cfg, pool, sink, n_window, root, control, say):
-    """What the window delivered against the plain reference; prints
-    each number compared beside its limit."""
+    """What the window delivered against the plain reference: (correct,
+    each number compared beside its limit), which it also prints."""
     t = time.perf_counter()
     reference = load_module("configs", cfg["name"], root)
     lo, hi = sink.hi[0], sink.hi[n_window - 1]
@@ -122,18 +129,19 @@ def _decide_correct(cfg, pool, sink, n_window, root, control, say):
     )
     # every row due between the window's first and last delivery arrived
     numbers["window_rows_lost_or_extra"] = abs(
-        sum(sink.rows[1:n_window])
+        sink.rows_between(n_window)
         - compare.rows_due(reference, pool, cfg, lo + 1, hi + 1)
     )
     numbers["undecodable_columns"] = sink.none_columns
     lim = compare.limits(cfg["compare"])
     lim.update(deliveries_out_of_order=0, window_rows_lost_or_extra=0,
                undecodable_columns=0)
-    say("[bench] compared " + json.dumps({
+    compared = {
         "ranges": n_ranges, "rows": n_rows,
         "numbers": {k: [numbers[k], lim[k]] for k in lim},
-        "reference_s": time.perf_counter() - t,
-    }))
+    }
+    say("[bench] compared " + json.dumps(
+        {**compared, "reference_s": time.perf_counter() - t}))
     if control:
         low, _, _ = compare.check_samples(
             sink, reference, pool, cfg["compare"], lo, hi,
@@ -143,7 +151,8 @@ def _decide_correct(cfg, pool, sink, n_window, root, control, say):
             "numbers": {k: [low[k], lim[k]] for k in low},
             "correct": all(low[k] <= lim[k] for k in low),
         }))
-    return n_ranges > 0 and all(numbers[k] <= lim[k] for k in lim)
+    correct = n_ranges > 0 and all(numbers[k] <= lim[k] for k in lim)
+    return correct, compared
 
 
 def _end_to_end(names, params, source, sink, n_window, t_open, batch,
@@ -210,17 +219,18 @@ def run_cell(workload, seed, seconds, trace, *, t_start=None, overrides=None,
         params.get("pool_events") or params["pool_batches"] * batch
     )
     t = time.perf_counter()
-    pool = Pool(seed, pool_events, cfg["n_ids"])
-    schema = make_schema()
+    pool = make_pool(cfg, seed, pool_events, root)
+    schema, stream = make_schema(cfg), stream_name(cfg)
     if live:
-        source = PacedSource(pool, schema, batch, params["rate_events_per_s"],
+        source = PacedSource(pool, schema, stream, batch,
+                             params["rate_events_per_s"],
                              params["max_release"])
     else:
-        source = CyclingSource(pool, schema, batch)
+        source = CyclingSource(pool, schema, stream, batch)
     length = max(int(params["sample_length_per_batch"] * batch), 8)
     ranges = SampleRanges(seed, pool.n, batch, length,
                           params["sample_ranges"])
-    sink = DeliverySink(cfg["index_col"], ranges, keep_index=live)
+    sink = DeliverySink(cfg["index_col"], ranges, pool, keep_index=live)
     if trace:
         # the harness's own spans, around its calls into the job
         source.poll = _annotated(source.poll, "bench.poll")
@@ -303,7 +313,8 @@ def run_cell(workload, seed, seconds, trace, *, t_start=None, overrides=None,
         job.run_cycle()
     job.flush()
     logging.getLogger("flink_siddhi_tpu").removeHandler(drops)
-    correct = _decide_correct(cfg, pool, sink, n_window, root, control, say)
+    correct, compared = _decide_correct(
+        cfg, pool, sink, n_window, root, control, say)
     failed = (
         int(getattr(job, "shed_events", 0)) + drops.dropped + _overflow(job)
     )
@@ -346,4 +357,5 @@ def run_cell(workload, seed, seconds, trace, *, t_start=None, overrides=None,
             device["busy_s"] = traced["busy_s"]
             device["window_s"] = traced["window_s"]
             result["breakdown"] = traced["breakdown"]
+    result["compared"] = compared  # last in the line
     return result

@@ -1,15 +1,15 @@
-"""Seeded event pool and the two sources (closed loop, open loop).
+"""The stream's schema and the two sources (closed loop, open loop).
 
-Copied in shape from ``chip_smoke.py`` (``make_schema``, ``make_columns``)
-and ``bench.py`` (``_CyclingSource``, ``_PacedSource``) so that the
-program's own copies can change without moving the yardstick.
+Copied in shape from ``chip_smoke.py`` (``make_schema``) and ``bench.py``
+(``_CyclingSource``, ``_PacedSource``) so that the program's own copies
+can change without moving the yardstick.
 
-The stream is (id int, name string, price double, timestamp long): ids
-uniform over ``n_ids``, one constant name, price uniform in [0, 100),
-event time 1 ms apart and in order. Event ``i`` of the stream (counted
-from 0) is row ``i % pool_events`` of the pool with timestamp
-``TS0 + i``: an event's timestamp is its index, which is how the sink
-reads "results complete through event i".
+The stream is the configuration's: its ``fields`` are the schema,
+``stream`` its name, and ``generator`` names the file under
+``generators/`` whose pool makes the events from ``--seed`` and keeps
+the event clock (the contract is ``generators/__init__.py``'s
+docstring). The sources cut that pool's stream into batches; they know
+events by their index, never by their timestamp.
 """
 
 from __future__ import annotations
@@ -18,8 +18,6 @@ import time
 
 import numpy as np
 
-STREAM = "inputStream"
-TS0 = 1_000
 # A job cannot be fed for ever. Lazy projection numbers events in int32
 # and resets that space at 2**30 events with a synchronous drain, after
 # which rows still in flight can decode as None (seen on the chip in 3 of
@@ -29,78 +27,43 @@ TS0 = 1_000
 EVENT_HORIZON = (1 << 30) - (1 << 22)
 
 
-def make_schema():
+def stream_name(cfg) -> str:
+    return cfg.get("stream", "inputStream")
+
+
+def make_schema(cfg):
     from flink_siddhi_tpu.schema.stream_schema import StreamSchema
     from flink_siddhi_tpu.schema.types import AttributeType
 
     return StreamSchema(
-        [
-            ("id", AttributeType.INT),
-            ("name", AttributeType.STRING),
-            ("price", AttributeType.DOUBLE),
-            ("timestamp", AttributeType.LONG),
-        ]
+        [(name, AttributeType(kind)) for name, kind in cfg["fields"]]
     )
-
-
-class Pool:
-    """``n`` events drawn from ``seed`` (ids and prices both), addressable
-    by global event index. The pool is long enough (32 replay batches)
-    that what a seed changes in a batch's mix of ids averages out over
-    one cycle (PERF.md, finding 1)."""
-
-    def __init__(self, seed: int, n: int, n_ids: int) -> None:
-        rng = np.random.default_rng(seed)
-        self.n = n
-        self.n_ids = n_ids
-        self.id = rng.integers(0, n_ids, size=n).astype(np.int32)
-        self.price = rng.random(n, dtype=np.float64) * 100.0
-
-    def columns(self, lo: int, hi: int):
-        """(id, price, timestamp) of events lo <= i < hi of the stream."""
-        idx = np.arange(lo, hi, dtype=np.int64)
-        rows = idx % self.n
-        return self.id[rows], self.price[rows], idx + TS0
 
 
 class _PoolSource:
     """Shared part: cuts the pool into batches and stamps them."""
 
-    def __init__(self, pool: Pool, schema, batch: int) -> None:
+    def __init__(self, pool, schema, stream_id, batch: int) -> None:
         from flink_siddhi_tpu.schema.batch import EventBatch
 
         if pool.n % batch:
             raise ValueError(f"pool {pool.n} is not a multiple of {batch}")
-        self.stream_id = STREAM
+        self.stream_id = stream_id
         self.schema = schema
         self.batch = batch
         self.served = 0  # batches handed to the job
         self.stopped = False
         self.exhausted = False  # ran into EVENT_HORIZON
         self._EventBatch = EventBatch
-        self._n_pool = pool.n // batch
-        self._id = pool.id.reshape(self._n_pool, batch)
-        self._price = pool.price.reshape(self._n_pool, batch)
-        code = schema.string_tables["name"].intern("test_event")
-        self._name = np.full(batch, code, dtype=np.int32)
-        self._ts = TS0 + np.arange(batch, dtype=np.int64)
+        self._serve = pool.server(
+            batch, lambda field, s: schema.string_tables[field].intern(s)
+        )
 
     def _next(self):
         j = self.served
-        k = j % self._n_pool
-        ts = self._ts + j * self.batch  # the one vectorised shift
+        cols, ts = self._serve(j)
         self.served = j + 1
-        return self._EventBatch(
-            self.stream_id,
-            self.schema,
-            {
-                "id": self._id[k],
-                "name": self._name,
-                "price": self._price[k],
-                "timestamp": ts,
-            },
-            ts,
-        )
+        return self._EventBatch(self.stream_id, self.schema, cols, ts)
 
     def _room(self) -> bool:
         if (self.served + 1) * self.batch > EVENT_HORIZON:
@@ -132,8 +95,9 @@ class PacedSource(_PoolSource):
     up to ``max_release`` of them as one batch: a stall must not throttle
     the offered load to one batch per cycle (``bench.py:_PacedSource``)."""
 
-    def __init__(self, pool, schema, batch, rate, max_release=3) -> None:
-        super().__init__(pool, schema, batch)
+    def __init__(self, pool, schema, stream_id, batch, rate,
+                 max_release=3) -> None:
+        super().__init__(pool, schema, stream_id, batch)
         self.rate = float(rate)
         self.period = batch / self.rate
         self.max_release = max_release

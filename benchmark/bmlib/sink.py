@@ -6,6 +6,14 @@ every latency of the benchmark is read from this log and from nothing
 the job counts at dispatch. It also keeps the rows of seeded sample
 ranges of the stream, which the comparison checks against the reference
 once the window has closed.
+
+Rows carry timestamps; the stream's event clock (the generator's pool)
+turns them into event indices: ``index_of(ts)``, the last event stamped
+``ts`` or earlier. Where several events share a timestamp (a tick), the
+rest of a delivery's newest tick may still be to come, so the delivery
+counts as complete only through the last event of the tick before; a
+tick that holds one event is complete with its row. "Complete through
+event i" never overstates.
 """
 
 from __future__ import annotations
@@ -14,24 +22,26 @@ import time
 
 import numpy as np
 
-from .data import TS0
-
 
 class DeliverySink:
-    def __init__(self, index_col, ranges, keep_index=False) -> None:
+    def __init__(self, index_col, ranges, clock, keep_index=False) -> None:
         """``index_col``: the row column holding the timestamp of the
         last event that contributes to the row (None: the row's own
-        ts). ``ranges``: a ``SampleRanges``. ``keep_index``: keep every
-        delivery's event indices (the live cell's latency samples)."""
+        ts). ``ranges``: a ``SampleRanges``. ``clock``: the pool, for
+        its ``index_of``. ``keep_index``: keep every delivery's event
+        indices (the live cell's latency samples)."""
         self.index_col = index_col
         self.ranges = ranges
+        self.index_of = clock.index_of
         self.keep_index = keep_index
         self.recording = False
         self.deliveries = 0  # since the job started, recording or not
         self.t = []  # perf_counter at each recorded delivery
-        self.hi = []  # newest event index in it
+        self.hi = []  # the event index it is complete through
         self.lo = []  # oldest event index in it
+        self.top = []  # newest event index in it (>= hi: an open tick)
         self.rows = []  # its row count
+        self.tail = []  # its rows beyond hi (those of the open tick)
         self.index = []  # its event indices (keep_index)
         self.pieces = {}  # range start -> [{column: rows in range}]
         self.none_columns = 0  # deliveries carrying an undecodable column
@@ -48,19 +58,28 @@ class DeliverySink:
             except TypeError:  # a None in it: an undecodable row
                 self.none_columns += 1
                 return
-        idx = idx - TS0
+        newest = idx[-1]
+        idx = self.index_of(idx)
         lo, hi = int(idx[0]), int(idx[-1])
+        lo, hi = min(lo, hi), max(lo, hi)
+        before = int(self.index_of(newest - 1))  # the tick before ends here
+        shared = hi - before > 1  # the newest tick holds several events
         self.t.append(t)
-        self.lo.append(min(lo, hi))
-        self.hi.append(max(lo, hi))
+        self.lo.append(lo)
+        self.hi.append(before if shared else hi)
+        self.top.append(hi)
         self.rows.append(len(idx))
+        self.tail.append(
+            len(idx) - int(np.searchsorted(idx, before, side="right"))
+            if shared else 0
+        )
         if self.keep_index:
             self.index.append(idx)
         for a, b in self.ranges.overlapping(lo, hi):
             i, j = np.searchsorted(idx, (a, b))
             if i == j:
                 continue
-            piece = {"@idx": idx[i:j].copy(), "@ts": ts[i:j] - TS0}
+            piece = {"@idx": idx[i:j].copy(), "@ts": ts[i:j].copy()}
             for k, v in cols.items():
                 v = v[i:j]
                 if v.dtype == object:
@@ -70,6 +89,14 @@ class DeliverySink:
                     v = v.astype(np.float64)
                 piece[k] = v.copy()
             self.pieces.setdefault(a, []).append(piece)
+
+    def rows_between(self, n) -> int:
+        """Rows with an index in ``(hi[0], hi[n - 1]]`` among the first
+        ``n`` deliveries, which came in order: all after the first but
+        for the first's open tick, less every row of the last's."""
+        top = self.top[n - 1]
+        beyond = sum(self.tail[k] for k in range(n) if self.top[k] == top)
+        return self.tail[0] + sum(self.rows[1:n]) - beyond
 
 
 class SampleRanges:

@@ -15,9 +15,11 @@ def _prefix(pool, precision):
     key = ("keyed_prefix", precision)
     cache = pool.__dict__.setdefault("_cache", {})
     if key not in cache:
-        order = np.argsort(pool.id, kind="stable")
-        sid = pool.id[order]
-        cs = np.cumsum(pool.price[order])
+        cols = pool.columns(0, pool.n, ("id", "price"))
+        ids, price = cols["id"], cols["price"]
+        order = np.argsort(ids, kind="stable")
+        sid = ids[order]
+        cs = np.cumsum(price[order])
         starts = np.flatnonzero(np.r_[True, sid[1:] != sid[:-1]])
         base = np.repeat(
             np.r_[0.0, cs[starts[1:] - 1]], np.diff(np.r_[starts, len(sid)])
@@ -28,12 +30,8 @@ def _prefix(pool, precision):
         cnt[order] = np.arange(pool.n) - np.repeat(
             starts, np.diff(np.r_[starts, len(sid)])
         ) + 1
-        cache[key] = (
-            run,
-            cnt,
-            np.bincount(pool.id, weights=pool.price, minlength=pool.n_ids),
-            np.bincount(pool.id, minlength=pool.n_ids),
-        )
+        cache[key] = (ids, run, cnt, np.bincount(ids, weights=price),
+                      np.bincount(ids))
     return cache[key]
 
 
@@ -43,13 +41,15 @@ def _bf16_running(pool, a, b):
     ascending order; events between two calls are folded in too."""
     from bmlib.compare import bf16_round
 
+    n_ids = len(_prefix(pool, "f64")[3])
     st = pool.__dict__.setdefault(
-        "_bf16_carry", {"upto": 0, "acc": np.zeros(pool.n_ids)}
+        "_bf16_carry", {"upto": 0, "acc": np.zeros(n_ids)}
     )
     if st["upto"] > a:
-        st.update(upto=0, acc=np.zeros(pool.n_ids))
+        st.update(upto=0, acc=np.zeros(n_ids))
     lo = st["upto"]
-    ids, price, _ = pool.columns(lo, b)
+    cols = pool.columns(lo, b, ("id", "price"))
+    ids, price = cols["id"], cols["price"]
     order = np.argsort(ids, kind="stable")
     sid = ids[order]
     starts = np.flatnonzero(np.r_[True, sid[1:] != sid[:-1]])
@@ -72,16 +72,16 @@ def _bf16_running(pool, a, b):
 
 
 def expected(pool, a, b, precision="f64"):
-    run, cnt, full_sum, full_cnt = _prefix(pool, "f64")
+    pool_ids, run, cnt, full_sum, full_cnt = _prefix(pool, "f64")
     idx = np.arange(a, b, dtype=np.int64)
     row, cyc = idx % pool.n, idx // pool.n
-    ids = pool.id[row]
+    ids = pool_ids[row]
     total = cyc * full_sum[ids] + run[row]
     if precision == "bf16":
         total = _bf16_running(pool, a, b)
     return {
         "@idx": idx,
-        "@ts": idx,
+        "@ts": pool.ts_of(idx),
         "id": ids,
         "total": total,
         "cnt": cyc * full_cnt[ids] + cnt[row],

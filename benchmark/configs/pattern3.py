@@ -20,7 +20,8 @@ def expected(pool, a, b, precision="f64"):
     from bmlib.compare import bf16_round
 
     lo = max(a - WITHIN_MS - 1, 0)  # an older first event has expired
-    ids, price, ts = pool.columns(lo, b)
+    cols = pool.columns(lo, b, ("id", "price", "timestamp"))
+    ids, price, ts = cols["id"], cols["price"], cols["timestamp"]
     n = len(ids)
     pos = np.arange(n)
 
@@ -40,7 +41,7 @@ def expected(pool, a, b, precision="f64"):
         out_price = bf16_round(out_price)
     return {
         "@idx": p3 + lo,
-        "@ts": p3 + lo,
+        "@ts": ts[p3],
         "t1": ts[p1],
         "t3": ts[p3],
         "price": out_price,

@@ -15,7 +15,8 @@ def expected(pool, a, b, precision="f64"):
     from bmlib.compare import bf16_round
 
     lo = max(a - (LENGTH - 1), 0)
-    ids, price, _ts = pool.columns(lo, b)
+    cols = pool.columns(lo, b, ("id", "price", "timestamp"))
+    ids, price = cols["id"], cols["price"]
     if precision == "bf16":
         price = bf16_round(price)
     n = len(ids)
@@ -37,7 +38,7 @@ def expected(pool, a, b, precision="f64"):
     idx = np.arange(a, b, dtype=np.int64)
     return {
         "@idx": idx,
-        "@ts": idx,
+        "@ts": cols["timestamp"][s:],
         "id": ids[s:],
         "total": total[s:],
         "cnt": cnt[s:],

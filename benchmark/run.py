@@ -7,9 +7,10 @@ JAX finds no TPU or fewer chips than the cell asks for, and fails (also
 with nothing on standard output) where the program is not beside it.
 Otherwise it loads, warms, measures and prints, as the last line of
 standard output, one JSON object: ``correct``, ``attempted``, ``failed``,
-``metrics``, ``device`` and, traced, ``breakdown``. With ``--trace 0``
-the metrics are the cell's end-to-end metrics, with ``--trace 1`` its
-per-layer metrics. Earlier lines (``[bench] ...``) carry what a reader
+``metrics``, ``device``, traced ``breakdown``, and last ``compared``: each
+number compared beside its limit, which is also the last line of
+standard error. With ``--trace 0`` the metrics are the cell's end-to-end
+metrics, with ``--trace 1`` its per-layer metrics. Earlier lines (``[bench] ...``) carry what a reader
 wants beside them: each number compared with its limit, the slices'
 median and quartiles, every slice, the split of set-up.
 
@@ -77,6 +78,8 @@ def main(argv=None, ap=None, **trial) -> int:
         t_start=T_START, **trial,
     )
     sys.stdout.flush()
+    print("[bench] compared " + json.dumps(result["compared"]),
+          file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -36,6 +36,6 @@ TINY = {
     "warm_events_min": 0,
     "slice_seconds": 0.25,
     "trace_lead_seconds": 0.2,
-    "trace_seconds": 0.5,
+    "trace_seconds": 1.6,
 }
 CELLS = ("window1k.replay", "pattern3.live", "keyed1k_x4.replay")

@@ -12,12 +12,13 @@ from conftest import CELLS, REPO, TINY
 
 from bmlib.cell import run_cell
 
-KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+KEYS = {"correct", "attempted", "failed", "metrics", "device",
+        "compared"}
 
 
 def _run(cell, trace=False, **kw):
     lines = []
-    out = run_cell(cell, 2_147_483_659, 1.5, trace, overrides=dict(TINY),
+    out = run_cell(cell, 2_147_483_659, 2.0, trace, overrides=dict(TINY),
                    say=lines.append, **kw)
     return out, lines
 
@@ -25,7 +26,7 @@ def _run(cell, trace=False, **kw):
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_runs_and_is_correct(cell):
     out, lines = _run(cell, control=True)
-    assert set(out) == KEYS
+    assert set(out) == KEYS and list(out)[-1] == "compared"
     json.dumps(out)
     assert out["correct"] is True and out["failed"] == 0
     assert out["attempted"] > 0

@@ -5,41 +5,37 @@ The benchmark never imports the interpreter; this test may."""
 import numpy as np
 import pytest
 
-from bmlib.cell import load_json, load_module
+from bmlib.cell import load_json, load_module, make_pool
 from bmlib.compare import bf16_round, compare_range
-from bmlib.data import TS0, Pool
-
-FIELDS = ["id", "name", "price", "timestamp"]
 
 
-def _interpreter_rows(cql, pool, n, names):
+def _interpreter_rows(cfg, pool, n, names):
     from flink_siddhi_tpu.baseline import BaselineEngine
 
-    ids, price, ts = pool.columns(0, n)
-    eng = BaselineEngine(cql, FIELDS)
+    cols = pool.columns(0, n)
+    eng = BaselineEngine(cfg["cql"], [name for name, _ in cfg["fields"]])
     out_ts, rows = [], []
     eng._emit = lambda _o, t, row: (out_ts.append(t), rows.append(row))
     eng.run_columns(
-        {"id": ids.tolist(), "name": ["test_event"] * n,
-         "price": price.tolist(), "timestamp": ts.tolist()},
-        ts.tolist(),
+        {k: v.tolist() for k, v in cols.items()},
+        cols["timestamp"].tolist(),
     )
-    table = {"@ts": np.asarray(out_ts, np.int64) - TS0}
+    table = {"@ts": np.asarray(out_ts, np.int64)}
     for name, col in zip(names, zip(*rows)):
         table[name] = np.asarray(col)
     return table
 
 
-def _running_rows(_cql, pool, n, _names):
+def _running_rows(_cfg, pool, n, _names):
     """The interpreter has no window-less group-by: a loop over events."""
-    ids, price, _ts = pool.columns(0, n)
+    cols = pool.columns(0, n, ("id", "price", "timestamp"))
     total, cnt, rows = {}, {}, []
-    for k, x in zip(ids.tolist(), price.tolist()):
+    for k, x in zip(cols["id"].tolist(), cols["price"].tolist()):
         total[k] = total.get(k, 0.0) + x
         cnt[k] = cnt.get(k, 0) + 1
         rows.append((k, total[k], cnt[k]))
     i, t, c = (np.asarray(col) for col in zip(*rows))
-    return {"@ts": np.arange(n), "id": i, "total": t, "cnt": c}
+    return {"@ts": cols["timestamp"], "id": i, "total": t, "cnt": c}
 
 
 @pytest.mark.parametrize("config, names, rows_of", [
@@ -51,8 +47,8 @@ def test_reference_equals_interpreter(config, names, rows_of):
     cfg = load_json("configs", config)
     ref = load_module("configs", config)
     n = 20_000
-    pool = Pool(11, 8_192, cfg["n_ids"])  # shorter than n: the pool cycles
-    want = rows_of(cfg["cql"], pool, n, names)
+    pool = make_pool(cfg, 11, 8_192)  # shorter than n: the pool cycles
+    want = rows_of(cfg, pool, n, names)
     got = ref.expected(pool, 0, n)
     assert len(got["@idx"]) == len(want["@ts"]) > 0
     for k in ("@ts",) + names:
@@ -73,7 +69,7 @@ def test_the_lower_precision_control_fails_the_limits(config):
     program's place comes out as not correct."""
     cfg = load_json("configs", config)
     ref = load_module("configs", config)
-    pool = Pool(12, 8_192, cfg["n_ids"])
+    pool = make_pool(cfg, 12, 8_192)
     want = ref.expected(pool, 2_000, 6_000)
     sound = compare_range(want, want, cfg["compare"])
     assert all(v == 0 for v in sound.values())
