@@ -29,6 +29,11 @@ PLAN_ZOO: Dict[str, str] = {
         "from S#window.timeBatch(2 sec) select sum(price) as s "
         "insert into out"
     ),
+    "hop_window_max": (
+        "from S[id != 0]#window.hop(timestamp, 10 sec, 2 sec) "
+        "select id, count() as num group by id "
+        "having num >= windowMax(num) insert into out"
+    ),
     "unique_window": (
         "from S#window.unique(id) select id, price insert into out"
     ),

@@ -319,10 +319,104 @@ class _LengthWindowGroupBy:
         emit(self.out, ts, tuple(row))
 
 
+class _HopWindowGroupBy:
+    """``#window.hop(ts, size, slide) select k, count() ... group by k
+    [having ... windowMax(x) ...]``: a dict of per-group counts per pane
+    of the slide. An event at or past a window's end closes it: a
+    group's count is the sum of its panes', ``windowMax`` is taken over
+    the window's groups, and the rows that pass ``having`` leave in
+    group-key order, stamped with the window's last millisecond
+    (compiler/hop_window.py states the semantics)."""
+
+    def __init__(self, q: ast.Query, win: ast.Window):
+        inp = q.input
+        self.filters = [_compile_scalar(f) for f in inp.filters]
+        ts_attr, size, slide = win.args
+        self.ts_name = ts_attr.name
+        self.slide = slide.ms
+        self.n_panes = size.ms // slide.ms
+        self.group_keys = [
+            k.split(".", 1)[-1] for k in q.selector.group_by
+        ]
+        # each select item: (alias, fn over the group's last event), or
+        # (alias, None) for count()
+        self.items = []
+        for it in q.selector.items:
+            e = it.expr
+            is_count = isinstance(e, ast.Call) and e.name == "count"
+            self.items.append(
+                (it.output_name(), None if is_count else _compile_scalar(e))
+            )
+        self.window_maxes = []  # (slot, fn over a row's env)
+        self.having = None
+        if q.selector.having is not None:
+            self.having = _compile_scalar(
+                self._lift(q.selector.having)
+            )
+        self.out = q.output_stream
+        self.panes: Dict[int, Dict[Any, list]] = {}  # key -> [event, count]
+        self.cur: Optional[int] = None
+
+    def _lift(self, e):
+        if isinstance(e, ast.Call) and e.name.lower() == "windowmax":
+            slot = f"@wmax{len(self.window_maxes)}"
+            self.window_maxes.append((slot, _compile_scalar(e.args[0])))
+            return ast.Attr(slot)
+        if isinstance(e, ast.Unary):
+            return ast.Unary(e.op, self._lift(e.operand))
+        if isinstance(e, ast.Binary):
+            return ast.Binary(e.op, self._lift(e.left), self._lift(e.right))
+        return e
+
+    def _close(self, q: int, emit) -> None:
+        """The window that ends where pane q starts."""
+        groups: Dict[Any, list] = {}
+        for p in range(q - self.n_panes, q):
+            for key, (ev, cnt) in self.panes.get(p, {}).items():
+                g = groups.setdefault(key, [ev, 0])
+                g[1] += cnt
+        self.panes.pop(q - self.n_panes, None)
+        rows = []
+        for key in sorted(groups):
+            ev, cnt = groups[key]
+            env = dict(ev)
+            for alias, fn in self.items:
+                env[alias] = cnt if fn is None else fn(ev)
+            rows.append(env)
+        for slot, fn in self.window_maxes:
+            best = max(fn(env) for env in rows) if rows else None
+            for env in rows:
+                env[slot] = best
+        for env in rows:
+            if self.having is None or self.having(env):
+                emit(self.out, q * self.slide - 1,
+                     tuple(env[alias] for alias, _f in self.items))
+
+    def on_event(self, ev, ts, emit):
+        for f in self.filters:
+            if not f(ev):
+                return
+        p = max(ev[self.ts_name] // self.slide,
+                self.cur if self.cur is not None else -(2 ** 62))
+        if self.cur is None:
+            self.cur = p
+        while self.cur < p:
+            if not self.panes:  # a gap in the stream: nothing to close
+                self.cur = p
+                break
+            self.cur += 1
+            self._close(self.cur, emit)
+        key = tuple(ev[k] for k in self.group_keys)
+        g = self.panes.setdefault(p, {}).setdefault(key, [ev, 0])
+        g[0] = ev
+        g[1] += 1
+
+
 class BaselineEngine:
     """Per-event interpreter for the benchmark CQL surface: stateless
     filters, every-chains with within, strict sequences (quantifiers +
-    absence), and sliding length-window group-by aggregation.
+    absence), sliding length-window group-by aggregation, and the hop
+    window with its per-window maximum.
     Multi-query plans fan each event through every query, one runtime
     per query (the reference's operator design)."""
 
@@ -340,9 +434,13 @@ class BaselineEngine:
             elif isinstance(inp, ast.StreamInput):
                 if inp.windows:
                     win = inp.windows[0]
+                    if win.name == "hop":
+                        self.handlers.append(_HopWindowGroupBy(q, win))
+                        continue
                     if win.name != "length":
                         raise SiddhiQLError(
-                            "baseline interpreter: only length windows"
+                            "baseline interpreter: only length and hop "
+                            "windows"
                         )
                     cap = win.args[0]
                     assert isinstance(cap, ast.Literal)

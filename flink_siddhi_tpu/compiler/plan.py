@@ -22,6 +22,7 @@ from ..query import ast, parse_plan
 from ..query.lexer import SiddhiQLError
 from ..query.planner import StreamPartition, infer_stream_partitions
 from ..schema.stream_schema import StreamSchema
+from ..schema.types import AttributeType
 from .config import DEFAULT_CONFIG, EngineConfig
 from ..extensions.registry import ExtensionRegistry, builtin_registry
 from ..runtime.tape import TapeSpec
@@ -76,6 +77,8 @@ class CompiledPlan:
     # this capacity instead of compiling one huge program (the ingest
     # batch size is unchanged; only the compiled window shrinks).
     tape_capacity_limit: Optional[int] = None
+    # times grow_state re-bucketed a state table (each a retrace)
+    grow_count: int = 0
 
     def signature(self, capacity: int = 128) -> str:
         """The shape-bucket class key (``analysis/admit.plan_signature``)
@@ -178,6 +181,10 @@ class CompiledPlan:
             grow = getattr(a, "grow_state", None)
             if grow is not None:
                 out[a.name] = grow(states[a.name])
+                if out[a.name] is not states[a.name] and (
+                    _leaf_shapes(out[a.name]) != _leaf_shapes(states[a.name])
+                ):
+                    self.grow_count += 1
         return out
 
     @property
@@ -442,13 +449,21 @@ class CompiledPlan:
         return by_stream
 
 
+# windows whose first argument is the attribute they read as time
+TIME_WINDOWS = ("hop", "externaltime", "externaltimebatch")
+
+
+def _leaf_shapes(state) -> List[Tuple]:
+    return [np.shape(x) for x in jax.tree.leaves(state)]
+
+
 # fst:hotpath device=out
 def _synthetic_tape(out, ci: ChainedInput):
     """Producer emissions -> the consumer's input Tape, inside the same
     jitted step. All three artifact output modes convert losslessly:
     buffered (n, ts, cols), aligned (mask, ts, cols), packed (n, block
     with bitcast i32 rows)."""
-    from ..runtime.tape import Tape
+    from ..runtime.tape import Tape, time_key
 
     if ci.mode == "aligned":
         mask, ts, cols = out
@@ -492,6 +507,11 @@ def _synthetic_tape(out, ci: ChainedInput):
         f"{ci.stream_id}.{f.name}": v
         for f, v in zip(ci.fields, col_vals)
     }
+    # a window over this stream reads a long as time from its time_key
+    # (on the input tape: a rebased copy; here: what the producer gave)
+    for f, v in zip(ci.fields, col_vals):
+        if f.atype == AttributeType.LONG:
+            cols_map[time_key(f"{ci.stream_id}.{f.name}")] = v
     return Tape(ts, stream, valid, cols_map)
 
 
@@ -720,10 +740,28 @@ def compile_plan(
         for hc in getattr(art, "host_columns", ())
     )
 
+    # long attributes that a window reads as time get a copy on the
+    # job's clock (runtime/tape.py time_key), which is not cut to 32
+    # bits; the raw column stays only where something reads its value
+    time_columns = tuple(sorted({
+        k
+        for art in artifacts
+        for k in getattr(art, "time_columns", ())
+        if k in column_types  # (not a chained stream's: _synthetic_tape)
+    }))
+    values_read = _referenced_field_names(parsed, time_args=False)
+    if values_read is not None:
+        columns = [
+            k for k in columns
+            if k not in time_columns
+            or k.split(".", 1)[1] in values_read
+        ]
+
     spec = TapeSpec(
         stream_codes, tuple(columns), column_types, tuple(encoded),
         device_columns=device_columns,
         host_preds=tuple(host_preds),
+        time_columns=time_columns,
     )
 
     partitions = infer_stream_partitions(parsed.queries)
@@ -1437,11 +1475,13 @@ def _rewrite_windowed_mutations(parsed, table_schemas):
     return dataclasses.replace(parsed, queries=tuple(out))
 
 
-def _referenced_field_names(parsed):
+def _referenced_field_names(parsed, time_args: bool = True):
     """Field names any query can read, or None when unknowable
     (``select *``). Name-level (not stream-qualified) and therefore
     conservative: a name used on ANY stream keeps that column on every
-    stream carrying it."""
+    stream carrying it. ``time_args=False`` leaves out the attribute a
+    window reads as time (``TIME_WINDOWS``' first argument): the names
+    read as values."""
     names = set()
 
     def add_expr(e):
@@ -1478,6 +1518,9 @@ def _referenced_field_names(parsed):
             for f in side.filters:
                 add_expr(f)
             for w in side.windows:
-                for arg in w.args:
+                args = w.args
+                if not time_args and w.name.lower() in TIME_WINDOWS:
+                    args = args[1:]
+                for arg in args:
                     add_expr(arg)
     return names

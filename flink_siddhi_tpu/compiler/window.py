@@ -38,7 +38,7 @@ from ..query import ast
 from ..query.lexer import SiddhiQLError
 from ..schema.encoders import GroupEncoder
 from ..schema.types import AttributeType
-from ..runtime.tape import EncodedColumn
+from ..runtime.tape import EncodedColumn, time_key
 from .expr import (
     ColumnEnv,
     CompiledExpr,
@@ -1456,7 +1456,9 @@ class BatchWindowArtifact:
                     ),
                     0,
                 )
-                t0 = jnp.where(state["t0"] >= 0, state["t0"], first_ts)
+                # (not t0 >= 0: a rebased time attribute may start
+                # before the job's clock origin)
+                t0 = jnp.where(state["seen"] > 0, state["t0"], first_ts)
                 abs_batch = jnp.where(
                     mask, (ts - t0) // T, 0
                 ).astype(jnp.int32)
@@ -1751,6 +1753,13 @@ def _window_of(inp: ast.StreamInput):
                 "#window.externalTimeBatch needs (tsAttribute, duration)"
             )
         return ("externalTimeBatch", (w.args[0], _time_arg(w.args[1])))
+    if lname == "hop":
+        if len(w.args) != 3 or not isinstance(w.args[0], ast.Attr):
+            raise SiddhiQLError(
+                "#window.hop needs (tsAttribute, size, slide)"
+            )
+        return ("hop", (w.args[0], _time_arg(w.args[1]),
+                        _time_arg(w.args[2])))
     if lname == "session":
         if not w.args or len(w.args) > 2:
             raise SiddhiQLError(
@@ -1850,7 +1859,15 @@ def compile_window_query(
         else None
     )
 
+    host_filters = host_filter_fns(inp.filters, resolver)
     window = _window_of(inp)
+    if window is not None and window[0] == "hop":
+        from .hop_window import compile_hop_window
+
+        return compile_hop_window(
+            q, name, window, resolver, stream_codes[inp.stream_id],
+            extensions, config, filter_fns, items, host_filters,
+        )
     if not collector.aggs and not group_names:
         # window with plain projection: current-event output == stateless
         # select (Siddhi emits arriving events unchanged for `insert into`)
@@ -1935,7 +1952,8 @@ def compile_window_query(
                 "grouping)"
             )
         code_key, encoder, encoded = _group_encoding(
-            name, group_resolved, sc, filter_fns
+            name, group_resolved, sc, filter_fns,
+            host_filters=host_filters,
         )
         art = PerKeyWindowArtifact(
             name=name,
@@ -1957,6 +1975,7 @@ def compile_window_query(
     if window is None or window[0] in (
         "length", "time", "externalTime", "timeLength",
     ):
+        time_columns = ()
         if window is None:
             mode, cap, time_ms, ts_key = "cumulative", 0, None, None
         elif window[0] == "length":
@@ -1973,13 +1992,12 @@ def compile_window_query(
             mode, cap, time_ms, ts_key = "timeLength", n, dur, None
         else:  # externalTime
             ts_attr, dur = window[1]
-            r = resolver.resolve(ts_attr)
-            mode, cap, time_ms, ts_key = (
-                "time", config.time_window_capacity, dur, r.key,
-            )
+            ts_key, time_columns = time_read(resolver.resolve(ts_attr))
+            mode, cap, time_ms = "time", config.time_window_capacity, dur
         if mode == "cumulative":
             code_key, encoder, encoded = _group_encoding(
-                name, group_resolved, sc, filter_fns
+                name, group_resolved, sc, filter_fns,
+                host_filters=host_filters,
             )
             art = CumulativeAggArtifact(
                 name=name,
@@ -2003,7 +2021,8 @@ def compile_window_query(
             group_fns.append(lambda env, k=key: env[k])
             group_dtypes.append(r.atype.device_dtype)
         code_key, encoder, encoded = _group_encoding(
-            name, group_resolved, sc, filter_fns
+            name, group_resolved, sc, filter_fns,
+            host_filters=host_filters,
         )
         # wire-opt metadata from the ORIGINAL (pre-rewrite) selector:
         # plain-ref sources, full per-item refs (incl. aggregate args),
@@ -2052,6 +2071,7 @@ def compile_window_query(
             filter_keys=w_filter_keys,
             group_keys_=tuple(r.key for r in group_resolved),
         )
+        art.time_columns = time_columns
         if art._blocked():
             # the sort-free tiled path consumes dense host-interned
             # group codes off the tape
@@ -2084,15 +2104,16 @@ def compile_window_query(
                 np.int32,
             ),
         )
-    batch_ts_key = None
+    batch_ts_key, time_columns = None, ()
     if mode == "externalTimeBatch":
         # same tumbling machinery as timeBatch, but stream time advances
         # with the user's timestamp attribute instead of event time
         ts_attr, dur = arg
-        batch_ts_key = resolver.resolve(ts_attr).key
+        batch_ts_key, time_columns = time_read(resolver.resolve(ts_attr))
         mode, arg = "timeBatch", dur
     code_key, encoder, encoded = _group_encoding(
-        name, group_resolved, sc, filter_fns
+        name, group_resolved, sc, filter_fns,
+        host_filters=host_filters,
     )
     # non-aggregate projection inputs need per-cell "last event" values.
     # having may reference SELECT ALIASES (resolved later against the
@@ -2135,7 +2156,42 @@ def compile_window_query(
     )
     art.encoded_columns = encoded
     art.host_columns = host_cols
+    art.time_columns = time_columns
     return art
+
+
+def time_read(r: ResolvedAttr) -> Tuple[str, Tuple[str, ...]]:
+    """Where a window reads attribute ``r`` as time, and what its
+    artifact declares as ``time_columns`` for that: a ``long`` is read
+    from its copy on the job's clock (``runtime.tape.time_key``: an
+    epoch-ms value does not fit the device's int32), any other type
+    from the column itself."""
+    if r.atype == AttributeType.LONG:
+        return time_key(r.key), (r.key,)
+    return r.key, ()
+
+
+def host_filter_fns(filters, resolver) -> Optional[List[Callable]]:
+    """The stream filters as numpy closures over the tape's host columns
+    (``compile_host_pred``), or None where one of them does not compile
+    that way or reads a float: the host compares in float64 and the
+    device in float32, and interning has to select exactly the events
+    the device's mask will."""
+    from .expr import compile_host_pred
+
+    out = []
+    for f in filters:
+        if any(
+            resolver.resolve(a).atype
+            in (AttributeType.FLOAT, AttributeType.DOUBLE)
+            for a in ast.iter_attrs(f)
+        ):
+            return None
+        he = compile_host_pred(f, resolver)
+        if he is None:
+            return None
+        out.append(he.fn)
+    return out
 
 
 def _group_encoding(
@@ -2143,18 +2199,24 @@ def _group_encoding(
     group_resolved: List[ResolvedAttr],
     stream_code: int,
     filter_fns: Sequence[Callable] = (),
+    encoder: Optional[GroupEncoder] = None,
+    host_filters: Optional[Sequence[Callable]] = None,
 ):
     """Dense group codes for state-table artifacts. Single-column int-like
     keys could index directly, but interning keeps tables dense for arbitrary
     key distributions and multi-column keys. Interning respects the query's
-    filters so rejected events never grow the table."""
+    filters so rejected events never grow the table: ``host_filters``
+    (``host_filter_fns``) where the filters compile to numpy, else the
+    device's own ``filter_fns``, which cost the host a round trip to the
+    device for every batch."""
     if not group_resolved:
         return None, None, ()
-    encoder = GroupEncoder()
+    if encoder is None:
+        encoder = GroupEncoder()
     out_key = f"@group:{name}"
     select_fn = None
-    if filter_fns:
-        fns = list(filter_fns)
+    fns = list(host_filters if host_filters is not None else filter_fns)
+    if fns:
 
         def select_fn(cols, _fns=fns):
             import numpy as _np

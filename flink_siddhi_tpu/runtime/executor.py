@@ -145,6 +145,8 @@ class _PlanRuntime:
     jitted_flush: Callable = None  # plan.flush under jit (device states)
     acc: Dict = None  # device-side output accumulator (None: fetch-per-cycle)
     wire_kinds: Dict = None  # sticky per-column wire widths (build_wire_tape)
+    # the encoders' counters as last booked (Job._count_groups)
+    group_stats: Dict = field(default_factory=dict)
     enabled: bool = True
     # NOTE: backpressure is ticket-based (see ``tickets`` below); there is
     # no per-cycle sawtooth sync anymore
@@ -2830,6 +2832,10 @@ class Job:
                             sc = self._attr_scope(schema)
                             if sc is not None:
                                 sc.inc("matches", len(payload))
+                            counters = getattr(a, "drain_counters", None)
+                            if counters is not None:
+                                for name, n in counters(payload).items():
+                                    tel.inc(name, n)
                         if isinstance(payload, ColumnBatch):
                             self._emit_columns(schema, payload)
                         else:
@@ -3705,7 +3711,10 @@ class Job:
             # the merged-order provenance map is only consulted by the
             # multi-batch lazy retention below
             want_prov=retain_lazy and len(involved) > 1,
+            # nested in tape_build: interning the group keys, expiry
+            intern_span=lambda: self.telemetry.span("group_intern"),
         )
+        self._count_groups(rt)
         if retain_lazy:
             if rt.lazy_base is None:
                 # first step (or first after restore): adopt the device
@@ -3777,6 +3786,33 @@ class Job:
             rt.lazy.push(rt.lazy_base, lcols)
             rt.lazy_base += total
         return tape
+
+    def _count_groups(self, rt: _PlanRuntime) -> None:
+        """The group tables' counters, read from the encoders after a
+        batch was interned: ``groups.interned`` (keys given a slot),
+        ``groups.slots_reused`` (of them, into a freed slot),
+        ``groups.expired`` (slots freed), gauge ``groups.live``."""
+        tel = self.telemetry
+        encoded = rt.plan.spec.encoded
+        if not encoded or not tel.enabled:
+            return
+        for name in ("interned", "slots_reused", "expired"):
+            total = sum(e.encoder.stats[name] for e in encoded)
+            seen = rt.group_stats.get(name, 0)
+            if total != seen:
+                tel.inc(f"groups.{name}", total - seen)
+                rt.group_stats[name] = total
+        tel.gauge("groups.live", sum(e.encoder.live for e in encoded))
+
+    def _grow_states(self, rt: _PlanRuntime) -> None:
+        """Host interning may have discovered more group keys than the
+        state tables hold: re-bucket them before the jitted step (a
+        shape change, so a one-off retrace: ``groups.regrow``)."""
+        plan = rt.plan
+        grown = plan.grow_count
+        rt.states = plan.grow_state(rt.states)
+        if plan.grow_count != grown:
+            self.telemetry.inc("groups.regrow", plan.grow_count - grown)
 
     # -- fused streaming dispatch (scan-of-microbatches segments) ----------
     def _fused_k(self, rt: _PlanRuntime) -> int:
@@ -3874,7 +3910,7 @@ class Job:
             # group keys: grow once per segment, before the scanned
             # call (host-driven re-bucketing = staging-class work)
             with _staging_allow():
-                rt.states = plan.grow_state(rt.states)
+                self._grow_states(rt)
             rt.states, rt.acc = rt.jitted_seg(rt.states, rt.acc, seg)
             rt.acc_dirty = True
             if rt.dirty_since is None:
@@ -3994,7 +4030,7 @@ class Job:
         # state tables before the jit call (shape change -> one-off
         # retrace; host-driven re-bucketing = staging-class work)
         with _staging_allow():
-            rt.states = plan.grow_state(rt.states)
+            self._grow_states(rt)
         with self._compile_scope(rt), tel.span("dispatch", seg=seg_id):
             t0 = time.monotonic()
             # NO device->host fetch here: emissions append to the

@@ -11,8 +11,9 @@ lengths so XLA compiles a handful of shapes, not one per batch.
 
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass
-from typing import Dict, List, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -21,6 +22,23 @@ from ..schema.batch import EventBatch
 from ..schema.types import AttributeType
 
 MIN_BUCKET = 128
+DAY_MS = 86_400_000
+
+
+def time_origin(epoch_ms: int) -> int:
+    """Where a time attribute's device clock starts: midnight (UTC) of
+    the job epoch's day. A ``long`` that a window reads as time rides
+    the job's clock as ``@ts`` does, int32 ms from here, so an epoch-ms
+    value neither wraps nor loses its alignment: every span that
+    divides a day cuts the rebased value where it cuts the epoch."""
+    return epoch_ms - epoch_ms % DAY_MS
+
+
+def time_key(key: str) -> str:
+    """The tape column that holds ``key`` rebased to the job's clock
+    (``TapeSpec.time_columns``): what a window reads as time. The raw
+    column ``key`` keeps its value for every other reader."""
+    return f"@time:{key}"
 
 
 def bucket_size(n: int, minimum: int = MIN_BUCKET) -> int:
@@ -50,6 +68,13 @@ class EncodedColumn:
     # shipping the code column — chained-group consumers map values to
     # codes ON DEVICE from the synced sorted table instead
     materialize: bool = True
+    # slot expiry (an encoder built with ``retain_ticks``): the rebased
+    # time column the owning window reads and the span of one tick (the
+    # hop window's slide). The encoder stamps every slot a batch touches
+    # with the tick of the batch's last selected event and frees a slot
+    # once ``retain_ticks`` ticks have passed it
+    tick_key: Optional[str] = None
+    tick_ms: int = 0
 
 
 @dataclass(frozen=True)
@@ -85,6 +110,11 @@ class TapeSpec:
     device_columns: Optional[Tuple[str, ...]] = None
     # wire predicate pushdown: host-evaluated masks added to the tape
     host_preds: Tuple[HostPred, ...] = ()
+    # long columns that a window reads as time: each also gets a column
+    # ``time_key(k)`` of int32 ms since ``time_origin(epoch)``, which
+    # only the window reads. The raw column (where ``columns`` still
+    # lists it: something reads it as a value) is built like any long
+    time_columns: Tuple[str, ...] = ()
 
     def built_columns(self) -> Tuple[str, ...]:
         if self.device_columns is None:
@@ -104,6 +134,9 @@ class Tape:
     stream: object  # int32[E]
     valid: object  # bool[E]
     cols: Dict[str, object]  # "stream.field" -> array[E]
+    # static: job epoch minus ``time_origin`` (0 where the plan has no
+    # time column). A time column's value v is job-relative v - time_off
+    time_off: int = 0
 
     @property
     def capacity(self) -> int:
@@ -114,13 +147,14 @@ class Tape:
         children = (self.ts, self.stream, self.valid) + tuple(
             self.cols[k] for k in keys
         )
-        return children, keys
+        return children, (keys, self.time_off)
 
     @classmethod
-    def tree_unflatten(cls, keys, children):
+    def tree_unflatten(cls, aux, children):
+        keys, time_off = aux
         ts, stream, valid = children[:3]
         cols = dict(zip(keys, children[3:]))
-        return cls(ts, stream, valid, cols)
+        return cls(ts, stream, valid, cols, time_off)
 
 
 # --------------------------------------------------------------------------
@@ -176,6 +210,7 @@ class WireTape:
     ts_kind: str = "i32"
     ts_base: object = None  # int32[1] first ts, or int32[2] (first, step)
     cap: int = 0  # static tape capacity ('d0' ships no ts array)
+    time_off: int = 0  # Tape.time_off; rebuilds an 'alias_tt' column
 
     @property
     def capacity(self) -> int:
@@ -187,16 +222,16 @@ class WireTape:
             self.cols[k] for k in keys
         )
         aux = (keys, self.kinds, self.stream_const, self.epoch_i32,
-               self.ts_kind, self.cap)
+               self.ts_kind, self.cap, self.time_off)
         return children, aux
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        keys, kinds, stream_const, epoch_i32, ts_kind, cap = aux
+        keys, kinds, stream_const, epoch_i32, ts_kind, cap, time_off = aux
         ts, n_valid, stream, ts_base = children[:4]
         cols = dict(zip(keys, children[4:]))
         return cls(ts, n_valid, stream, cols, kinds, stream_const,
-                   epoch_i32, ts_kind, ts_base, cap)
+                   epoch_i32, ts_kind, ts_base, cap, time_off)
 
     def expand(self) -> Tape:
         import jax.numpy as jnp
@@ -231,6 +266,8 @@ class WireTape:
         for key, kind in self.kinds:
             if kind == "alias_ts":
                 cols[key] = ts + jnp.int32(self.epoch_i32)
+            elif kind == "alias_tt":  # a time column equal to the ts
+                cols[key] = ts + jnp.int32(self.time_off)
             elif kind == "b1":
                 packed = self.cols[key]
                 bits = (
@@ -241,7 +278,7 @@ class WireTape:
                 cols[key] = self.cols[key]
             else:
                 cols[key] = self.cols[key].astype(jnp.int32)
-        return Tape(ts, stream, valid, cols)
+        return Tape(ts, stream, valid, cols, self.time_off)
 
 
 def build_wire_tape(
@@ -251,6 +288,7 @@ def build_wire_tape(
     sticky_kinds: Dict[str, str],
     capacity: int | None = None,
     want_prov: bool = True,
+    intern_span=None,
 ) -> Tuple[WireTape, np.ndarray]:
     """build_tape + narrowing. ``sticky_kinds`` (mutated) remembers each
     column's widest kind seen so widths only ever widen (bounded
@@ -259,9 +297,11 @@ def build_wire_tape(
     staging — save two full-width array fills per batch).
     """
     tape, prov = build_tape(
-        spec, batches, epoch_ms, capacity, want_prov=want_prov
+        spec, batches, epoch_ms, capacity, want_prov=want_prov,
+        intern_span=intern_span,
     )
     total = sum(len(b) for b in batches)
+    time_cols = {time_key(k) for k in spec.time_columns}
     epoch_i32 = int(np.int64(epoch_ms) & 0xFFFFFFFF)
     if epoch_i32 >= 1 << 31:
         epoch_i32 -= 1 << 32
@@ -269,7 +309,7 @@ def build_wire_tape(
     kinds: List[Tuple[str, str]] = []
     cols: Dict[str, np.ndarray] = {}
     with np.errstate(over="ignore"):
-        recon = None
+        recon = {}  # offset -> ts + offset, what an alias column holds
         for key in sorted(tape.cols):
             col = tape.cols[key]
             sticky = sticky_kinds.get(key)
@@ -278,14 +318,17 @@ def build_wire_tape(
             elif col.dtype == np.bool_:
                 kind = "b1"  # bit-packed: 1 bit/event on the wire
             else:
-                # alias check first (0 wire bytes); sticky 'alias_ts' may
+                # alias check first (0 wire bytes); a sticky alias may
                 # degrade to a real int kind the first time it mismatches
                 kind = None
-                if sticky in (None, "alias_ts"):
-                    if recon is None:
-                        recon = tape.ts[:total] + np.int32(epoch_i32)
-                    if np.array_equal(col[:total], recon):
-                        kind = "alias_ts"
+                is_time = key in time_cols
+                alias = "alias_tt" if is_time else "alias_ts"
+                if sticky in (None, alias):
+                    off = tape.time_off if is_time else epoch_i32
+                    if off not in recon:
+                        recon[off] = tape.ts[:total] + np.int32(off)
+                    if np.array_equal(col[:total], recon[off]):
+                        kind = alias
                 if kind is None:
                     lo, hi = (
                         (int(col[:total].min()), int(col[:total].max()))
@@ -295,7 +338,7 @@ def build_wire_tape(
                     kind = _int_kind(lo, hi)
                 # widths only widen; alias degrades to measured width
                 if sticky is not None and sticky != kind:
-                    order = ("alias_ts",) + _INT_KINDS
+                    order = (alias,) + _INT_KINDS
                     if kind in order and sticky in order:
                         kind = order[max(order.index(kind),
                                          order.index(sticky))]
@@ -303,7 +346,7 @@ def build_wire_tape(
             kinds.append((key, kind))
             if kind == "b1":
                 cols[key] = np.packbits(col, bitorder="little")
-            elif kind != "alias_ts":
+            elif kind not in ("alias_ts", "alias_tt"):
                 cols[key] = (
                     col
                     if kind in ("f32", "b", "i32")
@@ -398,6 +441,7 @@ def _finish_wire(
         ts_kind=ts_kind,
         ts_base=ts_base,
         cap=tape.capacity,
+        time_off=tape.time_off,
     )
 
 
@@ -437,12 +481,62 @@ def _merged_stream_values(
     return merged if identity else merged[order]
 
 
+def _check_i32_span(key: str, vals: np.ndarray, origin: int, why: str):
+    """A time attribute's first and last value (the stream is in order)
+    have to fit int32 counted from ``origin``."""
+    lo, hi = sorted((int(vals[0]), int(vals[-1])))
+    if lo < -(1 << 31) or hi >= 1 << 31:
+        raise ValueError(
+            f"time attribute {key!r} (value "
+            f"{origin + (lo if lo < -(1 << 31) else hi)}, clock origin "
+            f"{origin}) {why}"
+        )
+
+
+def _intern_groups(spec, batches, cols, stream, total, order, identity):
+    """Group keys -> dense codes (``spec.encoded``), added to ``cols``."""
+    cap = len(stream)
+    for enc in spec.encoded:
+        select = stream[:total] == enc.stream_code
+        if enc.select_fn is not None:
+            view = {k: v[:total] for k, v in cols.items()}
+            select = select & np.asarray(enc.select_fn(view))
+        in_cols = []
+        for k in enc.in_keys:
+            col = cols.get(k)
+            if col is not None:
+                col = col[:total]
+            else:
+                # the raw column was pruned off the wire (group values
+                # travel as codes); intern from the host batches
+                sid_k, fld_k = k.split(".", 1)
+                col = _merged_stream_values(
+                    batches, sid_k, fld_k, total, order, identity,
+                    spec.column_types[k].device_dtype
+                    if k in spec.column_types
+                    else None,
+                )
+                if col is None:
+                    col = np.zeros(total, dtype=np.int64)
+            in_cols.append(col)
+        tick_col = cols[enc.tick_key][:total] if enc.tick_key else None
+        codes = enc.encoder.intern_rows(
+            in_cols, select, tick_col, enc.tick_ms
+        )
+        if not enc.materialize:
+            continue  # interning side effect only
+        col = np.zeros(cap, dtype=np.int32)
+        col[:total] = codes
+        cols[enc.out_key] = col
+
+
 def build_tape(
     spec: TapeSpec,
     batches: Sequence[EventBatch],
     epoch_ms: int,
     capacity: int | None = None,
     want_prov: bool = True,
+    intern_span=None,
 ) -> Tuple[Tape, np.ndarray]:
     """Merge per-stream batches into one padded, ts-sorted host tape.
 
@@ -450,6 +544,8 @@ def build_tape(
     merged position i (sinks use it to reach host-only payloads).
     ``want_prov=False`` returns None in its place (two full-width array
     fills skipped — for callers that never consult it).
+    ``intern_span`` (a context-manager factory) is entered around the
+    group interning: the executor's nested ``group_intern`` span.
     Arrays are numpy; the jitted step's donate/commit moves them to device.
     """
     total = sum(len(b) for b in batches)
@@ -507,43 +603,50 @@ def build_tape(
         stream_id, field = key.split(".", 1)
         dtype = spec.column_types[key].device_dtype
         col = np.zeros(cap, dtype=dtype)
-        vals = _merged_stream_values(
-            batches, stream_id, field, total, order, identity, dtype
-        )
+        if key in spec.time_columns:
+            # read as a value too: the raw long, which has to fit the
+            # device's int32 (the window's read does not use it)
+            vals = _merged_stream_values(
+                batches, stream_id, field, total, order, identity, np.int64
+            )
+            if vals is not None and total:
+                _check_i32_span(
+                    key, vals, 0,
+                    "is read as a value (a projection or a filter) and "
+                    "does not fit the device's int32; only a window's "
+                    "read of it as time rides the job's clock",
+                )
+        else:
+            vals = _merged_stream_values(
+                batches, stream_id, field, total, order, identity, dtype
+            )
         if vals is not None:
             col[:total] = vals
         cols[key] = col
-
-    for enc in spec.encoded:
-        select = stream[:total] == enc.stream_code
-        if enc.select_fn is not None:
-            view = {k: v[:total] for k, v in cols.items()}
-            select = select & np.asarray(enc.select_fn(view))
-        in_cols = []
-        for k in enc.in_keys:
-            col = cols.get(k)
-            if col is not None:
-                col = col[:total]
-            else:
-                # the raw column was pruned off the wire (group values
-                # travel as codes); intern from the host batches
-                sid_k, fld_k = k.split(".", 1)
-                col = _merged_stream_values(
-                    batches, sid_k, fld_k, total, order, identity,
-                    spec.column_types[k].device_dtype
-                    if k in spec.column_types
-                    else None,
-                )
-                if col is None:
-                    col = np.zeros(total, dtype=np.int64)
-            in_cols.append(col)
-        codes = enc.encoder.intern_rows(in_cols, select)
-        if not enc.materialize:
-            continue  # interning side effect only
+    time_off = 0
+    if spec.time_columns:
+        origin = time_origin(epoch_ms)
+        time_off = epoch_ms - origin
+    for key in spec.time_columns:
+        # the host's int64 value, rebased: never cut to 32 bits
+        stream_id, field = key.split(".", 1)
         col = np.zeros(cap, dtype=np.int32)
-        col[:total] = codes
-        cols[enc.out_key] = col
+        vals = _merged_stream_values(
+            batches, stream_id, field, total, order, identity, np.int64
+        )
+        if vals is not None and total:
+            vals = vals - origin
+            _check_i32_span(
+                key, vals, origin,
+                "is more than 2**31 ms from the job's clock: a window's "
+                "time attribute has to be on the clock of the events' "
+                "timestamps",
+            )
+            col[:total] = vals
+        cols[time_key(key)] = col
 
+    with (intern_span or contextlib.nullcontext)():
+        _intern_groups(spec, batches, cols, stream, total, order, identity)
     # wire predicate pushdown: evaluate each host predicate over the
     # merged-order RAW host columns (f64 where the schema says DOUBLE)
     # and add the result as a bool pseudo-column — it ships bit-packed,
@@ -572,4 +675,4 @@ def build_tape(
             col[:total] = res
             cols[hp.out_key] = col
 
-    return Tape(ts, stream, valid, cols), prov
+    return Tape(ts, stream, valid, cols, time_off), prov

@@ -7,78 +7,277 @@ host into stable dense codes, the same trick dictionary-coded strings use
 hash maps inside siddhi-core; a dense code + fixed table is the TPU shape of
 that state (SURVEY.md §7 hard part 1: data-dependent structures -> fixed
 buffers).
+
+A code is a **slot** of the device table. By default the table is
+append-only: a key keeps its slot for the life of the job, which is what
+every artifact that decodes codes from a cached look-up table relies on. An
+encoder built with ``retain_ticks`` also **expires** keys (a time window
+whose keys are born and die: auctions, sessions, orders): the window's
+artifact gives every interning call the rebased time column, a slot is
+stamped with the tick of the last batch that touched it, and once
+``retain_ticks`` ticks have passed that stamp the slot is freed and handed
+to the next new key. The table then stays at the size of the keys a window
+holds, however many keys the stream has seen.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 
-class GroupEncoder:
-    """Append-only intern table over tuples of column values."""
+def _dense_span(vals: np.ndarray):
+    """(lowest value, span) of integer ``vals`` whose range is at most
+    four times their number, else (None, None)."""
+    if vals.dtype.kind not in "iu":
+        return None, None
+    lo, hi = int(vals.min()), int(vals.max())
+    if hi - lo >= 4 * len(vals) + 64:
+        return None, None
+    return lo, hi - lo + 1
 
-    def __init__(self) -> None:
+
+class GroupEncoder:
+    """Intern table over tuples of column values -> dense slots.
+
+    A single numeric column (the common group-by, and the hot path: one
+    call per micro-batch) is interned with numpy alone: the live keys
+    are kept sorted beside their slots, a batch's distinct values are
+    looked up with one ``searchsorted``, and new keys are merged in.
+    Anything else (several columns, object values) goes through a dict,
+    one Python step per selected row."""
+
+    def __init__(self, retain_ticks: Optional[int] = None) -> None:
+        self.retain_ticks = retain_ticks
+        self._n = 0  # slots ever handed out (the table's high-water mark)
+        # array mode: slot -> key, and the live keys sorted
+        self._slot_key: Optional[np.ndarray] = None
+        self._skeys: Optional[np.ndarray] = None
+        self._sslots: Optional[np.ndarray] = None
+        # dict mode: key tuple -> slot, slot -> key tuple (None: free)
         self._codes: Dict[Tuple, int] = {}
-        self._values: List[Tuple] = []
+        self._values: List[Optional[Tuple]] = []
+        # expiry: slot -> tick of the last batch that touched it; free
+        # slots, reused last-in first-out; the newest tick seen
+        self._last_tick = np.zeros(0, dtype=np.int64)
+        self._free = np.zeros(0, dtype=np.int32)
+        self._tick: Optional[int] = None
+        self._swept: Optional[int] = None
+        # what the executor's counters read (groups.*)
+        self.stats = {"interned": 0, "slots_reused": 0, "expired": 0}
 
     def __len__(self) -> int:
-        return len(self._values)
+        """Slots the device table needs (free slots included)."""
+        return self._n
 
+    @property
+    def live(self) -> int:
+        return self._n - len(self._free)
+
+    # -- interning ------------------------------------------------------------
     def intern_rows(
-        self, cols: Sequence[np.ndarray], select: np.ndarray
+        self,
+        cols: Sequence[np.ndarray],
+        select: np.ndarray,
+        tick_col: Optional[np.ndarray] = None,
+        tick_ms: int = 0,
     ) -> np.ndarray:
         """Codes for each row of ``zip(*cols)``; rows where ``select`` is
         False get code 0 and are NOT interned (they belong to other streams
-        and must not grow the table)."""
+        and must not grow the table). ``tick_col`` (the owning window's
+        rebased time column) and ``tick_ms`` drive expiry: the batch's tick
+        is that of its last selected row."""
         n = len(select)
         out = np.zeros(n, dtype=np.int32)
         if not n:
             return out
-        codes = self._codes
-        values = self._values
+        tick = None
+        if self.retain_ticks is not None and tick_col is not None:
+            self._sweep()
+            pos = np.flatnonzero(select)
+            if len(pos):
+                tick = int(tick_col[pos[-1]]) // tick_ms
         if len(cols) == 1 and cols[0].dtype != object:
             # vectorized single-column path: unique once (distinct group
-            # count, not row count), Python only per NEW group — the
-            # per-row loop below would dominate the host at bench batch
-            # sizes (~500k rows/batch)
-            col = cols[0]
-            sel_vals = col[select]
+            # count, not row count), nothing per row or per key in Python
+            sel_vals = cols[0][select]
             if not len(sel_vals):
                 return out
-            uniq = np.unique(sel_vals)
-            ucodes = np.empty(len(uniq), dtype=np.int32)
-            for u_i, u in enumerate(uniq.tolist()):
-                key = (u,)
-                code = codes.get(key)
-                if code is None:
-                    code = len(values)
-                    codes[key] = code
-                    values.append(key)
-                ucodes[u_i] = code
-            out[select] = ucodes[
-                np.searchsorted(uniq, sel_vals)
-            ]
-            return out
-        idx = np.nonzero(select)[0]
-        for i in idx:
-            key = tuple(c[i].item() for c in cols)
-            code = codes.get(key)
-            if code is None:
-                code = len(values)
-                codes[key] = code
-                values.append(key)
-            out[i] = code
+            lo, span = _dense_span(sel_vals)
+            if span is not None:
+                # ids that lie close together (a stream's newest keys, a
+                # small key set): mark and look up, no sort and no search
+                rel = sel_vals - lo
+                seen = np.zeros(span, dtype=np.bool_)
+                seen[rel] = True
+                present = np.flatnonzero(seen)
+                slots = self._intern_unique(
+                    (present + lo).astype(sel_vals.dtype)
+                )
+                lut = np.zeros(span, dtype=np.int32)
+                lut[present] = slots
+                out[select] = lut[rel]
+            else:
+                uniq = np.unique(sel_vals)
+                slots = self._intern_unique(uniq)
+                out[select] = slots[np.searchsorted(uniq, sel_vals)]
+        else:
+            idx = np.nonzero(select)[0]
+            slots = np.empty(len(idx), dtype=np.int32)
+            for j, i in enumerate(idx):
+                slots[j] = self._intern_key(tuple(c[i].item() for c in cols))
+            out[idx] = slots
+        if tick is not None:
+            self._last_tick[slots] = tick
+            self._tick = tick if self._tick is None else max(tick, self._tick)
         return out
 
+    def _take_slots(self, n_new: int) -> np.ndarray:
+        """``n_new`` slots: freed ones first, then fresh ones."""
+        take = min(n_new, len(self._free))
+        fresh = np.arange(self._n, self._n + n_new - take, dtype=np.int32)
+        slots = np.concatenate([self._free[len(self._free) - take:], fresh])
+        self._free = self._free[: len(self._free) - take]
+        self._n += n_new - take
+        self.stats["interned"] += n_new
+        self.stats["slots_reused"] += take
+        if self._n > len(self._last_tick):
+            grow = max(self._n, 2 * len(self._last_tick), 64)
+            self._last_tick = np.concatenate([
+                self._last_tick,
+                np.zeros(grow - len(self._last_tick), dtype=np.int64),
+            ])
+        return slots
+
+    def _intern_unique(self, uniq: np.ndarray) -> np.ndarray:
+        """Slots of the sorted distinct values ``uniq`` (array mode)."""
+        if self._skeys is None:
+            self._to_arrays(uniq.dtype)
+        sk, ss = self._skeys, self._sslots
+        pos = np.searchsorted(sk, uniq)
+        hit = pos < len(sk)
+        hit[hit] = sk[pos[hit]] == uniq[hit]
+        slots = np.empty(len(uniq), dtype=np.int32)
+        slots[hit] = ss[pos[hit]]
+        new = ~hit
+        n_new = int(new.sum())
+        if n_new:
+            got = self._take_slots(n_new)
+            if self._n > len(self._slot_key):
+                grown = np.zeros(
+                    max(self._n, 2 * len(self._slot_key), 64), dtype=sk.dtype
+                )
+                grown[: len(self._slot_key)] = self._slot_key
+                self._slot_key = grown
+            self._slot_key[got] = uniq[new]
+            slots[new] = got
+            self._skeys = np.insert(sk, pos[new], uniq[new])
+            self._sslots = np.insert(ss, pos[new], got)
+        return slots
+
+    def _intern_key(self, key: Tuple) -> int:
+        if self._skeys is not None:
+            self._to_dict()
+        code = self._codes.get(key)
+        if code is None:
+            code = int(self._take_slots(1)[0])
+            self._codes[key] = code
+            if code == len(self._values):
+                self._values.append(key)
+            else:
+                self._values[code] = key
+        return code
+
+    def _sweep(self) -> None:
+        """Free every slot that ``retain_ticks`` ticks have passed, as of
+        the newest tick an EARLIER call saw: by then the device has closed
+        every window the slot's key could be part of."""
+        if self._tick is None or self._tick == self._swept:
+            return
+        self._swept = self._tick
+        stamp = self._last_tick[: self._n]
+        dead = stamp + self.retain_ticks <= self._tick
+        if self._skeys is not None:
+            live = self._sslots
+            keep = ~dead[live]
+            freed = np.sort(live[~keep])  # slot order, as the dict's
+            self._skeys, self._sslots = self._skeys[keep], live[keep]
+        else:
+            freed = np.asarray(
+                [s for s in np.flatnonzero(dead)
+                 if self._values[s] is not None],
+                dtype=np.int32,
+            )
+            for s in freed:
+                del self._codes[self._values[s]]
+                self._values[s] = None
+        if len(freed):
+            self._free = np.concatenate([self._free, freed.astype(np.int32)])
+            self.stats["expired"] += len(freed)
+
     def value(self, code: int) -> Tuple:
+        if self._skeys is not None:
+            return (self._slot_key[code].item(),)
         return self._values[code]
+
+    # -- the two representations ----------------------------------------------
+    def _to_arrays(self, dtype) -> None:
+        """Dict mode (or a fresh table) -> array mode."""
+        live = [(v[0], s) for s, v in enumerate(self._values)
+                if v is not None]
+        self._slot_key = np.zeros(max(self._n, 64), dtype=dtype)
+        keys = np.asarray([k for k, _ in live], dtype=dtype)
+        slots = np.asarray([s for _, s in live], dtype=np.int32)
+        self._slot_key[slots] = keys
+        order = np.argsort(keys, kind="stable")
+        self._skeys, self._sslots = keys[order], slots[order]
+        self._codes, self._values = {}, []
+
+    def _to_dict(self) -> None:
+        self._values = self._value_list()
+        self._codes = {
+            v: s for s, v in enumerate(self._values) if v is not None
+        }
+        self._slot_key = self._skeys = self._sslots = None
+
+    def _value_list(self) -> List[Optional[Tuple]]:
+        """slot -> key tuple, None for a free slot."""
+        if self._skeys is None:
+            return list(self._values)
+        values: List[Optional[Tuple]] = [None] * self._n
+        for s, k in zip(self._sslots.tolist(), self._skeys.tolist()):
+            values[s] = (k,)
+        return values
 
     # -- checkpoint support -------------------------------------------------
     def state_dict(self) -> dict:
-        return {"values": list(self._values)}
+        """Keys by slot (None: a free slot) and, with expiry, each slot's
+        stamp, the free slots in reuse order and the newest tick: a
+        restored table hands out the same slots as the one it was taken
+        from."""
+        d = {"values": self._value_list()}
+        if self.retain_ticks is not None:
+            d["expiry"] = {
+                "last_tick": self._last_tick[: self._n].tolist(),
+                "free": self._free.tolist(),
+                "tick": self._tick,
+            }
+        return d
 
     def load_state_dict(self, d: dict) -> None:
-        self._values = [tuple(v) for v in d["values"]]
-        self._codes = {v: i for i, v in enumerate(self._values)}
+        self._slot_key = self._skeys = self._sslots = None
+        self._values = [
+            None if v is None else tuple(v) for v in d["values"]
+        ]
+        self._codes = {
+            v: i for i, v in enumerate(self._values) if v is not None
+        }
+        self._n = len(self._values)
+        exp = d.get("expiry") or {}
+        self._last_tick = np.asarray(
+            exp.get("last_tick", [0] * self._n), dtype=np.int64
+        )
+        self._free = np.asarray(exp.get("free", []), dtype=np.int32)
+        self._tick = exp.get("tick")
+        self._swept = None

@@ -162,6 +162,10 @@ class StreamSchema:
                     dtype=np.int32,
                     count=len(vals),
                 )
+            elif atype == AttributeType.LONG:
+                # the host keeps a long whole (an epoch-ms value does not
+                # fit the device's int32); the tape narrows or rebases it
+                cols[name] = np.asarray(vals, dtype=atype.host_dtype)
             else:
                 cols[name] = np.asarray(vals, dtype=atype.device_dtype)
         return cols
