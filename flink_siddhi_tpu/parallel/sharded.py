@@ -254,44 +254,27 @@ class ShardedJob(Job):
             bucket_size(max(sum(len(b) for b in sh) for sh in shards) or 1),
         )
         with tel.span("tape_build"):
-            tapes = [
-                build_tape(
-                    plan.spec, sh, self._epoch_ms, rt.tape_capacity
-                )[0]
-                for sh in shards
-            ]
-            stacked_tape = _tree_stack(
-                [jax.tree.map(jnp.asarray, t) for t in tapes]
-            )
+            stacked_tape = self._stage_tapes(rt, shards)
         # host-driven re-bucketing after group growth is staging-class
         # work (device_get + per-shard rebuild + explicit device_put)
         with _staging_allow():
             rt.states = self._grow_stacked(plan, rt.states)
         # per-shard on-device accumulation; no fetch in the hot loop
-        # (drained in bulk by _drain_plan, same as the single-device Job)
+        # (drained in bulk by _drain_plan, same as the single-device Job).
+        # The tape is committed, one row per chip: the call moves nothing
         with tel.span("dispatch"):
-            # KNOWN HAZARD, allowed deliberately (surfaced by the
-            # hot-loop transfer guard, tests/conftest.py): the stacked
-            # tape materializes on device 0 and IMPLICITLY reshards to
-            # the mesh at this call — on a real multi-chip mesh every
-            # upload bounces through one chip's HBM. The fix (host-
-            # stack + one explicit sharded device_put) measured 2-4x
-            # slower on the 8-virtual-device CPU lane (eager per-leaf
-            # 8-way splits per batch), so per-shard-affine staging is
-            # deferred to the multichip scale-out lane (ROADMAP) where
-            # real per-chip placement pays for it.
-            with _staging_allow():
-                rt.states, rt.acc = rt.jitted_acc(
-                    rt.states, rt.acc, stacked_tape
-                )
+            rt.states, rt.acc = rt.jitted_acc(
+                rt.states, rt.acc, stacked_tape
+            )
             rt.acc_dirty = True
             if rt.dirty_since is None:
                 rt.dirty_since = time.monotonic()
+        tel.inc("shard.cycles")
         # shared no-overflow contract (Job._update_drain_hint); strip the
         # leading shard axis via shape metadata only
         self._update_drain_hint(
             plan,
-            stacked_tape.ts.shape[-1],
+            rt.tape_capacity,
             lambda name: jax.tree.map(
                 lambda x: jax.ShapeDtypeStruct(
                     np.shape(x)[1:], x.dtype
@@ -299,6 +282,25 @@ class ShardedJob(Job):
                 rt.states.get(name),
             ),
         )
+
+    def _stage_tapes(self, rt: _PlanRuntime, shards):
+        """One cycle's per-shard tapes, stacked leaf by leaf in host
+        memory to ``[n_shards, capacity]`` and uploaded in ONE explicit
+        sharded put: row ``s`` goes straight to the chip that steps
+        shard ``s``, and no eager device program runs per leaf."""
+        tel = self.telemetry
+        tapes = [
+            build_tape(
+                rt.plan.spec, sh, self._epoch_ms, rt.tape_capacity,
+                want_prov=False,
+            )[0]
+            for sh in shards
+        ]
+        stacked = jax.tree.map(lambda *xs: np.stack(xs), *tapes)
+        with tel.span("shard_put"):
+            stacked = jax.device_put(stacked, self._state_sharding)
+        tel.inc("shard.tape_puts")
+        return stacked
 
     def prewarm_drains(self, widths=None) -> None:
         # no-op: Job's packed-drain programs (jit_pack, one per fetch

@@ -393,3 +393,142 @@ def test_segment_plus_nonsegmentable_pattern_compiles():
         assert sorted(single.results_with_ts(out)) == sorted(
             sharded.results_with_ts(out)
         )
+
+
+# -------------------------------------------------------------------------
+# tape staging: host stack, one sharded put (mesh-4, tier-1)
+# -------------------------------------------------------------------------
+
+_GROUPBY_CQL = (
+    "from S select id, sum(price) as total, count() as cnt "
+    "group by id insert into out"
+)
+_STAGING_CASES = {
+    # name: (cql, events, partition kind the planner must have chosen)
+    "groupby": (_GROUPBY_CQL, make_events(300, id_mod=13), "groupby"),
+    "shuffle": (
+        "from S[id == 2] select id, name, price insert into out",
+        make_events(300),
+        "shuffle",
+    ),
+    # a quantified chain does not split by time: owner-pinned
+    "broadcast": (
+        "from every a1 = S[id == 1]<2:3> -> a2 = S[id == 2] "
+        "select a1[0].price as p1, a2.price as p2 insert into out",
+        make_events(300),
+        "broadcast",
+    ),
+    "segment": (
+        "from every s1 = S[id == 2] -> s2 = S[id == 3] "
+        "select s1.price as p1, s2.price as p2 insert into out",
+        make_events(300),
+        "segment",
+    ),
+    # one key: three of the four shards receive no event in any cycle
+    "groupby_empty_shard": (
+        _GROUPBY_CQL, make_events(300, id_mod=1), "groupby",
+    ),
+}
+
+
+def _mesh4_job(cql, events, batch_size=64):
+    env = CEPEnvironment(batch_size=batch_size)
+    env.register_stream("S", events, FIELDS)
+    plan = compile_plan(
+        cql, {"S": env.schemas["S"]}, extensions=env.extensions
+    )
+    return ShardedJob(
+        [plan], [env.sources["S"]], mesh=make_cep_mesh(4),
+        batch_size=batch_size,
+    )
+
+
+@pytest.mark.parametrize("case", sorted(_STAGING_CASES))
+def test_staged_tape_is_the_four_tapes_one_row_per_device(case):
+    """What the step is called on: the per-shard ``build_tape`` results,
+    leaf for leaf and row for row, already laid one row per device."""
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from flink_siddhi_tpu.parallel.mesh import SHARD_AXIS
+    from flink_siddhi_tpu.runtime.tape import build_tape
+
+    cql, events, kind = _STAGING_CASES[case]
+    job = _mesh4_job(cql, events)
+    (rt,) = job._plans.values()
+    assert job._routers[rt.plan.plan_id].partition_of("S").kind == kind
+    staged = []
+    stage = job._stage_tapes
+
+    def spy(rt_, shards):
+        out = stage(rt_, shards)
+        staged.append((shards, out))
+        return out
+
+    job._stage_tapes = spy
+    job.run()
+    assert len(staged) >= 4  # 300 events in batches of 64
+    if case == "groupby_empty_shard":
+        assert all(
+            sorted(map(len, shards))[:3] == [0, 0, 0]
+            for shards, _ in staged
+        )
+    want = NamedSharding(job.mesh, P(SHARD_AXIS))
+    devices = list(job.mesh.devices.flat)
+    for shards, tape in staged:
+        cap = tape.capacity
+        refs = [
+            build_tape(rt.plan.spec, sh, job._epoch_ms, cap)[0]
+            for sh in shards
+        ]
+        assert tape.time_off == refs[0].time_off
+        assert sorted(tape.cols) == sorted(refs[0].cols)
+        got_leaves = jax.tree.leaves(tape)
+        for got in got_leaves:
+            assert got.shape == (4, cap)
+            assert got.sharding.is_equivalent_to(want, got.ndim)
+        for s, ref in enumerate(refs):
+            ref_leaves = jax.tree.leaves(ref)
+            assert len(got_leaves) == len(ref_leaves)
+            for got, exp in zip(got_leaves, ref_leaves):
+                assert got.dtype == exp.dtype
+                row = {
+                    sh.index[0].start: sh for sh in got.addressable_shards
+                }[s]
+                assert row.device == devices[s]
+                np.testing.assert_array_equal(np.asarray(row.data)[0], exp)
+
+
+def test_sharded_run_under_transfer_guard_puts_once_a_cycle(monkeypatch):
+    """The dispatch site allows nothing: under the hot-loop transfer
+    guard a ShardedJob runs clean with the guard still at "disallow"
+    when the step is called, and every cycle that dispatched made
+    exactly one explicit sharded upload."""
+    from flink_siddhi_tpu.runtime import executor
+
+    monkeypatch.setattr(executor, "HOTLOOP_TRANSFER_GUARD", True)
+    events = make_events(300, id_mod=13)
+    job = _mesh4_job(_GROUPBY_CQL, events)
+    (rt,) = job._plans.values()
+    step, guard_at_dispatch = rt.jitted_acc, []
+
+    def guarded_step(*args):
+        guard_at_dispatch.append(
+            jax.config.jax_transfer_guard_host_to_device
+        )
+        return step(*args)
+
+    rt.jitted_acc = guarded_step
+    job.run()
+    single = build_job(_GROUPBY_CQL, {"S": events}, sharded=False)
+    single.run()
+    assert sorted(job.results_with_ts("out")) == sorted(
+        single.results_with_ts("out")
+    )
+    telemetry = job.metrics()["telemetry"]
+    cycles = telemetry["counters"]["shard.cycles"]
+    assert telemetry["counters"]["shard.tape_puts"] == cycles > 0
+    assert guard_at_dispatch == ["disallow"] * cycles
+    assert telemetry["stages"]["nested.shard_put"]["count"] == cycles
+    # nested in tape_build, never a top-level span of the run loop
+    assert "shard_put" not in telemetry["stages"]
