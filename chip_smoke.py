@@ -10,8 +10,8 @@ checks its rows against an independent reference and raises on a
 mismatch: there is no ``try/except`` between a failed phase and a
 non-zero exit.
 
-Phases (sizes are the deployment's: ``BASELINE.json`` configs 3 and 4 as
-``bench.py`` runs them):
+Phases (sizes are the deployment's: ``BASELINE.json`` configs 3 and 4,
+their queries from ``flink_siddhi_tpu/baseline/workloads.py``):
 
 * **kernels**   the Pallas kernel left in the tree compiles (no
   interpreter) and equals its XLA form at its probe shape and at
@@ -35,7 +35,7 @@ Phases (sizes are the deployment's: ``BASELINE.json`` configs 3 and 4 as
   65,536-event batch; rows equal the one-chip ``Job``'s; state and
   accumulators live on four devices.
 
-Data is made from ``--seed`` in the bench generator's shapes: ``id``
+Data is made from ``--seed`` in the shapes of ``baseline/workloads.py``: ``id``
 uniform over 50 ids (1,000 for the window phase), one interned ``name``,
 ``price`` in [0, 100), timestamps 1 ms apart.
 
@@ -61,7 +61,7 @@ import time
 REPO = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, REPO)
 
-# the one compile cache (bench.py and tests/conftest.py use the same
+# the one compile cache (tests/conftest.py uses the same
 # idiom): where the environment names a directory, there; else a fixed
 # path in the repo — the path is part of the cache key, so it never moves
 os.environ.setdefault(
@@ -87,7 +87,7 @@ class Sizes:
     shard_batch: int = 65_536
 
 
-SEGMENT = 8  # Job.fused_segment_len, as bench.py runs streaming
+SEGMENT = 8  # Job.fused_segment_len, as the one-chip cells set it
 SHARD_BATCHES = 4
 
 
@@ -334,15 +334,16 @@ def phase_kernels(sizes: Sizes, seed: int, expect_mode: str = "compiled"):
 
 # -- phases: headline pattern, streaming and resident ------------------------
 def _headline_job(schema, batches, sizes: Sizes, sink):
-    from bench import _config_cql
+    from flink_siddhi_tpu.baseline.workloads import config_cql
     from flink_siddhi_tpu.compiler.config import EngineConfig
     from flink_siddhi_tpu.compiler.plan import compile_plan
     from flink_siddhi_tpu.runtime.executor import Job
     from flink_siddhi_tpu.runtime.sources import BatchSource
 
-    # as bench.py builds it: late materialization + predicate pushdown
+    # as benchmark/configs/pattern3.json builds it: late
+    # materialization + predicate pushdown
     plan = compile_plan(
-        _config_cql("headline"), {STREAM: schema}, plan_id="headline",
+        config_cql("headline"), {STREAM: schema}, plan_id="headline",
         config=EngineConfig(lazy_projection=True, pred_pushdown=True),
     )
     job = Job(
@@ -357,7 +358,7 @@ def _headline_job(schema, batches, sizes: Sizes, sink):
 
 def phase_headline(sizes: Sizes, seed: int, expect_mode: str = "compiled"):
     """Streaming then resident over the same events; one baseline run."""
-    from bench import _config_cql
+    from flink_siddhi_tpu.baseline.workloads import config_cql
     from flink_siddhi_tpu.runtime.replay import ResidentReplay
 
     out = {}
@@ -366,7 +367,7 @@ def phase_headline(sizes: Sizes, seed: int, expect_mode: str = "compiled"):
     cols = make_columns(seed, sizes.events, n_ids=50)
     batches = make_batches(schema, cols, sizes.batch)
     want = baseline_table(
-        _config_cql("headline"), cols, ("t1", "t3", "price")
+        config_cql("headline"), cols, ("t1", "t3", "price")
     )
     sink = RowSink()
     job = _headline_job(schema, batches, sizes, sink)
@@ -417,12 +418,12 @@ def phase_headline(sizes: Sizes, seed: int, expect_mode: str = "compiled"):
 
 # -- phase: window state at deployment size + checkpoint ---------------------
 def phase_window(sizes: Sizes, seed: int):
-    from bench import _config_cql
+    from flink_siddhi_tpu.baseline.workloads import config_cql
     from flink_siddhi_tpu.compiler.plan import compile_plan
     from flink_siddhi_tpu.runtime.executor import Job
     from flink_siddhi_tpu.runtime.sources import ReplayBatchSource
 
-    cql = _config_cql("window_groupby")
+    cql = config_cql("window_groupby")
     clock = _Clock()
     schema = make_schema()
     cols = make_columns(seed + 1, sizes.events, n_ids=1000)
@@ -476,7 +477,7 @@ def phase_window(sizes: Sizes, seed: int):
 
 # -- phase: the deployable entry ---------------------------------------------
 def phase_pipeline(sizes: Sizes, seed: int):
-    from bench import _config_cql
+    from flink_siddhi_tpu.baseline.workloads import config_cql
     from flink_siddhi_tpu import native
     from flink_siddhi_tpu.app.pipeline import CEPPipeline, PipelineConfig
 
@@ -485,7 +486,7 @@ def phase_pipeline(sizes: Sizes, seed: int):
     assert native.available(), "the C++ decoder did not build"
     n = sizes.pipeline_lines
     cols = make_columns(seed + 2, n, n_ids=50)
-    cql = _config_cql("filter")
+    cql = config_cql("filter")
     want = baseline_table(cql, cols, ("id", "name", "price"))
     with tempfile.TemporaryDirectory() as d:
         src, dst = os.path.join(d, "in.jsonl"), os.path.join(d, "out.jsonl")
@@ -544,11 +545,11 @@ _MIX = {
 
 
 def _mix_plans(schema):
-    from bench import _config_cql
+    from flink_siddhi_tpu.baseline.workloads import config_cql
     from flink_siddhi_tpu.compiler.plan import compile_plan
 
     texts = {
-        "pattern": _config_cql("headline"),
+        "pattern": config_cql("headline"),
         "keyed": (
             "partition with (id of inputStream) begin "
             "from every k1 = inputStream[price > 0.0] -> "

@@ -41,11 +41,6 @@ from .control.plane import AdmissionGate, ControlPlane, ControlRejected
 
 __version__ = "0.1.0"
 
-# the bench JSON contract version (bench.py emits it, scripts/
-# check_bench_schema.py gates it, the fst_build_info OpenMetrics gauge
-# exposes it) — one definition so the three cannot drift
-BENCH_SCHEMA_VERSION = 13
-
 __all__ = [
     "SiddhiCEP",
     "CEPEnvironment",

@@ -7,7 +7,7 @@ Usage::
             [--write-baseline FILE] [--list-rules] [--json]
 
 With no paths, lints the default surface: the ``flink_siddhi_tpu``
-package, ``bench.py``, and ``scripts/``. The default sweep runs the
+package and ``scripts/``. The default sweep runs the
 per-module FST1xx rules (rules.py) AND the cross-module FST2xx
 thread-ownership pass (threads.py). ``--rule`` restricts output
 to the named rule id(s) — iterate on ONE rule without wading through
@@ -59,10 +59,9 @@ _SKIP_PARTS = {".jax_cache", "__pycache__", ".git", "analysis_fixtures"}
 
 def _default_targets() -> List[str]:
     out = [_PKG_DIR]
-    for extra in ("bench.py", "scripts"):
-        p = os.path.join(REPO_ROOT, extra)
-        if os.path.exists(p):
-            out.append(p)
+    scripts = os.path.join(REPO_ROOT, "scripts")
+    if os.path.exists(scripts):
+        out.append(scripts)
     return out
 
 

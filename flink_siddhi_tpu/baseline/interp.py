@@ -11,9 +11,10 @@ processors with running aggregates —
 reference: operator/AbstractSiddhiOperator.java:209-233), written
 against the same parsed CQL AST the TPU engine compiles.
 
-``python bench.py --baseline`` replays the identical event stream
-through it on one core and prints its events/sec; BENCH numbers divide
-by the recorded measurement. It is deliberately the SIMPLE obvious
+Tests replay the identical event stream (``workloads.make_batches``)
+through it and through the engine and compare rows
+(tests/test_baseline_crosscheck.py, tests/test_baseline_workloads.py).
+It is deliberately the SIMPLE obvious
 implementation — per-event dispatch, dict state, no vectorization — the
 way the JVM engine processes events (which JIT-compiles to far faster
 code than CPython; BASELINE.md keeps the JVM-estimate ratio alongside

@@ -17,8 +17,9 @@ accepting load:
    tests/test_fleet.py;
 3. **serve** — the run loop starts; ``cold_start_to_first_row``
    (process start → first emitted row, measured by the first-row clock
-   sink) is the headline metric bench schema v12 records with vs
-   without the store.
+   sink) is what the store is for. No cell reads it yet (ROADMAP
+   Queue 3, ``fleet/``); what a CPU can pin, zero lowerings on a warm
+   boot, is tests/test_fleet.py's.
 
 :class:`ReplicaSupervisor` extends the supervisor's checkpoint
 boundary: the commit-log epoch about to be stamped rides the snapshot's

@@ -2182,8 +2182,8 @@ class Job:
         segments, in-flight tickets, lazy rings, and host emission
         phase so the SAME job can replay an identical stream again
         with every compiled executable still warm — the second-run
-        measurement contract shared by ``ResidentReplay.rerun``,
-        bench's streaming mode, and ``scripts/profile_dispatch.py``
+        measurement contract of ``ResidentReplay.rerun``
+        (tests/test_replay.py, tests/test_baseline_workloads.py)
         (ONE reset recipe, so a new runtime field cannot be forgotten
         in one of the copies). States re-grow to the interned encoder
         sizes: compiled programs were lowered against the GROWN
@@ -3926,8 +3926,8 @@ class Job:
                 # the device wall hides behind the ticket). Recorded
                 # under both names: dispatch.segment is the fused-mode
                 # stage model's leg (docs/observability.md),
-                # dispatch.enqueue the mode-agnostic one the
-                # profiler reads (scripts/profile_dispatch.py)
+                # dispatch.enqueue the mode-agnostic one, booked
+                # by the per-batch path too (_step_plan_window)
                 dt = time.monotonic() - t0
                 tel.record_seconds("dispatch.segment", dt)
                 tel.record_seconds("dispatch.enqueue", dt)
@@ -4047,8 +4047,8 @@ class Job:
                 rt.dirty_since = time.monotonic()
             if tel.enabled:
                 # host-side enqueue time of one dispatch (the device
-                # wall hides behind the ticket; scripts/
-                # profile_dispatch.py reports both legs)
+                # wall hides behind the ticket: leg.device carries
+                # it, telemetry/legs.py)
                 tel.record_seconds(
                     "dispatch.enqueue", time.monotonic() - t0
                 )

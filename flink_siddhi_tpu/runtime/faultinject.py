@@ -3,16 +3,15 @@
 Failure is a first-class, injected, measured input here (PAPERS.md #4:
 claims only count under load the system survives — the same standard
 applied to recovery). This module holds the process-death half of the
-harness, shared by the property tests (tests/faults.py re-exports it
-next to the wire-fault ``FaultSchedule``) and by ``bench.py --fault``
-(the measured-recovery block) — one implementation, so the debris a
-"dying writer" leaves and the pull-boundary crash semantics cannot
-drift between the tests and the bench — and the EVENT-TIME half:
+harness, used by the property tests (tests/faults.py re-exports it
+next to the wire-fault ``FaultSchedule``; tests/test_faults.py drives
+supervised recovery and the transactional sink through it) — one
+implementation of the debris a "dying writer" leaves and of the
+pull-boundary crash semantics — and the EVENT-TIME half:
 :class:`DisorderSchedule` / :class:`DisorderSource` inject seeded
 arrival disorder (bounded skew, bursty duplicates, late stragglers,
-idle partitions) with an exact injected account, shared by the
-disorder oracle tests (tests/test_event_time.py) and ``bench.py
---disorder`` (docs/event_time.md).
+idle partitions) with an exact injected account, for the disorder
+oracle tests (tests/test_event_time.py; docs/event_time.md).
 
 :class:`CrashPlan` + :func:`wrap_job` inject crashes into a SUPERVISED
 job: at scheduled source-pull boundaries (mode-agnostic: streaming
@@ -124,8 +123,8 @@ class DisorderSchedule:
 
     Four production failure shapes, composable, all DETERMINISTIC from
     the seed (the late/dup counters the engine reports must reconcile
-    EXACTLY against what was injected — tests and ``bench.py
-    --disorder`` both assert it):
+    EXACTLY against what was injected — tests/test_event_time.py
+    asserts it):
 
     * ``skew_ms``       — bounded arrival-order shuffle: each event's
       arrival is displaced by a seeded delay drawn from

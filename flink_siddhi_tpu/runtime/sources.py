@@ -312,8 +312,8 @@ class ReplayBatchSource(BatchSource):
     """BatchSource over an in-memory Sequence of prebuilt EventBatches
     with an EXACT, checkpointable replay position — the
     supervised-recovery analog of ListSource for the zero-per-record
-    ingest path (``bench.py --fault`` and supervised replay runs
-    restore mid-stream through it). The iterator-backed parent stays
+    ingest path (supervised replay runs restore mid-stream through
+    it: tests/test_faults.py). The iterator-backed parent stays
     non-checkpointable: an iterator has no position to restore."""
 
     def __init__(

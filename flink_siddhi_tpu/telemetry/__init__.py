@@ -26,13 +26,13 @@ from .tracing import TraceSampler
 # Stage names that partition the RUN-LOOP thread's wall-clock (spans
 # opened while another span is active on the same thread accrue under
 # "nested.<name>" instead — see spans.StageTimes). Summing exactly
-# these against an elapsed wall clock is how bench.py's
-# ``stage_breakdown.coverage`` (the >= 95% attribution contract) and
-# scripts/check_bench_schema.py are computed. Fetch-thread work
+# these against an elapsed wall clock is how attribution.py's
+# ``coverage`` is computed, and the benchmark's
+# ``runloop_unattributed_share`` reads the same spans. Fetch-thread work
 # (d2h + decode) intentionally overlaps this lane and is reported via
 # the drain.* histograms instead.
 TOP_LEVEL_STAGES = (
-    # bench setup
+    # a caller's own set-up, booked with add_time
     "input_gen",
     "plan_compile",
     "job_init",

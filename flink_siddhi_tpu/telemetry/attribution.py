@@ -51,9 +51,8 @@ Verdict: ``limiting_leg`` is the argmax over the NON-overlapped legs
 excluding ``setup`` (setup is real wall-clock — it stays in the
 coverage arithmetic — but a one-off compile dominating a short run is
 not a steady-state bottleneck; its share is still printed).
-``scripts/check_bench_schema.py`` re-derives both the coverage and the
-argmax from the published per-leg seconds, so a declared verdict
-cannot contradict its own numbers.
+tests/test_flightrec.py holds the cover (exhaustive, disjoint) and
+the verdict's arithmetic.
 """
 
 from __future__ import annotations

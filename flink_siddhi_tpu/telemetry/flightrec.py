@@ -40,8 +40,8 @@ lock, held only for dict/deque operations (no blocking calls, no I/O:
 
 Overhead: ``record()`` checks the owning registry's ``enabled`` flag
 first and returns immediately when telemetry is off — the same switch
-as every span/histogram (the bench ``BENCH_TELEMETRY=0`` A/B), so the
-journal path is part of the measured <2% envelope. Events only fire
+as every span/histogram (``job.telemetry.enabled = False``), so the
+journal path costs nothing when telemetry is off. Events only fire
 at control/fault/checkpoint boundaries, never per micro-batch.
 """
 

@@ -178,7 +178,7 @@ def _tenant_of_map(metrics: Dict) -> Dict[str, str]:
 
 def _build_info_labels() -> Dict[str, str]:
     """The fst_build_info label set: package version, jax version,
-    backend, bench schema version — the standard *_info gauge pattern
+    backend — the standard *_info gauge pattern
     (value always 1; the labels ARE the payload), so a scraper can
     join any series against what produced it."""
     import jax
@@ -193,9 +193,6 @@ def _build_info_labels() -> Dict[str, str]:
         "package_version": str(getattr(_pkg, "__version__", "0")),
         "jax_version": str(jax.__version__),
         "backend": str(backend),
-        "bench_schema_version": str(
-            getattr(_pkg, "BENCH_SCHEMA_VERSION", 0)
-        ),
     }
 
 

@@ -19,10 +19,10 @@ never imports the package, jax, or numpy) that
 The resulting p50/p99 is an end-to-end ingest→match-visibility
 measurement the system under test cannot game: it includes socket
 transit, decode, reorder queueing, device dispatch + backlog, drain,
-host decode, sink delivery, and the ack hop back. bench.py reports it
-NEXT TO the in-process telemetry numbers and prints the discrepancy
-ratio; a large ratio means the internal accounting is lying (or the
-ack/ingest hops dominate — the docs say how to tell).
+host decode, sink delivery, and the ack hop back. Read it NEXT TO the
+in-process telemetry numbers (tests/test_prober.py does): a large
+discrepancy means the internal accounting is lying (or the ack/ingest
+hops dominate — the docs say how to tell).
 
 Wire protocol (parent <-> child):
 

@@ -34,9 +34,9 @@ The **reconciliation account**: ``snapshot()["journal"]`` re-derives
 the violation/recovery totals from the flight recorder's ring
 (``counts_by_kind`` counts a collapsed burst in full), and
 ``snapshot()["reconciled"]`` asserts they match the watchdog's own
-tallies. ``bench.py --serve`` reads both sides through two different
-REST routes (``/api/v1/slo`` and ``/api/v1/flightrecorder``) and the
-schema gate requires exact agreement — the proof that the journaled
+tallies. tests/test_slo.py reads both sides through two different
+REST routes (``/api/v1/slo`` and ``/api/v1/flightrecorder``) and
+requires exact agreement — the proof that the journaled
 story and the counted story are the same story. (After a supervisor
 restore the journal rolls back to the checkpoint with the rest of the
 job state while a fresh watchdog starts at zero; the job factory

@@ -1,12 +1,11 @@
 #!/usr/bin/env python3
 """Tier-1 static analysis gate: fstlint + plancheck + admission.
 
-Runs alongside scripts/check_bench_schema.py in the tier-1 lane
-(tests/test_static_analysis.py imports and invokes this; CI can also
-call it directly). Exits nonzero on:
+Runs in the tier-1 lane (tests/test_static_analysis.py imports and
+invokes this; CI can also call it directly). Exits nonzero on:
 
 * any unsuppressed fstlint finding over the repo surface
-  (flink_siddhi_tpu/, bench.py, scripts/),
+  (flink_siddhi_tpu/, scripts/),
 * any stale / reason-less / REVIEWME baseline.toml suppression,
 * any plancheck issue over the window/pattern/join/multiquery zoo
   (full tier: static NFA/stack checks + eval_shape schema/donation

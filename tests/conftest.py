@@ -10,11 +10,11 @@ accelerator visible.
 
 import os
 
-# Persistent XLA compilation cache, shared with bench.py: the sharded
+# Persistent XLA compilation cache (chip_smoke.py and
+# benchmark/tests/conftest.py use the same idiom): the sharded
 # (shard_map) and resident-replay tests cost minutes of XLA CPU
 # compile per cold run on the 2-core tier-1 lane; with the cache warm,
-# repeat suite runs skip every unchanged compile. Same knobs bench.py
-# sets — one cache, both consumers.
+# repeat suite runs skip every unchanged compile.
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 os.environ.setdefault(
     "JAX_COMPILATION_CACHE_DIR", os.path.join(_REPO, ".jax_cache")
@@ -33,8 +33,8 @@ os.environ.setdefault("FST_VERIFY_PLANS", "1")
 
 # TPU smoke lane (`FST_TPU_SMOKE=1 python -m pytest -m tpu tests/`):
 # keep the real accelerator backend alive instead of pinning CPU —
-# the only configuration under which the real chip runs result-asserting
-# tests (bench.py asserts nothing; round-3 verdict item 8)
+# the one configuration under which pytest's result-asserting tests
+# run on the real chip (chip_smoke.py is the other asserting run there)
 _TPU_SMOKE = os.environ.get("FST_TPU_SMOKE") == "1"
 
 if not _TPU_SMOKE:

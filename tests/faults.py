@@ -11,7 +11,7 @@ Two injection axes, both seeded, both replayable:
   gets through — the schedule injects pain, not livelock.
 * **process faults** — :class:`CrashPlan` + :func:`wrap_job`
   (re-exported from ``flink_siddhi_tpu.runtime.faultinject``, the one
-  shared implementation that ``bench.py --fault`` also drives) inject
+  implementation) inject
   crashes into a SUPERVISED job at scheduled source-pull boundaries
   and killed-mid-checkpoint; see that module's docstring.
 

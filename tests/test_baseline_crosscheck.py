@@ -14,7 +14,7 @@ of the 64 stacked queries individually.
 import numpy as np
 import pytest
 
-import bench
+from flink_siddhi_tpu.baseline.workloads import config_cql, make_batches
 from flink_siddhi_tpu.baseline import BaselineEngine
 from flink_siddhi_tpu.compiler.config import EngineConfig
 from flink_siddhi_tpu.compiler.plan import compile_plan
@@ -58,8 +58,8 @@ def test_engine_matches_baseline_interpreter(config):
     compare_rows = config in ("headline", "filter")
     schema = _schema()
     n_ids = 1000 if config == "window_groupby" else 50
-    batches = bench.make_batches(n, batch, schema, "inputStream", n_ids)
-    cql = bench._config_cql(config)
+    batches = make_batches(n, batch, schema, "inputStream", n_ids)
+    cql = config_cql(config)
     plan = compile_plan(
         cql, {"inputStream": schema},
         config=EngineConfig(lazy_projection=True, pred_pushdown=True),
@@ -69,8 +69,8 @@ def test_engine_matches_baseline_interpreter(config):
     job = Job(
         [plan],
         [BatchSource("inputStream", schema,
-                     iter(bench.make_batches(n, batch, schema,
-                                             "inputStream", n_ids)))],
+                     iter(make_batches(n, batch, schema,
+                                       "inputStream", n_ids)))],
         batch_size=batch, time_mode="processing", retain_results=False,
     )
     for rt in job._plans.values():

@@ -42,7 +42,6 @@ import time
 import numpy as np
 import pytest
 
-import bench  # noqa: F401  (sets the shared XLA compilation cache dir)
 from flink_siddhi_tpu.compiler.plan import compile_plan
 from flink_siddhi_tpu.runtime.executor import (
     MAX_WM,

@@ -355,6 +355,23 @@ def _make_job(src, ctrl, store=None):
     return job
 
 
+def test_first_row_clock_stamps_the_boot_account_once():
+    """The serving half of cold-start-to-first-row (the old harness's
+    fleet driver was its one reader): the first row stamps
+    ``first_row_s`` into the replica's boot account, later rows leave
+    it alone."""
+    from flink_siddhi_tpu.fleet.bootstrap import FirstRowClock
+
+    boot = {"warm_store": True}
+    clock = FirstRowClock(time.monotonic(), boot)
+    assert "first_row_s" not in boot
+    clock(1_000, (1, 2.0))
+    first = boot["first_row_s"]
+    assert first >= 0
+    clock(2_000, (3, 4.0))
+    assert boot == {"warm_store": True, "first_row_s": first}
+
+
 def test_fleet_block_absent_outside_a_fleet():
     """Single-process jobs keep their payloads unchanged: no store, no
     replica identity → fleet is None everywhere it is surfaced."""

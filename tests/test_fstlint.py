@@ -315,7 +315,7 @@ def test_targeted_run_does_not_report_out_of_scope_stale(tmp_path):
     concept) — and suppressions for the targeted file still apply."""
     bl = tmp_path / "baseline.toml"
     bl.write_text(
-        '[[suppress]]\nrule = "FST103"\npath = "bench.py"\n'
+        '[[suppress]]\nrule = "FST103"\npath = "chip_smoke.py"\n'
         'reason = "covers a file outside this targeted run"\n'
     )
     clean = os.path.join(FIXTURES, "fst103_falsy_zero_good.py")

@@ -29,7 +29,7 @@ in tests/test_pallas_ops.py subprocesses).
 import numpy as np
 import pytest
 
-import bench
+from flink_siddhi_tpu.baseline.workloads import config_cql, make_batches
 from flink_siddhi_tpu.compiler.config import EngineConfig
 from flink_siddhi_tpu.compiler.plan import compile_plan
 from flink_siddhi_tpu.runtime.executor import Job
@@ -102,8 +102,8 @@ def _run(cql, n_ids, seg, n=N, batch=BATCH):
         [plan],
         [BatchSource(
             "inputStream", schema,
-            iter(bench.make_batches(n, batch, schema, "inputStream",
-                                    n_ids)),
+            iter(make_batches(n, batch, schema, "inputStream",
+                              n_ids)),
         )],
         batch_size=batch, time_mode="processing",
     )
@@ -178,7 +178,7 @@ def test_fused_matches_baseline_interpreter(config):
 
     n, batch = 40_000, 4096
     schema = _schema()
-    cql = bench._config_cql(config)
+    cql = config_cql(config)
     plan = compile_plan(
         cql, {"inputStream": schema},
         config=EngineConfig(lazy_projection=True, pred_pushdown=True),
@@ -186,8 +186,8 @@ def test_fused_matches_baseline_interpreter(config):
     job = Job(
         [plan],
         [BatchSource("inputStream", schema,
-                     iter(bench.make_batches(n, batch, schema,
-                                             "inputStream", 50)))],
+                     iter(make_batches(n, batch, schema,
+                                       "inputStream", 50)))],
         batch_size=batch, time_mode="processing", retain_results=False,
     )
     job.fused_segment_len = 3
@@ -205,7 +205,7 @@ def test_fused_matches_baseline_interpreter(config):
     eng._emit = lambda out, ts, row: base_rows.append(
         _norm_row(ts, row)
     )
-    batches = bench.make_batches(n, batch, schema, "inputStream", 50)
+    batches = make_batches(n, batch, schema, "inputStream", 50)
     cols = {
         "id": np.concatenate([b.columns["id"] for b in batches]).tolist(),
         "name": ["test_event"] * n,
@@ -231,8 +231,8 @@ def test_drain_staleness_bounded_under_fused_dispatch():
     job = Job(
         [plan],
         [BatchSource("inputStream", schema,
-                     iter(bench.make_batches(40_000, 2048, schema,
-                                             "inputStream", n_ids)))],
+                     iter(make_batches(40_000, 2048, schema,
+                                       "inputStream", n_ids)))],
         batch_size=2048, time_mode="processing",
     )
     job.fused_segment_len = 16
@@ -309,8 +309,8 @@ def test_checkpoint_forces_segment_boundary(tmp_path):
     job = Job(
         [plan],
         [BatchSource("inputStream", schema,
-                     iter(bench.make_batches(N, BATCH, schema,
-                                             "inputStream", n_ids)))],
+                     iter(make_batches(N, BATCH, schema,
+                                       "inputStream", n_ids)))],
         batch_size=BATCH, time_mode="processing",
     )
     job.fused_segment_len = 16
@@ -364,8 +364,8 @@ def test_fused_h2d_overlap_counters(monkeypatch):
     job = Job(
         [plan],
         [BatchSource("inputStream", schema,
-                     iter(bench.make_batches(N, BATCH, schema,
-                                             "inputStream", n_ids)))],
+                     iter(make_batches(N, BATCH, schema,
+                                       "inputStream", n_ids)))],
         batch_size=BATCH, time_mode="processing",
     )
     job.fused_segment_len = 3

@@ -11,7 +11,7 @@ multi-query stacks that exercise the tape-capacity chunking.
 import numpy as np
 import pytest
 
-import bench
+from flink_siddhi_tpu.baseline.workloads import make_batches
 from flink_siddhi_tpu.compiler.config import EngineConfig
 from flink_siddhi_tpu.compiler.plan import compile_plan
 from flink_siddhi_tpu.runtime.executor import Job
@@ -89,7 +89,7 @@ def test_resident_matches_streaming(case):
     n, batch = 40_000, 4096
 
     def batches(schema):
-        return bench.make_batches(n, batch, schema, "inputStream", n_ids)
+        return make_batches(n, batch, schema, "inputStream", n_ids)
 
     cfg = EngineConfig(lazy_projection=True, pred_pushdown=True)
     a = _run(cql, batches, "streaming", batch, config=cfg)
@@ -115,7 +115,7 @@ def test_resident_matches_streaming_multiquery():
     n, batch = 20_000, 4096
 
     def batches(schema):
-        return bench.make_batches(n, batch, schema, "inputStream", 5)
+        return make_batches(n, batch, schema, "inputStream", 5)
 
     a = _run(cql, batches, "streaming", batch)
     b = _run(cql, batches, "resident", batch)
@@ -212,7 +212,7 @@ def test_rerun_is_deterministic_counts_only():
     cql = CASES["pattern3"][0]
 
     def batches():
-        return bench.make_batches(n, batch, schema, "inputStream", 50)
+        return make_batches(n, batch, schema, "inputStream", 50)
 
     plan = compile_plan(
         cql, {"inputStream": schema},

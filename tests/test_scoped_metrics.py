@@ -557,8 +557,8 @@ def test_prometheus_route_serves_text_format():
 
 def test_build_info_gauge_present_and_parses():
     """Satellite (ISSUE 15): the exposition carries the standard
-    *_info gauge — package version, jax version, backend, bench schema
-    version as labels, value 1 — and the whole document still parses
+    *_info gauge — package version, jax version, backend as labels,
+    value 1 — and the whole document still parses
     under the standalone text-format checker."""
     import jax
 
@@ -575,9 +575,6 @@ def test_build_info_gauge_present_and_parses():
     assert labels["package_version"] == pkg.__version__
     assert labels["jax_version"] == jax.__version__
     assert labels["backend"] == "cpu"
-    assert labels["bench_schema_version"] == str(
-        pkg.BENCH_SCHEMA_VERSION
-    )
 
 
 def test_checker_rejects_malformed_text():

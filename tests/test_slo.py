@@ -8,8 +8,8 @@ carried-verdict preclear path on the control apply (the run loop skips
 the redundant deep re-analysis the service gate already ran —
 observable as ``control.preclear``).
 
-``bench.py --serve`` drives all of this end to end off the REST plane;
-these are the deterministic unit/route versions of the same contracts.
+These are the deterministic unit/route versions of the serving
+contracts, read off the REST plane.
 """
 
 import json

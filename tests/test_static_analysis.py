@@ -1,5 +1,4 @@
-"""scripts/run_static_analysis.py in the tier-1 lane (the analog of
-test_bench_schema.py running check_bench_schema.py): the combined
+"""scripts/run_static_analysis.py in the tier-1 lane: the combined
 lint + plancheck gate must exit 0 on the repo as committed. ``--fast``
 skips only the deep inert-tape zoo executions (run in full by CI /
 direct invocation; tests/test_plancheck.py keeps deep coverage on the

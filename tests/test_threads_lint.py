@@ -194,12 +194,13 @@ def test_sweep_cache_reuses_unchanged_files(tmp_path, monkeypatch):
     # stamp afterwards or the repo's LIVE sweep cache (the tier-1
     # repo-lints-clean gate's) sees a stale whole-set key and pays a
     # full FST2xx re-run on the next real fstlint invocation
-    target = os.path.join(fstlint.REPO_ROOT, "bench.py")
+    target = os.path.join(
+        fstlint.REPO_ROOT, "scripts", "run_static_analysis.py")
     st = os.stat(target)
     try:
         os.utime(target)
         assert fstlint.main([]) == 0
-        assert calls[first:] == ["bench.py"]
+        assert calls[first:] == ["scripts/run_static_analysis.py"]
     finally:
         os.utime(target, ns=(st.st_atime_ns, st.st_mtime_ns))
 

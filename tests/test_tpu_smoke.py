@@ -1,12 +1,12 @@
 """Real-TPU smoke lane: result-ASSERTING runs on the actual chip.
 
-Everything else in tests/ runs on the virtual CPU mesh, and bench.py
-(the only other thing that touches the real device) asserts nothing —
-so f32/Pallas-lowering divergence on hardware would go unseen (round-3
-verdict item 8). This 5-minute lane runs the headline pattern, a
-sliding window aggregation, and a join at small N against the same
-Python oracles the CPU tests use, with Pallas COMPILED (not
-interpreted).
+Everything else in tests/ runs on the virtual CPU mesh, so
+f32/Pallas-lowering divergence on hardware would go unseen there
+(chip_smoke.py checks the served path at deployment size; the
+benchmark's cells decide ``correct``). This 5-minute lane runs the
+headline pattern, a sliding window aggregation, and a join at small N
+against the same Python oracles the CPU tests use, with Pallas COMPILED
+(not interpreted).
 
 Invocation (one process per chip — see .claude/skills/verify):
 
