@@ -24,7 +24,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 import heapq
 import logging
-
+import threading
 import time
 
 from ..compiler import pallas_ops
@@ -33,7 +33,7 @@ from ..compiler.plan import CompiledPlan
 from ..runtime.executor import Job, _PlanRuntime, _staging_allow
 from ..runtime.tape import build_tape, bucket_size
 from ..schema.batch import EventBatch
-from ..telemetry import LatencyHistogram
+from ..telemetry import LatencyHistogram, MetricsRegistry
 from .mesh import SHARD_AXIS, make_cep_mesh
 from .router import Router
 
@@ -260,7 +260,7 @@ class ShardedJob(Job):
         with _staging_allow():
             rt.states = self._grow_stacked(plan, rt.states)
         # per-shard on-device accumulation; no fetch in the hot loop
-        # (drained in bulk by _drain_plan, same as the single-device Job).
+        # (drained in bulk through Job's drain queue and fetch thread).
         # The tape is committed, one row per chip: the call moves nothing
         with tel.span("dispatch"):
             rt.states, rt.acc = rt.jitted_acc(
@@ -302,210 +302,181 @@ class ShardedJob(Job):
         tel.inc("shard.tape_puts")
         return stacked
 
-    def prewarm_drains(self, widths=None) -> None:
-        # no-op: Job's packed-drain programs (jit_pack, one per fetch
-        # width) serve its fetch thread; a sharded drain still slices
-        # the stacked accumulator directly, on the run loop
-        return
+    # -- drain: Job's queue and fetch thread; two seams know the rank ------
+    # ShardedJob inherits the whole drain (request, readiness, FIFO
+    # poll, backlog wait, the blocking barrier, prewarm_drains) and
+    # supplies only what knows that the accumulator is stacked
+    # ``[shards, ...]``: the slice program of a fetch width and the
+    # fetch-thread body.
 
-    def drain_outputs(self, wait: bool = True) -> None:
-        # still synchronous, on the run loop: request, two blocking
-        # fetches, decode, merge and emit before the next cycle (Job's
-        # wait=False fetch thread and ticketed readiness are not wired
-        # here). What IS shared with Job is the host side after the
-        # fetch: drain_decode's columnar lane, ColumnBatch and
-        # _emit_columns (see _drain_plan_body)
-        for rt in self._plans.values():
-            self._drain_plan(rt)
+    # smallest fetch bucket of a sharded drain. A sharded slice program
+    # is under the persistent cache's threshold and compiles every run,
+    # so prewarm_drains pays for each width from here to the capacity:
+    # the floor keeps that to ten programs at the default budget, and a
+    # sparse drain's fetch to 16,384 slots a shard
+    MIN_FETCH_WIDTH = 1 << 14
 
-    def _interval_drain(self) -> None:
-        for rt in self._plans.values():
-            if self._has_consumers(rt):
-                self._drain_plan(rt)
+    @staticmethod
+    def _pack_data(rt: _PlanRuntime, acc: Dict, width: int):
+        """Job._pack_data over the stacked accumulator: one device array
+        holding ``buf[:, :, :width]``, each shard's slice cut on its own
+        chip, jitted once a width."""
+        jits = getattr(rt, "pack_jits", None)
+        if jits is None:
+            # fst:threadsafe lazy idempotent init, GIL-atomic dict ops (as Job._pack_data): prewarm (run loop) and the fetch thread may race the first width; the loser's entry is identical
+            jits = rt.pack_jits = {}
+        fn = jits.get(width)
+        if fn is None:
+            # fst:hotpath
+            def pack(a, _w=width):
+                shards, rows, _ = a["buf"].shape
+                return jax.lax.slice(
+                    a["buf"], (0, 0, 0), (shards, rows, _w)
+                )
 
-    def _drain_plan(self, rt: _PlanRuntime) -> None:
-        # the drain IS the engine's intended device->host boundary:
-        # gathering the sharded accumulator to host (and the scalar
-        # ops the cross-shard gather stages) is the design's own
-        # transfer, so the hot-loop guard must not trip on it
-        with _staging_allow():
-            with self.telemetry.span("drain"):
-                self._drain_plan_body(rt)
+            fn = jits[width] = jax.jit(pack)
+        return fn(acc)
 
-    def _drain_plan_body(self, rt: _PlanRuntime) -> None:
-        if rt.acc is None or not rt.plan.artifacts:
-            return
-        # footprint meter poll (same drain-boundary contract as Job):
-        # leaf nbytes sums whole stacked shards — metadata only
-        self._update_footprint(rt)
-        t_dirty = rt.dirty_since
-        rt.acc_dirty = False
-        rt.dirty_since = None
-        tel = self.telemetry
-        t_req = time.monotonic()
-        # the drain's legs, each a profiler annotation and a histogram:
-        # drain.fetch (request -> both fetches done), drain.decode (the
-        # per-shard decodes, summed), drain.emit (merge, emit, sinks).
-        # The lane is picked per stream from the sinks, by Job's own
-        # rule: a stream in ``columnar`` (retention off, every sink has
-        # accept_columns, no snapshot limiter) decodes to one
-        # ColumnBatch a shard, merges with one argsort and leaves
-        # through _emit_columns — no Python row exists between the
-        # chips and the sink. Every other stream takes the row lane
-        # below (decode to tuples, heapq.merge, _emit_rows), which is
-        # also the oracle the tests hold the columnar lane to.
-        columnar = self._columnar_streams(rt)
-        with tel.annotate("fst.drain.fetch"):
-            meta = np.asarray(rt.acc["meta"])  # (shards, 2, A) — one fetch
+    @staticmethod
+    # fst:thread-root name=drain-fetch
+    def _fetch_acc(rt: _PlanRuntime, acc: Dict, want: bool,
+                   columnar: frozenset,
+                   stages: Dict, tel: MetricsRegistry, drain: int):
+        """Job._fetch_acc over the stacked accumulator, on the same one
+        fetch thread: the count prefix of every shard in one fetch
+        (``meta`` is ``(shards, 2, A)``), one data fetch at the width
+        bucketed from the fullest shard, ``drain_decode`` per shard,
+        and the cross-shard merge — so the run loop receives what
+        Job's does, ``{artifact: [(schema, payload)]}`` with counts and
+        overflow summed over shards, and only emits.
+
+        The lane is per stream, by Job's rule (``columnar``, resolved
+        at request time): a columnar stream decodes to one ColumnBatch
+        a shard and merges with one argsort (ColumnBatch.merge_by_ts);
+        every other stream decodes to tuples and merges with
+        heapq.merge — the oracle the tests hold the columnar lane to.
+        Both orders are the same: by timestamp, ties to the lower
+        shard.
+
+        Booked here as each ends: ``drain.fetch``, ``drain.decode``
+        (the shards' decodes, summed; each shard's own into its
+        histogram on the runtime, folded by metrics()), ``drain.merge``;
+        ``stages["merge_s"]`` is the half of ``drain.emit`` this thread
+        did and ``stages["off_loop"]`` whether it was the fetch thread
+        (_drain_poll_inner books both)."""
+        with tel.annotate("fst.drain.fetch", drain=drain):
+            stages["t_fetch0"] = time.monotonic()
+            meta = np.asarray(acc["meta"])  # phase one, every shard's
             counts, overflow = meta[:, 0], meta[:, 1]
-            seen = getattr(rt, "_overflow_seen", None)
-            already = 0 if seen is None else int(np.sum(seen))
-            total = int(overflow.sum())
-            if total > already:  # log new drops once, not per check
-                _LOG.warning(
-                    "%s: %d emissions dropped across shards (accumulator "
-                    "full; raise EngineConfig.acc_budget_bytes or drain "
-                    "more often)", rt.plan.plan_id, total - already,
-                )
-                tel.inc("faults.emissions_dropped", total - already)
-            rt._overflow_seen = overflow
             max_n = int(counts.max()) if counts.size else 0
-            if max_n == 0:
-                return
-            # bucketed fetch width: stable slice shapes (see
-            # Job._drain_plan)
-            fetch_n = min(bucket_size(max_n, minimum=1024),
-                          rt.plan.acc_capacity())
-            data = np.asarray(
-                rt.acc["buf"][:, :, :fetch_n]
-            )[:, :, :max_n]  # fetch two
-        tel.record_seconds("drain.fetch", time.monotonic() - t_req)
-        rt.acc = rt.jitted_init_acc()
-        rt._overflow_seen = None  # counters reset with the accumulator
-        # per-shard decode-time histograms, kept PER SHARD on the
-        # runtime and folded into the job registry after the sweep —
-        # the mergeable-across-shards histogram contract in production
-        # use (tests assert merge associativity)
-        shard_hists = getattr(rt, "_shard_decode_hists", None)
-        if shard_hists is None and tel.enabled:
-            shard_hists = rt._shard_decode_hists = [
-                LatencyHistogram() for _ in range(self.n_shards)
-            ]
-        # per-event traces complete PER SHARD into per-shard histograms
-        # (merged by metrics() — the same cross-shard fold as the decode
-        # hists). Rate-limited streams are excluded: their rows may be
-        # thinned at emission, and a thinned row must not stop the
-        # clock — those complete post-limiter in _emit_rows or
-        # _emit_columns instead (into the base trace.e2e, without
-        # per-shard attribution).
-        shard_trace = getattr(rt, "_shard_trace_hists", None)
-        if shard_trace is None and self.tracer.enabled:
-            shard_trace = rt._shard_trace_hists = [
-                LatencyHistogram() for _ in range(self.n_shards)
-            ]
-        # merge each output's per-shard (already time-ordered) payloads
-        # by timestamp so sinks observe near-monotonic time across shards
-        per_schema = {}
-        decode_s = 0.0
-        epoch = self._epoch_ms or 0
-        for s in range(self.n_shards):
-            with tel.annotate("fst.drain.decode", shard=s):
-                t0 = time.perf_counter()
-                decoded = rt.plan.drain_decode(
-                    counts[s], data[s], columnar_streams=columnar
+            stages["t_meta"] = time.monotonic()
+            data = None
+            if want and max_n:
+                width = min(
+                    bucket_size(
+                        max_n, minimum=ShardedJob.MIN_FETCH_WIDTH
+                    ),
+                    rt.plan.acc_capacity(),
                 )
-                dt = time.perf_counter() - t0
-            decode_s += dt
-            if shard_hists is not None:
-                shard_hists[s].record_seconds(dt)
-            for a in rt.plan.artifacts:
-                # a payload is a ColumnBatch (columnar lane) or a list
-                # of (ts, row) pairs; len() counts rows of either
-                for schema, payload in decoded.get(a.name) or []:
-                    if (
-                        shard_trace is not None
-                        and schema.stream_id not in self._rate_limiters
-                    ):
-                        if isinstance(payload, ColumnBatch):
-                            self.tracer.complete_ts(
-                                epoch, payload.ts, hist=shard_trace[s]
-                            )
-                        else:
-                            self.tracer.complete_rows(
-                                epoch, payload, hist=shard_trace[s]
-                            )
-                    if tel.enabled:
-                        # pre-rate-limit match attribution, summed
-                        # across shards (same scope the single-device
-                        # drain records into — the merged cross-shard
-                        # view falls out of one registry)
-                        sc = self._attr_scope(schema)
-                        if sc is not None:
-                            sc.inc("matches", len(payload))
-                    per_schema.setdefault(
-                        schema.stream_id, (schema, [])
-                    )[1].append(payload)
-        tel.record_seconds("drain.decode", decode_s)
-        t_emit = time.monotonic()
-        n_rows = n_columnar = 0  # handed to the emit tails, pre-limiter
-        with tel.annotate("fst.drain.emit"):
-            for schema, parts in per_schema.values():
-                n = sum(len(p) for p in parts)
-                n_rows += n
-                if all(isinstance(p, ColumnBatch) for p in parts):
-                    # heapq.merge's order exactly, ties included (equal
-                    # timestamps: the lower shard first). Traces of an
-                    # unlimited stream completed per shard above, so
-                    # _emit_columns' own completion finds none pending;
-                    # a rate-limited one completes there, post-limiter
-                    n_columnar += n
-                    self._emit_columns(
-                        schema, ColumnBatch.merge_by_ts(parts)
+                data = np.asarray(
+                    ShardedJob._pack_data(rt, acc, width)
+                )[:, :, :max_n]
+            stages["t_dec0"] = time.monotonic()
+        tel.record_seconds(
+            "drain.fetch", stages["t_dec0"] - stages["t_fetch0"]
+        )
+        # stream id -> (artifact that first wrote it, schema, the
+        # shards' payloads in shard order): artifacts that write one
+        # stream merge into one emission
+        streams: Dict[str, Tuple] = {}
+        with tel.annotate("fst.drain.decode", drain=drain):
+            if data is not None:
+                shard_hists = getattr(rt, "_shard_decode_hists", None)
+                if shard_hists is None:
+                    shard_hists = rt._shard_decode_hists = [
+                        LatencyHistogram() for _ in range(len(counts))
+                    ]
+                for s, hist in enumerate(shard_hists):
+                    t0 = time.perf_counter()
+                    shard = rt.plan.drain_decode(
+                        counts[s], data[s], columnar_streams=columnar
                     )
-                    continue
-                # a stream that decoded rows anywhere (a stacked group
-                # writes rows into a stream a plain artifact writes
-                # columns into) stays whole on the row lane
-                shard_rows = [
-                    p.rows() if isinstance(p, ColumnBatch) else p
-                    for p in parts
-                ]
-                if self._sinks.get(schema.stream_id):
-                    # sinks observe emission order: merge shards by
-                    # timestamp
-                    rows = list(
-                        heapq.merge(*shard_rows, key=lambda p: p[0])
-                    )
-                else:
-                    # collectors re-sort on read; skip the per-row merge
-                    rows = [r for sh in shard_rows for r in sh]
-                # traces already completed per shard above, except for
-                # rate-limited streams (completed post-limiter here)
-                self._emit_rows(
-                    schema, rows,
-                    trace=schema.stream_id in self._rate_limiters,
-                )
-        if tel.enabled:
-            # same semantics as Job's drain.total: meta check -> rows
-            # emitted (the timestamp merge and sink delivery included),
-            # so the metric is comparable across job kinds
-            now = time.monotonic()
-            tel.record_seconds("drain.emit", now - t_emit)
-            tel.record_seconds("drain.total", now - t_req)
-            stale = None
-            if t_dirty is not None and self._has_consumers(rt):
-                # same contract as Job: age of the oldest undrained
-                # match when its drain completed — consumer-visible
-                # drains only (capacity swaps of unobserved plans are
-                # not the scheduler's report card)
-                stale = now - t_dirty
-                tel.record_seconds("drain.staleness", stale)
-            tel.inc("drains.completed")
+                    hist.record_seconds(time.perf_counter() - t0)
+                    for a in rt.plan.artifacts:
+                        for schema, payload in shard.get(a.name) or []:
+                            streams.setdefault(
+                                schema.stream_id, (a.name, schema, [])
+                            )[2].append(payload)
+            t_merge0 = time.monotonic()
+        tel.record_seconds("drain.decode", t_merge0 - stages["t_dec0"])
+        decoded = None
+        if data is not None:
+            decoded = {a.name: [] for a in rt.plan.artifacts}
+            n_rows = n_columnar = 0  # handed to the emit tails
+            with tel.annotate("fst.drain.merge", drain=drain):
+                for name, schema, parts in streams.values():
+                    n = sum(len(p) for p in parts)
+                    n_rows += n
+                    if all(isinstance(p, ColumnBatch) for p in parts):
+                        n_columnar += n
+                        merged = ColumnBatch.merge_by_ts(parts)
+                    else:
+                        # a stream that decoded rows anywhere (a stacked
+                        # group writes rows into a stream a plain
+                        # artifact writes columns into) stays whole on
+                        # the row lane
+                        merged = list(heapq.merge(
+                            *(
+                                p.rows() if isinstance(p, ColumnBatch)
+                                else p
+                                for p in parts
+                            ),
+                            key=lambda r: r[0],
+                        ))
+                    decoded[name].append((schema, merged))
+            stages["merge_s"] = time.monotonic() - t_merge0
+            tel.record_seconds("drain.merge", stages["merge_s"])
             # how often the columnar lane engages: rows this drain
-            # handed to _emit_columns over all rows it handed on
+            # hands to _emit_columns over all rows it hands on
             tel.inc("drain.rows", n_rows)
             tel.inc("drain.rows_columnar", n_columnar)
-            self._scoped_drain_record(rt, now - t_req, stale)
+        stages["t_fetch1"] = time.monotonic()
+        # for drains.fetched_off_loop: where all of the above ran
+        stages["off_loop"] = threading.current_thread().name.startswith(
+            "fst-fetch"
+        )
+        return counts.sum(axis=0), overflow.sum(axis=0), decoded
+
+    def _drain_poll_inner(
+        self, rt: _PlanRuntime, block: bool = False, limit: int = 0
+    ) -> None:
+        """Job's poll, one drain a call, so that each completed drain
+        books its own ``drain.emit`` (merge + emit + sinks): the
+        merge's seconds from the fetch thread plus the emission's here,
+        which starts when the poll has the fetch's result — at entry,
+        or when the fetch thread finished if the poll waited for it.
+        ``drains.fetched_off_loop`` is bumped here too, in the poll
+        that bumps ``drains.completed``, so that the two agree over
+        any window: a drain fetched, decoded and merged anywhere but on
+        the fetch thread is missing from it."""
+        tel = self.telemetry
+        done = 0
+        while rt.drain_q and not (limit and done >= limit):
+            head = rt.drain_q[0]
+            t0 = time.monotonic()
+            super()._drain_poll_inner(rt, block, 1)
+            if rt.drain_q and rt.drain_q[0] is head:
+                return  # not fetched yet, and not asked to wait
+            done += 1
+            stages = head.get("stages") or {}
+            if stages.get("off_loop"):
+                tel.inc("drains.fetched_off_loop")
+            if tel.enabled and "merge_s" in stages:
+                tel.record_seconds(
+                    "drain.emit",
+                    stages["merge_s"] + time.monotonic()
+                    - max(t0, stages["t_fetch1"]),
+                )
 
     def flush(self) -> None:
         for rt in self._plans.values():
@@ -546,14 +517,6 @@ class ShardedJob(Job):
             pid: [int(x) for x in r.routed]
             for pid, r in list(self._routers.items())
         }
-        # fold per-shard trace histograms into the trace view's e2e
-        m["telemetry"]["trace"] = self.tracer.snapshot(
-            extra_hists=[
-                h
-                for rt in list(self._plans.values())
-                for h in getattr(rt, "_shard_trace_hists", ())
-            ]
-        )
         return m
 
     # -- results: merge shard-interleaved output back to time order ---------

@@ -2415,8 +2415,7 @@ class Job:
             self._drain_poll(rt, block=True)
 
     def _interval_drain(self) -> None:
-        """Latency-bounding drain pass over plans someone observes
-        (overridden by ShardedJob, whose drains are synchronous).
+        """Latency-bounding drain pass over plans someone observes.
 
         Admission is STALENESS-ORDERED and backlog-aware: only plans
         whose oldest undrained match has reached the staleness budget
@@ -2495,7 +2494,12 @@ class Job:
         back, with ``width`` bucketed from the ACTUAL max match count —
         the transfer is sized to what was matched, never to a predicted
         width (the old fast path shipped a >=1024-wide slice on every
-        drain and paid an extra round trip on misprediction)."""
+        drain and paid an extra round trip on misprediction).
+
+        One of the two places that know the accumulator's rank (the
+        other is _fetch_acc): ShardedJob overrides both for its stacked
+        ``[shards, ...]`` accumulator and inherits the rest of the
+        drain."""
         jits = getattr(rt, "pack_jits", None)
         if jits is None:
             # fst:threadsafe lazy idempotent init, GIL-atomic dict ops: prewarm (run loop) and the fetch thread may race the first width; the loser's entry is identical and a lost insert just recompiles once
@@ -2671,7 +2675,12 @@ class Job:
         into ``drain.fetch`` / ``drain.decode`` HERE, as it ends: the
         busy share of this thread then counts work in the second it was
         done, not at the run loop's next poll. ``stages`` takes the
-        stamps the run loop's own legs need."""
+        stamps the run loop's own legs need.
+
+        Reached as ``self._fetch_acc`` (_advance_ready), so a subclass
+        whose accumulator has another rank supplies its own body and
+        returns the same triple (ShardedJob: counts and overflow summed
+        over shards, the shards' payloads merged)."""
         with tel.annotate("fst.drain.fetch", drain=drain):
             stages["t_fetch0"] = time.monotonic()
             meta = np.asarray(acc["meta"])  # phase one: the count prefix
@@ -2873,12 +2882,8 @@ class Job:
             if limit and done >= limit:
                 return
 
-    def _emit_rows(
-        self, schema, rows, rate_limit: bool = True, trace: bool = True
-    ) -> None:
-        """Shared append-to-collectors/sinks tail for all decode paths.
-        ``trace=False``: the caller already completed these rows'
-        traces (the sharded drain's per-shard path) — skip the scan."""
+    def _emit_rows(self, schema, rows, rate_limit: bool = True) -> None:
+        """Shared append-to-collectors/sinks tail for all decode paths."""
         if not rows:
             return
         sid = schema.stream_id
@@ -2896,11 +2901,10 @@ class Job:
                     return
         self.output_fields.setdefault(sid, schema.field_names)
         epoch = self._epoch_ms or 0
-        if trace:
-            # rows surfacing to a consumer complete their event's trace
-            # (post-rate-limit: a thinned row is not visible, so it
-            # must not stop the clock)
-            self.tracer.complete_rows(epoch, rows)
+        # rows surfacing to a consumer complete their event's trace
+        # (post-rate-limit: a thinned row is not visible, so it
+        # must not stop the clock)
+        self.tracer.complete_rows(epoch, rows)
         sinks = self._sinks.get(sid)
         self.emitted_counts[sid] = self.emitted_counts.get(sid, 0) + len(rows)
         if self.telemetry.enabled:

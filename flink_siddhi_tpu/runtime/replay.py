@@ -456,8 +456,9 @@ class ShardedResidentReplay(ResidentReplay):
     the job's Router, stacked ``[cycles, shards, ...]``, laid out with
     the mesh sharding, and advanced by a scan whose body is the
     shard_map'd step — the mesh analog of Flink's bounded execution of
-    an N-subtask pipeline. Drains stay synchronous (the ShardedJob
-    contract)."""
+    an N-subtask pipeline. Each segment's drain is the blocking form
+    (``Job._drain_plan``): a barrier, not the queued drain of the live
+    loop."""
 
     def __init__(
         self, job, segment_cycles: Optional[int] = None
@@ -608,5 +609,5 @@ class ShardedResidentReplay(ResidentReplay):
                     if rt.dirty_since is None:
                         rt.dirty_since = time.monotonic()
                 with tel.span("replay.drain"):
-                    # ShardedJob drains synchronously
+                    # the blocking form: a barrier a segment
                     job._drain_plan(rt)

@@ -75,3 +75,29 @@ class FaultSchedule:
             self._consecutive += 1
             self.injected.append((seq, api, action))
             return action
+
+
+class HeldFetchThread:
+    """A stalled drain fetch thread: holds a job's one fetch thread so
+    that requested drains stay pending (swapped out and queued, not
+    fetched), until the ``with`` block ends. ``release_after`` opens it
+    from a timer instead, for a caller that is about to block on a
+    pending drain; leaving the block opens it in any case, so a failed
+    assertion cannot leave the pool's thread waiting at exit."""
+
+    def __init__(self, job) -> None:
+        self._gate = threading.Event()
+        self._timer: Optional[threading.Timer] = None
+        job._fetch_pool.submit(self._gate.wait)
+
+    def release_after(self, seconds: float) -> None:
+        self._timer = threading.Timer(seconds, self._gate.set)
+        self._timer.start()
+
+    def __enter__(self) -> "HeldFetchThread":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._gate.set()
+        if self._timer is not None:
+            self._timer.cancel()

@@ -459,6 +459,73 @@ def test_sharded_columnar_and_row_lanes_deliver_identical_data(case, sinks):
         assert mixed == fast, case
 
 
+def _queued_or_blocking(lane, queued):
+    """A mesh-4 keyed group-by with a drain a cycle. ``queued``: the
+    job's one fetch thread is held, so the first five drains are all
+    pending at once (swapped out, queued, not fetched) before any is
+    fetched, decoded, merged and emitted; else every drain is the
+    blocking form, complete before the next cycle."""
+    from flink_siddhi_tpu.parallel import ShardedJob, make_cep_mesh
+    from tests.faults import HeldFetchThread
+
+    schema = _schema()
+    plan = compile_plan(
+        SHARDED_CASES["keyed_group_by"]["cql"], {"s": schema},
+        config=EngineConfig(acc_budget_bytes=1 << 20),
+    )
+    job = ShardedJob(
+        [plan],
+        [BatchSource(
+            "s", schema, iter(_make_batches(schema, chunk=500, n_ids=16))
+        )],
+        mesh=make_cep_mesh(4),
+        batch_size=500,
+        retain_results=False,
+    )
+    delivered = []
+    if lane == "columnar":
+        sink = _Recorder(plan.output_streams()["out"][0].field_names)
+        delivered = sink.rows
+        job.add_sink("out", sink)
+    else:
+        job.add_sink(
+            "out", lambda ts, row: delivered.append((ts, tuple(row)))
+        )
+    (rt,) = job._plans.values()
+    if queued:
+        with HeldFetchThread(job):
+            for k in range(1, 6):
+                job.run_cycle()
+                job.drain_outputs(wait=False)
+                assert len(rt.drain_q) == k
+            assert not delivered  # five drains pending, none emitted
+    while not job.finished:
+        job.run_cycle()
+        job.drain_outputs(wait=not queued)
+    job.flush()
+    assert not rt.drain_q
+    return delivered, job.metrics()["telemetry"]["counters"]
+
+
+@pytest.mark.parametrize("lane", ["columnar", "row"])
+def test_queued_sharded_drain_delivers_what_a_blocking_one_does(lane):
+    """ShardedJob drains through Job's drain queue and fetch thread
+    (ISSUE 30): with several drains pending, the rows and their order
+    are those of blocking drains on either lane, every drain was
+    fetched, decoded and merged off the run loop, and the lane counters
+    say which lane ran."""
+    got, c = _queued_or_blocking(lane, queued=True)
+    want, _ = _queued_or_blocking(lane, queued=False)
+    assert len(want) == 4000  # one row per event
+    assert got == want
+    assert [t for t, _ in got] == sorted(t for t, _ in got)
+    assert c["drains.fetched_off_loop"] == c["drains.completed"] >= 8
+    assert c["drain.rows"] == 4000
+    assert c.get("drain.rows_columnar", 0) == (
+        4000 if lane == "columnar" else 0
+    )
+
+
 def test_merge_by_ts_is_heapq_merge_ties_included():
     rng = np.random.default_rng(7)
     parts, row_parts = [], []

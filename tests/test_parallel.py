@@ -20,6 +20,7 @@ from flink_siddhi_tpu.parallel import Router, ShardedJob, make_cep_mesh
 from flink_siddhi_tpu.query.planner import StreamPartition
 from flink_siddhi_tpu.runtime.executor import Job
 from flink_siddhi_tpu.schema.batch import EventBatch
+from tests.faults import HeldFetchThread
 
 
 @dataclasses.dataclass
@@ -532,3 +533,123 @@ def test_sharded_run_under_transfer_guard_puts_once_a_cycle(monkeypatch):
     assert telemetry["stages"]["nested.shard_put"]["count"] == cycles
     # nested in tape_build, never a top-level span of the run loop
     assert "shard_put" not in telemetry["stages"]
+
+
+# -------------------------------------------------------------------------
+# the queued drain: Job's drain queue and fetch thread on a mesh (mesh-4)
+# -------------------------------------------------------------------------
+
+
+def _queued_job(n_events=640, batch_size=64):
+    """A mesh-4 keyed group-by whose accumulator is the smallest there
+    is (65,536 slots a shard), rows retained: the row lane."""
+    from flink_siddhi_tpu.compiler.config import EngineConfig
+
+    env = CEPEnvironment(batch_size=batch_size)
+    events = make_events(n_events, id_mod=13)
+    env.register_stream("S", events, FIELDS)
+    plan = compile_plan(
+        _GROUPBY_CQL, {"S": env.schemas["S"]}, extensions=env.extensions,
+        config=EngineConfig(acc_budget_bytes=1 << 20),
+    )
+    job = ShardedJob(
+        [plan], [env.sources["S"]], mesh=make_cep_mesh(4),
+        batch_size=batch_size,
+    )
+    return job, next(iter(job._plans.values()))
+
+
+def _queue_drains(job, rt, n):
+    for k in range(1, n + 1):
+        assert job.run_cycle() > 0
+        job.drain_outputs(wait=False)
+        assert len(rt.drain_q) == k
+
+
+def test_blocking_drain_and_flush_are_barriers_over_pending_drains():
+    """drain_outputs(wait=True) and flush() complete every pending
+    sharded drain, in order, before they return: results() reads all
+    rows of the events stepped so far, none twice."""
+    job, rt = _queued_job()
+    with HeldFetchThread(job) as held:
+        _queue_drains(job, rt, 3)
+        assert not job.collected.get("out")
+        held.release_after(0.2)
+        job.drain_outputs(wait=True)
+        assert not rt.drain_q
+        assert len(job.collected["out"]) == 3 * 64
+    with HeldFetchThread(job) as held:
+        _queue_drains(job, rt, 3)
+        held.release_after(0.2)
+        job.flush()
+        assert not rt.drain_q
+        assert len(job.collected["out"]) == 6 * 64
+    job.run()
+    got = job.results_with_ts("out")
+    single = build_job(
+        _GROUPBY_CQL, {"S": make_events(640, id_mod=13)}, sharded=False
+    )
+    single.run()
+    assert got == sorted(single.results_with_ts("out"), key=lambda p: p[0])
+    c = job.metrics()["telemetry"]["counters"]
+    assert c["drains.fetched_off_loop"] == c["drains.completed"] >= 7
+
+
+def test_more_pending_sharded_drains_than_the_bound_is_the_backlog_span():
+    """The seventh pending drain blocks the run loop on the oldest:
+    the span drain.backlog_wait, as on one chip."""
+    job, rt = _queued_job()
+    assert job.MAX_PENDING_DRAINS == 6
+    with HeldFetchThread(job) as held:
+        _queue_drains(job, rt, 6)
+        assert "nested.drain.backlog_wait" not in (
+            job.telemetry.snapshot()["stages"]
+        )
+        held.release_after(0.2)
+        job.run_cycle()
+        job.drain_outputs(wait=False)  # the seventh: waits for the first
+        assert len(rt.drain_q) <= 6
+    waits = job.telemetry.snapshot()["stages"]["nested.drain.backlog_wait"]
+    assert waits["count"] == 1 and waits["seconds"] > 0.1
+    job.run()
+    assert len(job.results("out")) == 640
+
+
+def test_an_exception_on_the_fetch_thread_surfaces_on_the_run_loop():
+    job, rt = _queued_job(n_events=128)
+
+    def broken(*args, **kw):
+        raise RuntimeError("decode fell over")
+
+    object.__setattr__(rt.plan, "drain_decode", broken)
+    job.run_cycle()
+    job.drain_outputs(wait=False)  # queued: the fetch thread raises
+    with pytest.raises(RuntimeError, match="decode fell over"):
+        job.drain_outputs(wait=True)
+
+
+def test_after_prewarm_a_steady_sharded_run_lowers_no_program():
+    """prewarm_drains compiles the sharded slice program of every fetch
+    width between ShardedJob.MIN_FETCH_WIDTH and the capacity; after it
+    and the first cycles, a run with a drain a cycle lowers nothing."""
+    from flink_siddhi_tpu.telemetry import compile_events
+
+    job, rt = _queued_job()
+    with compile_events.watch() as warm:
+        job.prewarm_drains()
+    assert sorted(rt.pack_jits) == [1 << 14, 1 << 15, 1 << 16]
+    assert warm.count == 3
+    for _ in range(2):
+        job.run_cycle()
+        job.drain_outputs(wait=True)
+    before = job.metrics()["compiles"]["total_lowerings"]
+    with compile_events.watch() as steady:
+        while not job.finished:
+            job.run_cycle()
+            job.drain_outputs(wait=False)
+        job.drain_outputs(wait=True)
+    assert steady.count == 0, steady.durations
+    assert job.metrics()["compiles"]["total_lowerings"] == before
+    assert len(job.results("out")) == 640
+    c = job.metrics()["telemetry"]["counters"]
+    assert c["drains.fetched_off_loop"] == c["drains.completed"] >= 10
