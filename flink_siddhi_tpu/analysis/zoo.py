@@ -73,6 +73,12 @@ PLAN_ZOO: Dict[str, str] = {
         "as b on a.id == b.vol "
         "select a.id, b.price insert into out"
     ),
+    "tumbling_window_join": (
+        "from S[id > 0]#window.hop(timestamp, 10 sec, 10 sec) as a join "
+        "Trades#window.hop(timestamp, 10 sec, 10 sec) as b "
+        "on a.id == b.vol "
+        "select a.id as id, count() as n group by a.id insert into out"
+    ),
     "join_groupby_rewrite": (
         "from S#window.length(8) as a join Trades#window.length(8) "
         "as b on a.id == b.vol "

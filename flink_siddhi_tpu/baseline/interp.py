@@ -413,11 +413,75 @@ class _HopWindowGroupBy:
         g[1] += 1
 
 
+class _WindowJoin:
+    """``from L[f]#window.hop(ts, size, size) as l join
+    R[f]#window.hop(ts, size, size) as r on l.k == r.k select l.k,
+    count() group by l.k``: per tumbling window two dicts, the left and
+    the right events of each key. An event of either side at or past a
+    window's end closes it: one row for every key both sides touched, in
+    key order, ``count()`` the key's pairs, stamped with the window's
+    last millisecond (compiler/window_join.py states the semantics)."""
+
+    def __init__(self, q: ast.Query):
+        inp = q.input
+        self.sides = []  # (filters, ts attribute, key attribute)
+        for si in (inp.left, inp.right):
+            ts_attr, size, slide = si.windows[0].args
+            if size.ms != slide.ms:
+                raise SiddhiQLError(
+                    "baseline interpreter: a window join tumbles"
+                )
+            self.size = size.ms
+            key = next(
+                a for a in (inp.on.left, inp.on.right)
+                if a.qualifier == si.ref_name
+            )
+            self.sides.append((
+                [_compile_scalar(f) for f in si.filters],
+                ts_attr.name, key.name,
+            ))
+        # each select item: True the key, False count()
+        self.items = [
+            not isinstance(it.expr, ast.Call) for it in q.selector.items
+        ]
+        self.out = q.output_stream
+        self.panes: Dict[int, Tuple[Dict, Dict]] = {}
+        self.cur: Optional[int] = None
+
+    def _close(self, q: int, emit) -> None:
+        left, right = self.panes.pop(q - 1, ({}, {}))
+        for key in sorted(left):
+            if key in right:
+                pairs = left[key] * right[key]
+                emit(self.out, q * self.size - 1,
+                     tuple(key if k else pairs for k in self.items))
+
+    def on_event(self, ev, ts, emit):
+        for side, (filters, ts_name, key_name) in enumerate(self.sides):
+            if all(f(ev) for f in filters):
+                break
+        else:
+            return
+        p = max(ev[ts_name] // self.size,
+                self.cur if self.cur is not None else -(2 ** 62))
+        if self.cur is None:
+            self.cur = p
+        while self.cur < p:
+            if not self.panes:  # a gap in the stream: nothing to close
+                self.cur = p
+                break
+            self.cur += 1
+            self._close(self.cur, emit)
+        counts = self.panes.setdefault(p, ({}, {}))[side]
+        counts[ev[key_name]] = counts.get(ev[key_name], 0) + 1
+
+
 class BaselineEngine:
     """Per-event interpreter for the benchmark CQL surface: stateless
     filters, every-chains with within, strict sequences (quantifiers +
-    absence), sliding length-window group-by aggregation, and the hop
-    window with its per-window maximum.
+    absence), sliding length-window group-by aggregation, the hop
+    window with its per-window maximum, and the tumbling-window join
+    (over one stream: the interpreter routes no streams).
     Multi-query plans fan each event through every query, one runtime
     per query (the reference's operator design)."""
 
@@ -450,6 +514,11 @@ class BaselineEngine:
                     )
                 else:
                     self.handlers.append(_Select(q))
+            elif isinstance(inp, ast.JoinInput) and all(
+                si.windows and si.windows[0].name == "hop"
+                for si in (inp.left, inp.right)
+            ):
+                self.handlers.append(_WindowJoin(q))
             else:
                 raise SiddhiQLError(
                     "baseline interpreter: unsupported input"

@@ -67,6 +67,46 @@ _I32_MAX = 2 ** 31 - 1
 EMIT_ROWS = 4096
 
 
+def pane_clock(mask, ts, slide: int, state: Dict):
+    """Where a micro-batch stands on a pane ring's clock: each event's
+    pane of ``slide`` ms (a late event: the newest pane), whether any
+    event passed, the newest pane before the batch (its first event's,
+    on a fresh ring) and the newest pane the batch reaches."""
+    pane = jnp.floor_divide(ts.astype(jnp.int32), slide)
+    any_ev = mask.any()
+    first = jnp.min(jnp.where(mask, pane, _I32_MAX))
+    cur0 = jnp.where(state["started"], state["cur"], first)
+    pane = jnp.maximum(pane, cur0)  # a late event: the newest pane
+    last = jnp.where(
+        any_ev, jnp.max(jnp.where(mask, pane, _I32_MIN)), cur0
+    )
+    return pane, any_ev, cur0, last
+
+
+def pane_rounds(carry: Dict, mask, pane, any_ev, last, runway: int,
+                fold, close):
+    """Fold a micro-batch into a pane ring and close every window it
+    reached, ``runway`` panes a round: ``fold(c, hi)`` adds the events
+    of panes ``c["folded"] < p <= hi``, ``close(q, c)`` closes the
+    window that ends where pane ``q`` starts. A gap in the stream is
+    skipped in one step once the ring is empty (``c["row_tot"]``)."""
+
+    def round_(c):
+        hi = jnp.minimum(last, c["cur"] + runway)
+        c = fold(c, hi)
+        c = lax.fori_loop(c["cur"] + 1, hi + 1, close, c)
+        # a gap in the stream: with the ring empty, skip to the
+        # pane before the next event's
+        nxt = jnp.min(jnp.where(mask & (pane > hi), pane, _I32_MAX))
+        skip = (c["row_tot"].sum() == 0) & (nxt != _I32_MAX)
+        cur = jnp.where(skip, nxt - 1, hi)
+        return {**c, "cur": cur, "folded": cur}
+
+    return lax.while_loop(
+        lambda c: any_ev & (c["folded"] < last), round_, carry
+    )
+
+
 @dataclass
 class HopWindowArtifact:
     name: str
@@ -171,13 +211,8 @@ class HopWindowArtifact:
             if self.code_key is not None
             else jnp.zeros(E, jnp.int32)
         )
-        pane = jnp.floor_divide(env[self.ts_key].astype(jnp.int32), slide)
-        any_ev = mask.any()
-        first = jnp.min(jnp.where(mask, pane, _I32_MAX))
-        cur0 = jnp.where(state["started"], state["cur"], first)
-        pane = jnp.maximum(pane, cur0)  # a late event: the newest pane
-        last = jnp.where(
-            any_ev, jnp.max(jnp.where(mask, pane, _I32_MIN)), cur0
+        pane, any_ev, cur0, last = pane_clock(
+            mask, env[self.ts_key], slide, state
         )
         row = jnp.mod(pane, P)
         # the slot's key, written by the events themselves
@@ -201,6 +236,7 @@ class HopWindowArtifact:
             "n_out": jnp.asarray(0, jnp.int32),  # rows due (may pass V)
         }
 
+        @jax.named_scope("fst.hop_fold")
         def fold(c, hi):
             sel = mask & (pane > c["folded"]) & (pane <= hi)
             flat = jnp.where(sel, row * G + g, P * G)
@@ -280,21 +316,7 @@ class HopWindowArtifact:
 
             return lax.cond(c["row_tot"][gone] > 0, zero, lambda x: x, c)
 
-        def round_(c):
-            hi = jnp.minimum(last, c["cur"] + R)
-            with jax.named_scope("fst.hop_fold"):
-                c = fold(c, hi)
-            c = lax.fori_loop(c["cur"] + 1, hi + 1, close, c)
-            # a gap in the stream: with the ring empty, skip to the
-            # pane before the next event's
-            nxt = jnp.min(jnp.where(mask & (pane > hi), pane, _I32_MAX))
-            skip = (c["row_tot"].sum() == 0) & (nxt != _I32_MAX)
-            cur = jnp.where(skip, nxt - 1, hi)
-            return {**c, "cur": cur, "folded": cur}
-
-        carry = lax.while_loop(
-            lambda c: any_ev & (c["folded"] < last), round_, carry
-        )
+        carry = pane_rounds(carry, mask, pane, any_ev, last, R, fold, close)
 
         new_state = dict(state)
         new_state["cnt"] = carry["cnt"]

@@ -20,6 +20,16 @@ by whichever event arrives later.
 Outer joins emit the arriving event with zero-filled columns for the missing
 side (the engine has no device-side null; SURVEY.md §7 hard part 1 applies —
 a null-mask column is a planned refinement).
+
+Which query takes which path (``plan._compile_query``): a join whose sides
+carry ``#window.length`` or ``#window.time`` (or no window) is compiled here,
+pairs out, a ``time`` or window-less side holding the last
+``EngineConfig.join_window_capacity`` rows; with ``group by`` / aggregates it
+is first rewritten into this join plus an aggregation over its output
+(``plan._rewrite_aggregated_joins``). A join with ``#window.hop`` on a side is
+the tumbling-window equi-join of ``compiler/window_join.py``: keyed per
+window on the device, one row per key, cost by the events and not by the
+pair grid (docs/window_join.md). A table side goes to ``compiler/table.py``.
 """
 
 from __future__ import annotations
@@ -39,8 +49,6 @@ from .expr import ColumnEnv, ExprResolver, ResolvedAttr, compile_expr
 from .output import OutputField, OutputSchema
 from .window import _window_of
 
-JOIN_WINDOW_CAPACITY = 128  # ring slots per side when the window is
-# unbounded or time-based (bounded-slot policy, SURVEY.md §7 hard part 2)
 JOIN_OUT_FACTOR = 4  # output buffer capacity = factor * tape capacity
 
 

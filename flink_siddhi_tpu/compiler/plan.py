@@ -757,6 +757,14 @@ def compile_plan(
             or k.split(".", 1)[1] in values_read
         ]
 
+    if len(artifacts) == 1:
+        # a column the plan's one artifact reads on the host alone (a
+        # window join's right key: interned, never read on the device)
+        columns = [
+            k for k in columns
+            if k not in getattr(artifacts[0], "host_only_columns", ())
+        ]
+
     spec = TapeSpec(
         stream_codes, tuple(columns), column_types, tuple(encoded),
         device_columns=device_columns,
@@ -1175,6 +1183,14 @@ def _compile_query(
             q, name, schemas, stream_codes, extensions, config
         )
     if isinstance(inp, ast.JoinInput):
+        from .window_join import compile_window_join, is_window_join
+
+        if is_window_join(inp):
+            # both sides under one tumbling window, the ``on`` equality
+            # a key: folded per key on the device, not as a pair grid
+            return compile_window_join(
+                q, name, schemas, stream_codes, extensions, config
+            )
         from .join import compile_join_query
 
         return compile_join_query(
@@ -1192,13 +1208,17 @@ def _rewrite_aggregated_joins(parsed, table_schemas, all_schemas):
     — legal SiddhiQL — compile instead of raising a chaining hint."""
     import dataclasses
 
+    from .window_join import is_window_join
+
     out = []
     changed = False
     for q in parsed.queries:
         inp = q.input
+        # (a window join aggregates per key itself: window_join.py)
         is_stream_join = isinstance(inp, ast.JoinInput) and not (
             inp.left.stream_id in table_schemas
             or inp.right.stream_id in table_schemas
+            or is_window_join(inp)
         )
         sel = q.selector
         has_agg = any(

@@ -2194,6 +2194,22 @@ def host_filter_fns(filters, resolver) -> Optional[List[Callable]]:
     return out
 
 
+def _select_fn(fns: Sequence[Callable]) -> Optional[Callable]:
+    """host columns -> the mask of the rows that pass every filter (None
+    where there is none): what an ``EncodedColumn`` interns."""
+    fns = list(fns)
+    if not fns:
+        return None
+
+    def select_fn(cols):
+        m = np.ones(len(next(iter(cols.values()))), dtype=bool)
+        for f in fns:
+            m = m & np.asarray(f(cols))
+        return m
+
+    return select_fn
+
+
 def _group_encoding(
     name: str,
     group_resolved: List[ResolvedAttr],
@@ -2214,18 +2230,9 @@ def _group_encoding(
     if encoder is None:
         encoder = GroupEncoder()
     out_key = f"@group:{name}"
-    select_fn = None
-    fns = list(host_filters if host_filters is not None else filter_fns)
-    if fns:
-
-        def select_fn(cols, _fns=fns):
-            import numpy as _np
-
-            m = _np.ones(len(next(iter(cols.values()))), dtype=bool)
-            for f in _fns:
-                m = m & _np.asarray(f(cols))
-            return m
-
+    select_fn = _select_fn(
+        host_filters if host_filters is not None else filter_fns
+    )
     enc = EncodedColumn(
         out_key=out_key,
         in_keys=tuple(r.key for r in group_resolved),

@@ -158,6 +158,10 @@ def infer_stream_partitions(
                 put(inp.stream_id, StreamPartition("shuffle"))
         elif isinstance(inp, ast.JoinInput):
             lk, rk = _equi_join_keys(inp.on, inp.left, inp.right)
+            if inp.left.stream_id == inp.right.stream_id and lk != rk:
+                # one stream keyed two ways: no routing serves both
+                # sides, the join's one instance owns the stream
+                lk = rk = None
             if lk and rk:
                 put(inp.left.stream_id, StreamPartition("groupby", (lk,)))
                 put(inp.right.stream_id, StreamPartition("groupby", (rk,)))

@@ -3800,11 +3800,14 @@ class Job:
         encoded = rt.plan.spec.encoded
         if not encoded or not tel.enabled:
             return
-        for name in ("interned", "slots_reused", "expired"):
-            total = sum(e.encoder.stats[name] for e in encoded)
+        for name in sorted({k for e in encoded for k in e.encoder.stats}):
+            total = sum(e.encoder.stats.get(name, 0) for e in encoded)
             seen = rt.group_stats.get(name, 0)
             if total != seen:
-                tel.inc(f"groups.{name}", total - seen)
+                # a key source's own counter carries its full name
+                # (``join.left_events``); the table's are ``groups.*``
+                tel.inc(name if "." in name else f"groups.{name}",
+                        total - seen)
                 rt.group_stats[name] = total
         tel.gauge("groups.live", sum(e.encoder.live for e in encoded))
 
@@ -4074,18 +4077,24 @@ class Job:
         accumulator to empty, so no overflow requires (k+1)*block <= cap;
         the extra /2 keeps the historical safety margin for in-flight
         cycles dispatched between the hint check and the swap."""
-        block = max(
-            (
+        cap = plan.acc_capacity()
+
+        def cycles(a) -> int:
+            if hasattr(a, "safe_cycles"):
+                # a block that is wide for one rare step (a window
+                # join's closing): the artifact knows what fits
+                return a.safe_cycles(tape_capacity, state_of(a.name), cap)
+            block = (
                 a.emit_block_width(tape_capacity, state_of(a.name))
                 if hasattr(a, "emit_block_width")
                 else tape_capacity
-                for a in plan.artifacts
-            ),
-            default=tape_capacity,
-        )
-        cap_cycles = max(
-            1, plan.acc_capacity() // (2 * max(block, 1)) - 1
-        )
+            )
+            return cap // (2 * max(block, 1)) - 1
+
+        cap_cycles = max(1, min(
+            (cycles(a) for a in plan.artifacts),
+            default=cap // (2 * max(tape_capacity, 1)) - 1,
+        ))
         self._drain_hints[plan.plan_id] = cap_cycles
 
     def _decode_outputs(

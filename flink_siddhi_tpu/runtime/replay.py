@@ -411,9 +411,17 @@ class ResidentReplay:
         with job.telemetry.span("tape_build"):
             for i, w in enumerate(wires[:-1]):
                 if _wire_sig(w) != want:
+                    # re-narrowed, not re-interned: the first build's
+                    # group codes stand (slots that expire are not
+                    # handed out the same way twice)
                     wires[i] = build_wire_tape(
                         rt.plan.spec, windows[i], job._epoch_ms,
                         rt.wire_kinds, capacity=rt.tape_capacity,
+                        codes={
+                            e.out_key: w.cols[e.out_key]
+                            for e in rt.plan.spec.encoded
+                            if e.out_key in w.cols
+                        },
                     )[0]
         return wires
 

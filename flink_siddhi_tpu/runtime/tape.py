@@ -49,6 +49,21 @@ def bucket_size(n: int, minimum: int = MIN_BUCKET) -> int:
 
 
 @dataclass(frozen=True)
+class KeySource:
+    """One feed of a slot table that several columns share (the two
+    sides of a window join): an event of ``stream_code`` that passes
+    ``select_fn`` takes ``in_key``'s value as its key. ``tick_key`` is
+    the rebased time column of that side's window, ``counter`` the job
+    counter that counts the events selected."""
+
+    in_key: str
+    stream_code: int
+    select_fn: object = None
+    tick_key: Optional[str] = None
+    counter: Optional[str] = None
+
+
+@dataclass(frozen=True)
 class EncodedColumn:
     """A host-computed dense-code column: rows of ``in_keys`` (for events of
     ``stream_code``) interned through ``encoder`` into ``out_key``. Used for
@@ -75,6 +90,11 @@ class EncodedColumn:
     # once ``retain_ticks`` ticks have passed it
     tick_key: Optional[str] = None
     tick_ms: int = 0
+    # one table fed by several columns, each under its own filter: a
+    # row takes the key of the source that selects it (the sources are
+    # disjoint). Set, it stands in for ``in_keys``, ``stream_code``,
+    # ``select_fn`` and ``tick_key``
+    sources: Tuple[KeySource, ...] = ()
 
 
 @dataclass(frozen=True)
@@ -289,6 +309,7 @@ def build_wire_tape(
     capacity: int | None = None,
     want_prov: bool = True,
     intern_span=None,
+    codes=None,
 ) -> Tuple[WireTape, np.ndarray]:
     """build_tape + narrowing. ``sticky_kinds`` (mutated) remembers each
     column's widest kind seen so widths only ever widen (bounded
@@ -298,7 +319,7 @@ def build_wire_tape(
     """
     tape, prov = build_tape(
         spec, batches, epoch_ms, capacity, want_prov=want_prov,
-        intern_span=intern_span,
+        intern_span=intern_span, codes=codes,
     )
     total = sum(len(b) for b in batches)
     time_cols = {time_key(k) for k in spec.time_columns}
@@ -493,36 +514,62 @@ def _check_i32_span(key: str, vals: np.ndarray, origin: int, why: str):
         )
 
 
-def _intern_groups(spec, batches, cols, stream, total, order, identity):
-    """Group keys -> dense codes (``spec.encoded``), added to ``cols``."""
+def _intern_groups(spec, batches, cols, stream, total, order, identity,
+                   known=None):
+    """Group keys -> dense codes (``spec.encoded``), added to ``cols``.
+    ``known`` (out_key -> the codes an earlier build of these batches
+    interned) are taken as they are: a table whose slots expire hands
+    out other slots the second time."""
+    if not spec.encoded:
+        return
     cap = len(stream)
+    view = {k: v[:total] for k, v in cols.items()}
+
+    def selected(stream_code, select_fn):
+        select = stream[:total] == stream_code
+        if select_fn is not None:
+            select = select & np.asarray(select_fn(view))
+        return select
+
+    def key_column(k):
+        col = view.get(k)
+        if col is None:
+            # the raw column was pruned off the wire (group values
+            # travel as codes); intern from the host batches
+            sid_k, fld_k = k.split(".", 1)
+            col = _merged_stream_values(
+                batches, sid_k, fld_k, total, order, identity,
+                spec.column_types[k].device_dtype
+                if k in spec.column_types
+                else None,
+            )
+            if col is None:
+                col = np.zeros(total, dtype=np.int64)
+        return col
+
     for enc in spec.encoded:
-        select = stream[:total] == enc.stream_code
-        if enc.select_fn is not None:
-            view = {k: v[:total] for k, v in cols.items()}
-            select = select & np.asarray(enc.select_fn(view))
-        in_cols = []
-        for k in enc.in_keys:
-            col = cols.get(k)
-            if col is not None:
-                col = col[:total]
-            else:
-                # the raw column was pruned off the wire (group values
-                # travel as codes); intern from the host batches
-                sid_k, fld_k = k.split(".", 1)
-                col = _merged_stream_values(
-                    batches, sid_k, fld_k, total, order, identity,
-                    spec.column_types[k].device_dtype
-                    if k in spec.column_types
-                    else None,
-                )
-                if col is None:
-                    col = np.zeros(total, dtype=np.int64)
-            in_cols.append(col)
-        tick_col = cols[enc.tick_key][:total] if enc.tick_key else None
-        codes = enc.encoder.intern_rows(
-            in_cols, select, tick_col, enc.tick_ms
-        )
+        if known and enc.out_key in known:
+            codes = np.asarray(known[enc.out_key][:total], dtype=np.int32)
+        elif enc.sources:
+            codes = enc.encoder.intern_sources(
+                [
+                    (
+                        key_column(src.in_key),
+                        selected(src.stream_code, src.select_fn),
+                        view[src.tick_key] if src.tick_key else None,
+                        src.counter,
+                    )
+                    for src in enc.sources
+                ],
+                enc.tick_ms,
+            )
+        else:
+            tick_col = view[enc.tick_key] if enc.tick_key else None
+            codes = enc.encoder.intern_rows(
+                [key_column(k) for k in enc.in_keys],
+                selected(enc.stream_code, enc.select_fn),
+                tick_col, enc.tick_ms,
+            )
         if not enc.materialize:
             continue  # interning side effect only
         col = np.zeros(cap, dtype=np.int32)
@@ -537,6 +584,7 @@ def build_tape(
     capacity: int | None = None,
     want_prov: bool = True,
     intern_span=None,
+    codes=None,
 ) -> Tuple[Tape, np.ndarray]:
     """Merge per-stream batches into one padded, ts-sorted host tape.
 
@@ -546,6 +594,8 @@ def build_tape(
     fills skipped — for callers that never consult it).
     ``intern_span`` (a context-manager factory) is entered around the
     group interning: the executor's nested ``group_intern`` span.
+    ``codes``: group codes that an earlier build of the same batches
+    interned (``_intern_groups``), for a rebuild.
     Arrays are numpy; the jitted step's donate/commit moves them to device.
     """
     total = sum(len(b) for b in batches)
@@ -636,6 +686,12 @@ def build_tape(
         )
         if vals is not None and total:
             vals = vals - origin
+            if len(spec.stream_codes) > 1:
+                # the rows of the other streams hold no value
+                mine = stream[:total] == spec.stream_codes[stream_id]
+                vals = np.where(
+                    mine, vals, vals[mine][0] if mine.any() else 0
+                )
             _check_i32_span(
                 key, vals, origin,
                 "is more than 2**31 ms from the job's clock: a window's "
@@ -646,7 +702,8 @@ def build_tape(
         cols[time_key(key)] = col
 
     with (intern_span or contextlib.nullcontext)():
-        _intern_groups(spec, batches, cols, stream, total, order, identity)
+        _intern_groups(spec, batches, cols, stream, total, order, identity,
+                       codes)
     # wire predicate pushdown: evaluate each host predicate over the
     # merged-order RAW host columns (f64 where the schema says DOUBLE)
     # and add the result as a bool pseudo-column — it ships bit-packed,

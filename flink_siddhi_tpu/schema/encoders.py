@@ -133,6 +133,35 @@ class GroupEncoder:
             self._tick = tick if self._tick is None else max(tick, self._tick)
         return out
 
+    def intern_sources(self, sources, tick_ms: int = 0) -> np.ndarray:
+        """One table fed by several columns: ``sources`` is a list of
+        ``(column, select, tick_col, counter)``; a row takes its key
+        from the source that selects it (the sources are disjoint: the
+        two sides of a window join), and a key has one slot whichever
+        source brought it. The selected values are interned together as
+        one short column; ``stats[counter]`` counts each source's
+        rows."""
+        at, vals, ticks = [], [], []
+        for col, select, tick_col, counter in sources:
+            pos = np.flatnonzero(select)
+            at.append(pos)
+            vals.append(col[pos].astype(np.int64, copy=False))
+            if counter is not None:
+                self.stats[counter] = self.stats.get(counter, 0) + len(pos)
+            if tick_col is not None and len(pos):
+                # the side's last selected row (the stream is in order)
+                ticks.append(int(tick_col[pos[-1]]))
+        merged = np.concatenate(vals)
+        codes = self.intern_rows(
+            [merged], np.ones(len(merged), dtype=np.bool_),
+            np.broadcast_to(np.int64(max(ticks)), merged.shape)
+            if ticks else None,
+            tick_ms,
+        )
+        out = np.zeros(len(sources[0][1]), dtype=np.int32)
+        out[np.concatenate(at)] = codes
+        return out
+
     def _take_slots(self, n_new: int) -> np.ndarray:
         """``n_new`` slots: freed ones first, then fresh ones."""
         take = min(n_new, len(self._free))

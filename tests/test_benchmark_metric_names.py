@@ -34,7 +34,9 @@ from bmlib.sink import DeliverySink, SampleRanges  # noqa: E402
 TINY = {"batch": 4_096}
 TINY_Q5 = {"event_time_rate": 2_000, "batch": 2_000, "fused_segment_len": 2,
            "engine_config": {"hop_group_slots": 8_192}}
-POOL_BATCHES = {"nexmark_q5.replay": 20}
+# (nexmark_q8.replay: a window is ten batches, the pool two windows, and
+# the run's 40 batches close three)
+POOL_BATCHES = {"nexmark_q5.replay": 20, "nexmark_q8.replay": 20}
 WARM_BATCHES, RUN_BATCHES = 8, 40
 
 # Names a sound tiny run does not book, or books only when the timing

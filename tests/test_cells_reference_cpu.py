@@ -47,6 +47,15 @@ TINY = {
         "whole_batches": 30, "fused_segment_len": 2, "open_tail": 4_000,
         "engine_config": {"hop_group_slots": 8_192},
     },
+    # the same stream: a tumbling window is ten batches, a pool cycle two
+    # windows. The stream's 30 whole batches end on a window's end, and
+    # the first event after it (a person: every fiftieth event is) closes
+    # the third window, so nothing is left open that the reference counts
+    "nexmark_q8": {
+        "event_time_rate": 2_000, "batch": 2_000, "pool": 40_000,
+        "whole_batches": 30, "fused_segment_len": 2,
+        "engine_config": {"hop_group_slots": 4_096},
+    },
 }
 
 
