@@ -26,6 +26,7 @@ from ..schema.types import AttributeType
 from .config import DEFAULT_CONFIG, EngineConfig
 from ..extensions.registry import ExtensionRegistry, builtin_registry
 from ..runtime.tape import TapeSpec
+from .compact import front_compact, to_word
 from .expr import ExprResolver
 from .select import compile_select
 
@@ -264,18 +265,14 @@ class CompiledPlan:
         total_rows = sum(r for _, r in layout) or 1
         a_count = max(len(self.artifacts), 1)
         return {
-            # meta[0] = per-artifact emission counts, meta[1] = overflow
+            # meta[0] = per-artifact emission counts, meta[1] = overflow,
+            # meta[2] = aligned appends (front-compactions), meta[3] = of
+            # them, those whose mask was a prefix and scattered nothing
             # (single array so a host drain-check costs ONE fetch)
-            "meta": jnp.zeros((2, a_count), dtype=jnp.int32),
+            "meta": jnp.zeros((4, a_count), dtype=jnp.int32),
             "buf": jnp.zeros((total_rows, self.acc_capacity()),
                              dtype=jnp.int32),
         }
-
-    @staticmethod
-    def _to_i32_row(arr):
-        if arr.dtype == jnp.float32:
-            return jax.lax.bitcast_convert_type(arr, jnp.int32)
-        return arr.astype(jnp.int32)
 
     # fst:hotpath device=states,acc,tape
     def step_acc(self, states: Dict, acc: Dict, tape,
@@ -292,7 +289,7 @@ class CompiledPlan:
         appended to ``acc`` on the device."""
         buf = acc["buf"]
         cap = buf.shape[1]
-        ns, over = acc["meta"][0], acc["meta"][1]
+        ns, over, compactions, identity = acc["meta"]
         new_n, new_over = [], []
         for ai, (a, (row0, _r)) in enumerate(
             zip(self.artifacts, self.acc_layout())
@@ -312,27 +309,21 @@ class CompiledPlan:
                 n = n.astype(jnp.int32)
             elif a.output_mode == "aligned":
                 mask, ts, cols = out
-                n = mask.sum().astype(jnp.int32)
-                # O(V) front-compaction, tape order kept (no sort); all
-                # rows compact through ONE scatter (per-fusion launch
-                # overhead dominates at micro-batch sizes)
-                vlen = int(mask.shape[0])
-                pos = jnp.cumsum(mask.astype(jnp.int32)) - 1
-                dest = jnp.where(mask, pos, vlen)
                 src = jnp.stack(
-                    [self._to_i32_row(r)
+                    [to_word(r)
                      for r in [ts] + [jnp.asarray(c) for c in cols]]
                 )
-                block = (
-                    jnp.zeros_like(src)
-                    .at[:, dest]
-                    .set(src, mode="drop")
-                )
+                # all rows compact through ONE scatter (per-fusion launch
+                # overhead dominates at micro-batch sizes), or through
+                # none where the mask is a prefix already
+                n, block, is_prefix = front_compact(mask, src)
+                compactions = compactions.at[ai].add(1)
+                identity = identity.at[ai].add(is_prefix.astype(jnp.int32))
             else:
                 n, ts, cols = out
                 n = n.astype(jnp.int32)
                 block = jnp.stack(
-                    [self._to_i32_row(r)
+                    [to_word(r)
                      for r in [ts] + [jnp.asarray(c) for c in cols]]
                 )
             v = int(block.shape[1])
@@ -364,7 +355,10 @@ class CompiledPlan:
         if not self.artifacts:
             return new_states, acc
         return new_states, {
-            "meta": jnp.stack([jnp.stack(new_n), jnp.stack(new_over)]),
+            "meta": jnp.stack(
+                [jnp.stack(new_n), jnp.stack(new_over),
+                 compactions, identity]
+            ),
             "buf": buf,
         }
 

@@ -39,6 +39,7 @@ from ..query.lexer import SiddhiQLError
 from ..schema.encoders import GroupEncoder
 from ..schema.types import AttributeType
 from ..runtime.tape import EncodedColumn, time_key
+from .compact import batch_rows, front_compact
 from .expr import (
     ColumnEnv,
     CompiledExpr,
@@ -479,7 +480,8 @@ class SlidingWindowArtifact:
 
         Same semantics as ``_step_matrix`` (window = last C matching
         events / time span; aggregates over the emitting event's group),
-        new machinery: arrivals compact via scatter (not argsort); the
+        new machinery: arrivals compact via scatter (not argsort), or not
+        at all where the mask is a prefix already (compact.py); the
         arrival(+v)/expiry(-v) sequences are each already sorted by
         merge key, so their interleave comes from two searchsorteds; and
         the per-group running sum of the merged sequence is computed in
@@ -497,15 +499,9 @@ class SlidingWindowArtifact:
         ring = state["ring"]
         G = state["groups"].shape[0]
 
-        M = mask.sum()
-        rank = jnp.cumsum(mask) - 1
-        dest = jnp.where(mask, rank, E)  # E -> dropped
-
-        def compact(col, dtype=None):
+        def tape_col(col, dtype=None):
             col = jnp.broadcast_to(jnp.asarray(col), (E,))
-            if dtype is not None:
-                col = col.astype(dtype)
-            return jnp.zeros(E, col.dtype).at[dest].set(col, mode="drop")
+            return col if dtype is None else col.astype(dtype)
 
         # value columns: one per agg arg needing sums, plus squares for
         # stddev, plus an implicit count column. INTEGER sum args are
@@ -532,6 +528,21 @@ class SlidingWindowArtifact:
             )
         }
 
+        # every column the fold reads in arrival order (the ring's copy
+        # of each argument included) goes through ONE front-compaction
+        tape_cols = {"ts": tape_col(tape.ts)}
+        for j in need_sum:
+            tape_cols[f"s{j}"] = tape_col(self.arg_fns[j](env))
+        for j in need_sq:
+            tape_cols[f"q{j}"] = tape_col(self.arg_fns[j](env), jnp.float32)
+        for j in range(len(self.arg_types)):
+            tape_cols[f"a{j}"] = tape_col(
+                self.arg_fns[j](env), ring[f"a{j}"].dtype
+            )
+        if self.code_key is not None:
+            tape_cols["gc"] = tape_col(env[self.code_key], jnp.int32)
+        M, arrivals, is_prefix = front_compact(mask, tape_cols)
+
         def digits(v):
             v = v.astype(jnp.int32)
             return (
@@ -553,7 +564,7 @@ class SlidingWindowArtifact:
             rcols.append(ringv)
 
         for j in need_sum:
-            bv = compact(self.arg_fns[j](env))
+            bv = arrivals[f"s{j}"]
             rv = ring[f"a{j}"]
             if j in int_sum:
                 for d, (bd, rd) in enumerate(
@@ -567,19 +578,19 @@ class SlidingWindowArtifact:
                     rv.astype(jnp.float32),
                 )
         for j in need_sq:
-            v = compact(self.arg_fns[j](env), jnp.float32)
+            v = arrivals[f"q{j}"]
             rv = ring[f"a{j}"].astype(jnp.float32)
             plane(f"q{j}", v * v, rv * rv)
         plane("cnt", jnp.ones(E, jnp.float32), jnp.ones(C, jnp.float32))
         K = len(vcols)
 
         if self.code_key is not None:
-            codes_b = compact(env[self.code_key], jnp.int32)
+            codes_b = arrivals["gc"]
             ring_gc = ring["gc"]
         else:
             codes_b = jnp.zeros(E, jnp.int32)
             ring_gc = jnp.zeros(C, jnp.int32)
-        ts_b = compact(tape.ts)
+        ts_b = arrivals["ts"]
         live_b = jnp.arange(E, dtype=jnp.int32) < M
 
         # concat sequence: ring (oldest C) ++ this batch's arrivals
@@ -733,19 +744,16 @@ class SlidingWindowArtifact:
                 + (wcol(f"s{j}:2") << 22)
             )
 
-        def unsort(concat_vals, dtype):
-            batch_vals = concat_vals[C + jnp.clip(rank, 0)]
-            return jnp.where(mask, batch_vals, 0).astype(dtype)
-
         cnt = wcol("cnt")
         minmax = [a for a in self.aggs if a.kind in ("min", "max")]
         ext = (
             self._blocked_extrema(
-                minmax, ring, codes, live, env, compact, cnt, N
+                minmax, ring, codes, live, arrivals, cnt, N
             )
             if minmax
             else {}
         )
+        concat_rows = {}  # per aggregate, one value per concat position
         for agg in self.aggs:
             if agg.kind == "count":
                 rows = cnt
@@ -780,7 +788,14 @@ class SlidingWindowArtifact:
                         0.0,
                     )
                 )
-            env[agg.slot] = unsort(rows, agg.out_type.device_dtype)
+            concat_rows[agg.slot] = rows
+        # back to tape order: ONE gather for all aggregates (a slice
+        # where the mask is a prefix)
+        by_slot = batch_rows(mask, is_prefix, concat_rows, C)
+        for agg in self.aggs:
+            env[agg.slot] = jnp.where(mask, by_slot[agg.slot], 0).astype(
+                agg.out_type.device_dtype
+            )
 
         gcp = self.group_code_proj or (None,) * len(self.proj_fns)
         cols = tuple(
@@ -805,15 +820,7 @@ class SlidingWindowArtifact:
             "valid": lax.dynamic_slice(live, (M,), (C,)),
         }
         for j, _t in enumerate(self.arg_types):
-            cat = jnp.concatenate(
-                [
-                    ring[f"a{j}"],
-                    compact(
-                        self.arg_fns[j](dict(tape.cols)),
-                        ring[f"a{j}"].dtype,
-                    ),
-                ]
-            )
+            cat = jnp.concatenate([ring[f"a{j}"], arrivals[f"a{j}"]])
             new_ring[f"a{j}"] = lax.dynamic_slice(cat, (M,), (C,))
         if self.code_key is not None:
             cat = jnp.concatenate([ring_gc, codes_b])
@@ -828,7 +835,7 @@ class SlidingWindowArtifact:
         return new_state, (out_mask, tape.ts, cols)
 
     def _blocked_extrema(
-        self, minmax, ring, codes, live, env, compact, cnt, N
+        self, minmax, ring, codes, live, arrivals, cnt, N
     ) -> Dict:
         """min/max for blocked LENGTH windows: FIFO expiry makes a
         window's live members the LAST cnt same-group arrivals — a
@@ -854,9 +861,7 @@ class SlidingWindowArtifact:
         for agg in minmax:
             j = agg.arg_idx
             rv = ring[f"a{j}"]
-            vals = jnp.concatenate(
-                [rv, compact(self.arg_fns[j](env), rv.dtype)]
-            )
+            vals = jnp.concatenate([rv, arrivals[f"a{j}"]])
             combine = jnp.minimum if agg.kind == "min" else jnp.maximum
             if jnp.issubdtype(vals.dtype, jnp.floating):
                 ident = jnp.asarray(

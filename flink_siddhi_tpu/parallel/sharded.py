@@ -344,7 +344,7 @@ class ShardedJob(Job):
                    stages: Dict, tel: MetricsRegistry, drain: int):
         """Job._fetch_acc over the stacked accumulator, on the same one
         fetch thread: the count prefix of every shard in one fetch
-        (``meta`` is ``(shards, 2, A)``), one data fetch at the width
+        (``meta`` is ``(shards, 4, A)``), one data fetch at the width
         bucketed from the fullest shard, ``drain_decode`` per shard,
         and the cross-shard merge — so the run loop receives what
         Job's does, ``{artifact: [(schema, payload)]}`` with counts and
@@ -368,6 +368,7 @@ class ShardedJob(Job):
             stages["t_fetch0"] = time.monotonic()
             meta = np.asarray(acc["meta"])  # phase one, every shard's
             counts, overflow = meta[:, 0], meta[:, 1]
+            Job._book_compactions(tel, meta[:, 2], meta[:, 3])
             max_n = int(counts.max()) if counts.size else 0
             stages["t_meta"] = time.monotonic()
             data = None

@@ -2654,13 +2654,24 @@ class Job:
         return pool
 
     @staticmethod
+    def _book_compactions(tel: MetricsRegistry, aligned, identity) -> None:
+        """Rows 2 and 3 of the count prefix (plan.py ``init_acc``), booked
+        at each drain: the accumulator's aligned appends since the last
+        one and, of them, those whose mask was a prefix already, so that
+        the front-compaction scattered nothing (compiler/compact.py).
+        The step decides on the device, so the host learns it here."""
+        tel.inc("acc.compactions", int(aligned.sum()))
+        tel.inc("acc.compactions_identity", int(identity.sum()))
+
+    @staticmethod
     # fst:thread-root name=drain-fetch
     def _fetch_acc(rt: _PlanRuntime, acc: Dict, want: bool,
                    columnar: frozenset,
                    stages: Dict, tel: MetricsRegistry, drain: int):
         """Fetch-thread body — the TWO-PHASE count-prefix fetch. Phase
         one transfers the tiny meta array (per-artifact counts +
-        overflow). Phase two, only when matches exist and a consumer
+        overflow, and the two compaction counts, booked at once). Phase
+        two, only when matches exist and a consumer
         wants them, dispatches the data slice at a width bucketed from
         the ACTUAL max count and transfers exactly that — an empty
         drain never touches the data buffer, a sparse one ships a
@@ -2685,6 +2696,7 @@ class Job:
             stages["t_fetch0"] = time.monotonic()
             meta = np.asarray(acc["meta"])  # phase one: the count prefix
             counts, overflow = meta[0], meta[1]
+            Job._book_compactions(tel, meta[2], meta[3])
             max_n = int(counts.max()) if counts.size else 0
             stages["t_meta"] = time.monotonic()
             data = None
