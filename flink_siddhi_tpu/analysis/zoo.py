@@ -34,6 +34,11 @@ PLAN_ZOO: Dict[str, str] = {
         "select id, count() as num group by id "
         "having num >= windowMax(num) insert into out"
     ),
+    "keyed_session_window": (
+        "from S[id != 0]#window.session(timestamp, 10 sec, id) "
+        "select id, count() as n, min(timestamp) as t0, sum(price) as s "
+        "group by id insert into out"
+    ),
     "unique_window": (
         "from S#window.unique(id) select id, price insert into out"
     ),

@@ -476,11 +476,109 @@ class _WindowJoin:
         counts[ev[key_name]] = counts.get(ev[key_name], 0) + 1
 
 
+class _SessionWindow:
+    """``#window.session(gap[, key])``, ``#window.session(ts, gap,
+    key)`` and the ``partition with`` form: a dict of open sessions,
+    oldest ``last`` first. The clock is the newest time seen (an older
+    event counts at the clock); a key's event less than ``gap`` after
+    its session's last joins it, else the session is over; after every
+    event each session with ``clock - last >= gap`` closes and emits
+    one row, stamped ``last + gap - 1``, the event's closings in (stamp,
+    key) order (compiler/session_window.py states the semantics).
+    ``flush`` closes what is open."""
+
+    def __init__(self, q: ast.Query, win: ast.Window):
+        inp = q.input
+        self.filters = [_compile_scalar(f) for f in inp.filters]
+        args = win.args
+        self.ts_name = None
+        if len(args) == 3:
+            self.ts_name, args = args[0].name, args[1:]
+        gap = args[0]
+        self.gap = gap.ms if isinstance(gap, ast.TimeLiteral) else gap.value
+        self.key_name = (
+            args[1].name if len(args) == 2
+            else dict(q.partition_with).get(inp.stream_id)
+        )
+        # each select item: ('key', None) | (aggregate, fn of the event)
+        self.items = []
+        for it in q.selector.items:
+            e = it.expr
+            if isinstance(e, ast.Call):
+                arg = e.args[0] if e.args else None
+                on_clock = (
+                    isinstance(arg, ast.Attr) and arg.name == self.ts_name
+                )
+                self.items.append((
+                    e.name.lower() + ("@clock" if on_clock else ""),
+                    _compile_scalar(arg) if arg is not None else None,
+                ))
+            else:
+                self.items.append(("key", None))
+        self.out = q.output_stream
+        self.open: Dict[Any, list] = {}  # key -> [first, last, values]
+        self.clock: Optional[int] = None
+
+    def _row(self, key, s):
+        first, last, vals = s
+        row = []
+        for (kind, _fn), v in zip(self.items, vals):
+            if kind == "key":
+                row.append(key)
+            elif kind == "count":
+                row.append(len(v))
+            elif kind == "sum":
+                row.append(sum(v))
+            elif kind == "avg":
+                row.append(sum(v) / len(v))
+            elif kind in ("min@clock", "max@clock"):
+                row.append(first if kind[:3] == "min" else last)
+            else:
+                row.append(min(v) if kind == "min" else max(v))
+        return last + self.gap - 1, key, tuple(row)
+
+    def on_event(self, ev, ts, emit):
+        for f in self.filters:
+            if not f(ev):
+                return
+        t = ev[self.ts_name] if self.ts_name is not None else ts
+        if self.clock is not None:
+            t = max(t, self.clock)
+        self.clock = t
+        key = ev[self.key_name] if self.key_name is not None else None
+        over = []
+        s = self.open.pop(key, None)
+        if s is not None and t - s[1] >= self.gap:
+            over.append(self._row(key, s))
+            s = None
+        if s is None:
+            s = [t, t, [[] for _ in self.items]]
+        s[1] = t
+        for (_kind, fn), v in zip(self.items, s[2]):
+            v.append(fn(ev) if fn is not None else 1)
+        self.open[key] = s  # the newest ``last`` is at the end
+        while self.open:
+            k = next(iter(self.open))
+            if t - self.open[k][1] < self.gap:
+                break
+            over.append(self._row(k, self.open.pop(k)))
+        for stamp, _k, row in sorted(over, key=lambda r: r[:2]):
+            emit(self.out, stamp, row)
+
+    def flush(self, emit):
+        over = [self._row(k, s) for k, s in self.open.items()]
+        self.open = {}
+        for stamp, _k, row in sorted(over, key=lambda r: r[:2]):
+            emit(self.out, stamp, row)
+
+
 class BaselineEngine:
     """Per-event interpreter for the benchmark CQL surface: stateless
     filters, every-chains with within, strict sequences (quantifiers +
     absence), sliding length-window group-by aggregation, the hop
-    window with its per-window maximum, and the tumbling-window join
+    window with its per-window maximum, the session window (both
+    spellings and ``partition with``; ``flush()`` closes what is open)
+    and the tumbling-window join
     (over one stream: the interpreter routes no streams).
     Multi-query plans fan each event through every query, one runtime
     per query (the reference's operator design)."""
@@ -502,10 +600,13 @@ class BaselineEngine:
                     if win.name == "hop":
                         self.handlers.append(_HopWindowGroupBy(q, win))
                         continue
+                    if win.name == "session":
+                        self.handlers.append(_SessionWindow(q, win))
+                        continue
                     if win.name != "length":
                         raise SiddhiQLError(
-                            "baseline interpreter: only length and hop "
-                            "windows"
+                            "baseline interpreter: only length, hop and "
+                            "session windows"
                         )
                     cap = win.args[0]
                     assert isinstance(cap, ast.Literal)
@@ -532,6 +633,12 @@ class BaselineEngine:
         emit = self._emit
         for h in self.handlers:
             h.on_event(ev, ts, emit)
+
+    def flush(self) -> None:
+        """End of stream: the handlers that hold rows back emit them."""
+        for h in self.handlers:
+            if hasattr(h, "flush"):
+                h.flush(self._emit)
 
     def run_columns(self, cols: Dict[str, list], ts_list: list) -> int:
         """Replay columnar data per event (zip to dicts on the fly)."""

@@ -28,11 +28,11 @@ class EngineConfig:
     time_window_capacity: int = 512
     # max distinct timeBatch windows touched per micro-batch
     time_batch_slots: int = 64
-    # #window.hop: the group slots its state starts with. Slots expire
-    # and are reused, so the table needs the keys a window holds, not
-    # the keys ever seen: set it from the window and the rate at which
-    # keys open, and the step never re-buckets (a recompile) inside a
-    # steady stream
+    # #window.hop, the window join and #window.session: the group slots
+    # their state starts with. Slots expire and are reused, so the table
+    # needs the keys a window (a session's gap) holds, not the keys ever
+    # seen: set it from the window and the rate at which keys open, and
+    # the step never re-buckets (a recompile) inside a steady stream
     hop_group_slots: int = 64
     # join ring slots per side (time/unbounded windows)
     join_window_capacity: int = 128

@@ -92,6 +92,9 @@ class OutputField:
     name: str
     atype: AttributeType
     table: Optional[StringTable] = None  # decode dictionary when encoded
+    # a time on the job's clock (ms since the job's epoch, as a row's
+    # stamp): the emission tail adds the epoch, consumers see epoch ms
+    on_clock: bool = False
 
     def decode(self, v) -> Any:
         if self.table is not None:

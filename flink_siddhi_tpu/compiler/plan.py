@@ -447,6 +447,16 @@ class CompiledPlan:
 TIME_WINDOWS = ("hop", "externaltime", "externaltimebatch")
 
 
+def _time_arg_of(w: ast.Window):
+    """The attribute window ``w`` reads as time, or None: the first
+    argument of ``TIME_WINDOWS`` and of the three-argument
+    ``#window.session(tsAttribute, gap, key)``."""
+    name = w.name.split(".")[-1].lower()
+    if name in TIME_WINDOWS or (name == "session" and len(w.args) == 3):
+        return w.args[0]
+    return None
+
+
 def _leaf_shapes(state) -> List[Tuple]:
     return [np.shape(x) for x in jax.tree.leaves(state)]
 
@@ -1494,8 +1504,9 @@ def _referenced_field_names(parsed, time_args: bool = True):
     (``select *``). Name-level (not stream-qualified) and therefore
     conservative: a name used on ANY stream keeps that column on every
     stream carrying it. ``time_args=False`` leaves out the attribute a
-    window reads as time (``TIME_WINDOWS``' first argument): the names
-    read as values."""
+    window reads as time (``_time_arg_of``): the names read as values.
+    A session's ``min()`` / ``max()`` of its own time attribute is no
+    such read: it is the session's first and last time on the clock."""
     names = set()
 
     def add_expr(e):
@@ -1508,8 +1519,21 @@ def _referenced_field_names(parsed, time_args: bool = True):
         sel = q.selector
         if sel.is_star:
             return None
+        clock = None
+        if not time_args and isinstance(q.input, ast.StreamInput):
+            clock = next(
+                (w.args[0] for w in q.input.windows
+                 if w.name.split(".")[-1].lower() == "session"
+                 and _time_arg_of(w) is not None), None)
         for item in sel.items:
-            add_expr(item.expr)
+            e = item.expr
+            if (
+                clock is not None and isinstance(e, ast.Call)
+                and e.name.lower() in ("min", "max")
+                and e.args == (clock,)
+            ):
+                continue
+            add_expr(e)
         for g in sel.group_by:
             names.add(ast.bare_group_key(g))
         add_expr(sel.having)
@@ -1533,7 +1557,7 @@ def _referenced_field_names(parsed, time_args: bool = True):
                 add_expr(f)
             for w in side.windows:
                 args = w.args
-                if not time_args and w.name.lower() in TIME_WINDOWS:
+                if not time_args and _time_arg_of(w) is not None:
                     args = args[1:]
                 for arg in args:
                     add_expr(arg)

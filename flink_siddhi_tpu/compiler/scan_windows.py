@@ -1,7 +1,12 @@
-"""Per-event scan windows: ``#window.sort(N, attr)`` and
-``#window.unique(attr)``.
+"""Per-event scan windows: ``#window.sort(N, attr)``,
+``#window.unique(attr)`` and the two heavy-hitter sketches
+(``#window.frequent``, ``#window.lossyFrequent``).
 
-These two windows retain a DATA-DEPENDENT set (top-N by a key; the
+``#window.session`` is routed from here and is no scan: its fold is
+vectorised over the micro-batch and its sessions close on the stream's
+clock (``compiler/session_window.py``, ``docs/session_window.md``).
+
+Sort and unique retain a DATA-DEPENDENT set (top-N by a key; the
 latest event per key) whose per-event evolution is inherently
 sequential, unlike the positional/time windows the vectorized paths
 handle. They compile to one ``lax.scan`` over the micro-batch with a
@@ -275,6 +280,7 @@ def compile_scan_window(
     rewritten,
     collector,
     having_re,
+    host_filters=None,
 ):
     kind, args = window
     inp = q.input
@@ -286,10 +292,13 @@ def compile_scan_window(
                 f"stream {inp.stream_id!r} has no partition key"
             )
     if kind == "session":
-        return _compile_session_window(
-            q, name, args, resolver, stream_codes, extensions,
-            filter_fns, rewritten, collector, having_re,
-            part_attr=part_attr,
+        # vectorised, not a scan: compiler/session_window.py
+        from .session_window import compile_session_window
+
+        return compile_session_window(
+            q, name, args, resolver, stream_codes[inp.stream_id],
+            extensions, config, filter_fns, rewritten, collector,
+            having_re, host_filters, part_attr=part_attr,
         )
     if kind in ("frequent", "lossyFrequent"):
         if part_attr is not None:
@@ -496,319 +505,6 @@ def _compile_frequency_window(
     )
     art.encoded_columns = encoded
     return art
-
-
-@dataclass
-class SessionWindowArtifact:
-    """``#window.session(gap[, key])``: per-key sessions that close when
-    the gap elapses with no event for that key. One ``lax.scan`` over
-    the batch with a [G] session table carry (siddhi-core's
-    SessionWindowProcessor shape).
-
-    Emission timing: a closed session emits when its key's NEXT event
-    arrives past the gap (with ts = sessionEnd + gap) or at end of
-    stream — siddhi's timer thread emits at gap expiry instead, so
-    between those two points a closed-but-unemitted session is simply
-    not yet visible here (same rows, later)."""
-
-    name: str
-    output_schema: OutputSchema
-    stream_code: int
-    filter_fns: List
-    gap_ms: int
-    code_key: str
-    encoder: GroupEncoder
-    aggs: List[_Agg]
-    arg_fns: List[Callable]
-    arg_types: List[AttributeType]
-    proj_map: List  # per select item: ('key',) | ('agg', slot)
-    output_mode: str = "packed"
-
-    def _pack(self, n, emit_ts, code_col, slot_vals):
-        """(1 + fields, width) int32 block: ts row + one row per select
-        item (key codes as i32; float aggregates bitcast; integer
-        aggregates rounded — a plain astype of the f32 accumulator)."""
-        rows = [emit_ts.astype(jnp.int32)]
-        for kind, f in zip(self.proj_map, self.output_schema.fields):
-            if kind[0] == "key":
-                rows.append(code_col.astype(jnp.int32))
-            else:
-                v = slot_vals[kind[1]]
-                if jnp.issubdtype(
-                    jnp.dtype(f.atype.device_dtype), jnp.floating
-                ):
-                    rows.append(
-                        jax.lax.bitcast_convert_type(
-                            v.astype(jnp.float32), jnp.int32
-                        )
-                    )
-                else:
-                    rows.append(jnp.round(v).astype(jnp.int32))
-        return n, jnp.stack(rows)
-
-    def _cap(self) -> int:
-        return _bucket(
-            len(self.encoder) if self.encoder else 1,
-            _MIN_UNIQUE_CAPACITY,
-        )
-
-    def cost_info(self) -> Dict:
-        """Admission-cost descriptor: per-key session aggregates (no
-        events retained); one closed-session row per closing event.
-        The session table grows with key cardinality."""
-        return {
-            "name": self.name,
-            "kind": "session_window",
-            "amplification": 1,
-            "residency_ms": None,
-            "grows_with": "keys",
-        }
-
-    def init_state(self) -> Dict:
-        G = self._cap()
-        st = {
-            "enabled": jnp.asarray(True),
-            "open": jnp.zeros(G, bool),
-            "last": jnp.zeros(G, jnp.int32),
-            "cnt": jnp.zeros(G, jnp.int32),
-        }
-        for j, t in enumerate(self.arg_types):
-            st[f"s{j}"] = jnp.zeros(G, jnp.float32)
-            st[f"mn{j}"] = jnp.full(
-                G, _identity("min", t.device_dtype), t.device_dtype
-            )
-            st[f"mx{j}"] = jnp.full(
-                G, _identity("max", t.device_dtype), t.device_dtype
-            )
-        return st
-
-    def grow_state(self, state: Dict) -> Dict:
-        G = self._cap()
-        if state["open"].shape[0] >= G:
-            return state
-        out = {"enabled": state["enabled"]}
-        for k, v in state.items():
-            if k == "enabled":
-                continue
-            pad_val = (
-                _identity("min" if k.startswith("mn") else "max", v.dtype)
-                if k.startswith(("mn", "mx"))
-                else jnp.asarray(0, v.dtype)
-            )
-            old = v.shape[0]
-            out[k] = jnp.concatenate(
-                [v, jnp.full(G - old, pad_val, v.dtype)]
-            )
-        return out
-
-    def emit_block_width(self, tape_capacity: int, state: Dict) -> int:
-        return tape_capacity + self._cap()
-
-    def _session_rows(self, buf, codes):
-        """Slot values of the sessions stored for ``codes``."""
-        out = {"cnt": buf["cnt"][codes].astype(jnp.float32)}
-        for agg in self.aggs:
-            j = agg.arg_idx
-            if agg.kind == "count":
-                v = buf["cnt"][codes].astype(jnp.float32)
-            elif agg.kind == "sum":
-                v = buf[f"s{j}"][codes]
-            elif agg.kind == "avg":
-                v = buf[f"s{j}"][codes] / jnp.maximum(
-                    buf["cnt"][codes].astype(jnp.float32), 1.0
-                )
-            elif agg.kind == "min":
-                v = buf[f"mn{j}"][codes].astype(jnp.float32)
-            elif agg.kind == "max":
-                v = buf[f"mx{j}"][codes].astype(jnp.float32)
-            else:
-                raise SiddhiQLError(
-                    f"{agg.kind}() is not supported over #window.session"
-                )
-            out[agg.slot] = v
-        return out
-
-    # fst:hotpath device=state,tape
-    def step(self, state: Dict, tape) -> Tuple[Dict, Tuple]:
-        env: ColumnEnv = dict(tape.cols)
-        mask = tape.valid & (tape.stream == self.stream_code)
-        for f in self.filter_fns:
-            mask = mask & f(env)
-        mask = mask & state["enabled"]
-        E = tape.capacity
-        codes = (
-            jnp.clip(
-                env[self.code_key].astype(jnp.int32), 0, self._cap() - 1
-            )
-            if self.code_key is not None
-            else jnp.zeros(E, jnp.int32)
-        )
-        arg_cols = [
-            jnp.broadcast_to(jnp.asarray(fn(env)), (E,)).astype(
-                jnp.float32
-            )
-            for fn in self.arg_fns
-        ]
-        buf0 = {k: v for k, v in state.items() if k != "enabled"}
-
-        def body(buf, x):
-            active, c, ts = x[0], x[1], x[2]
-            vals = x[3:]
-            was_open = buf["open"][c]
-            closes = active & was_open & (
-                ts - buf["last"][c] > jnp.int32(self.gap_ms)
-            )
-            # emit the CLOSED session (pre-reset values)
-            emit_ts = buf["last"][c] + jnp.int32(self.gap_ms)
-            emitted = self._session_rows(buf, c)
-            fresh = closes | (active & ~was_open)
-            nb = dict(buf)
-            nb["open"] = jnp.where(
-                active, buf["open"].at[c].set(True), buf["open"]
-            )
-            # straggler defense (same shape as the expired-ring cummax):
-            # a cross-batch out-of-order event must not REWIND the
-            # session clock — a rewound 'last' would let a later
-            # in-order event spuriously close/split the session and
-            # regress emit_ts. The monotone max also keeps `closes`
-            # judged against the newest activity.
-            nb["last"] = jnp.where(
-                active,
-                buf["last"].at[c].set(
-                    jnp.maximum(buf["last"][c], ts)
-                ),
-                buf["last"],
-            )
-            cnt0 = jnp.where(fresh, 0, buf["cnt"][c])
-            nb["cnt"] = jnp.where(
-                active, buf["cnt"].at[c].set(cnt0 + 1), buf["cnt"]
-            )
-            for j, v in enumerate(vals):
-                s0 = jnp.where(fresh, 0.0, buf[f"s{j}"][c])
-                nb[f"s{j}"] = jnp.where(
-                    active, buf[f"s{j}"].at[c].set(s0 + v), buf[f"s{j}"]
-                )
-                idn = _identity("min", buf[f"mn{j}"].dtype)
-                m0 = jnp.where(fresh, idn, buf[f"mn{j}"][c])
-                nb[f"mn{j}"] = jnp.where(
-                    active,
-                    buf[f"mn{j}"].at[c].set(
-                        jnp.minimum(m0, v.astype(buf[f"mn{j}"].dtype))
-                    ),
-                    buf[f"mn{j}"],
-                )
-                idx_ = _identity("max", buf[f"mx{j}"].dtype)
-                x0 = jnp.where(fresh, idx_, buf[f"mx{j}"][c])
-                nb[f"mx{j}"] = jnp.where(
-                    active,
-                    buf[f"mx{j}"].at[c].set(
-                        jnp.maximum(x0, v.astype(buf[f"mx{j}"].dtype))
-                    ),
-                    buf[f"mx{j}"],
-                )
-            ys = (closes, emit_ts, c) + tuple(
-                emitted[slot]
-                for slot in sorted(emitted)
-                if slot != "cnt"
-            )
-            return nb, ys
-
-        xs = (mask, codes, tape.ts) + tuple(arg_cols)
-        new_buf, ys = lax.scan(body, buf0, xs)
-        closes, emit_ts, ccode = ys[0], ys[1], ys[2]
-        slot_names = [s for s in sorted(
-            {a.slot for a in self.aggs}
-        )]
-        slot_vals = dict(zip(slot_names, ys[3:3 + len(slot_names)]))
-        n = closes.sum().astype(jnp.int32)
-        pos = jnp.cumsum(closes.astype(jnp.int32)) - 1
-        dest = jnp.where(closes, pos, E)
-        W = E
-
-        def compact(col, dtype=jnp.float32):
-            return (
-                jnp.zeros(W, dtype)
-                .at[dest]
-                .set(col.astype(dtype), mode="drop")
-            )
-
-        out_ts = compact(emit_ts, jnp.int32)
-        c_code = compact(ccode, jnp.int32)
-        c_slots = {
-            k: compact(v) for k, v in slot_vals.items()
-        }
-        new_state = dict(new_buf)
-        new_state["enabled"] = state["enabled"]
-        return new_state, self._pack(n, out_ts, c_code, c_slots)
-
-    @property
-    def flush_is_noop(self) -> bool:
-        return False
-
-    def flush(self, state: Dict) -> Tuple[Dict, Tuple]:
-        """End of stream: every open session closes (time passes every
-        deadline — the engine-wide flush rule)."""
-        G = self._cap()
-        open_ = state["open"]
-        n = open_.sum().astype(jnp.int32)
-        pos = jnp.cumsum(open_.astype(jnp.int32)) - 1
-        dest = jnp.where(open_, pos, G)
-        codes = jnp.arange(G, dtype=jnp.int32)
-        rows = self._session_rows(state, codes)
-        emit_ts = state["last"] + jnp.int32(self.gap_ms)
-
-        def compact(col, dtype=jnp.float32):
-            return (
-                jnp.zeros(G, dtype)
-                .at[dest]
-                .set(col.astype(dtype), mode="drop")
-            )
-
-        c_code = compact(codes, jnp.int32)
-        c_slots = {k: compact(v) for k, v in rows.items()}
-        new_state = dict(state)
-        new_state["open"] = jnp.zeros(G, bool)
-        return new_state, self._pack(
-            n, compact(emit_ts, jnp.int32), c_code, c_slots
-        )
-
-    def decode_packed(self, n: int, block: "np.ndarray"):
-        """Key columns decode codes back through the encoder."""
-        schema = self.output_schema
-        from .output import emission_order
-
-        order = emission_order(block[0], n)
-        ts_list = (
-            np.asarray(block[0, :n])[order].astype(np.int64).tolist()
-        )
-        col_lists = []
-        for c, (f, kind) in enumerate(
-            zip(schema.fields, self.proj_map)
-        ):
-            raw = np.asarray(block[1 + c, :n])[order]
-            if kind[0] == "key":
-                # append-only encoder: extend the cached LUT (same
-                # pattern as the sliding-window group-code decode)
-                cache = getattr(self, "_lut_cache", None)
-                if cache is None:
-                    cache = self._lut_cache = {}
-                lut = cache.setdefault(c, [])
-                for i in range(len(lut), len(self.encoder)):
-                    lut.append(f.decode(self.encoder.value(i)[0]))
-                col_lists.append(
-                    [lut[int(v)] if 0 <= int(v) < len(lut) else None
-                     for v in raw.tolist()]
-                )
-            else:
-                if np.dtype(f.atype.device_dtype) == np.dtype(np.float32):
-                    raw = raw.view(np.float32)
-                col_lists.append(f.decode_column(raw))
-        rows = (
-            list(zip(ts_list, map(tuple, zip(*col_lists))))
-            if col_lists
-            else [(t, ()) for t in ts_list]
-        )
-        return [(schema, rows)]
 
 
 @dataclass
@@ -1041,93 +737,3 @@ class FrequencyWindowArtifact:
         new_state = dict(new_buf)
         new_state["enabled"] = state["enabled"]
         return new_state, (mask & emit, tape.ts, cols)
-
-
-def _compile_session_window(
-    q, name, args, resolver, stream_codes, extensions,
-    filter_fns, rewritten, collector, having_re, part_attr=None,
-):
-    gap_ms, key_attr = args
-    inp = q.input
-    if part_attr is not None:
-        # 'partition with' sessions: the partition key IS the session
-        # key (each partition instance tracks its own gap), which is
-        # exactly the keyed-session artifact below
-        if key_attr is not None and key_attr.name != part_attr:
-            raise SiddhiQLError(
-                "#window.session inside 'partition with' must key the "
-                "session by the partition attribute (or omit the key)"
-            )
-        key_attr = ast.Attr(part_attr)
-    if having_re is not None:
-        raise SiddhiQLError(
-            "having over #window.session is not supported yet"
-        )
-    code_key, encoder, encoded = None, None, ()
-    if key_attr is not None:
-        r = resolver.resolve(key_attr)
-        from .window import _group_encoding
-
-        code_key, encoder, encoded = _group_encoding(
-            name, [r], stream_codes[inp.stream_id], filter_fns
-        )
-    gb = tuple(
-        ast.bare_group_key(g) for g in q.selector.group_by
-    )
-    if gb and (key_attr is None or gb != (key_attr.name,)):
-        raise SiddhiQLError(
-            "group by on #window.session must be the session key"
-        )
-    slot_names = {a.slot for a in collector.aggs}
-    proj_map = []
-    out_fields: List[OutputField] = []
-    key_idx = None
-    for i, item in enumerate(rewritten):
-        e = item.expr
-        if isinstance(e, ast.Attr) and e.name in slot_names:
-            agg = next(a for a in collector.aggs if a.slot == e.name)
-            proj_map.append(("agg", e.name))
-            out_fields.append(
-                OutputField(item.output_name(), agg.out_type, None)
-            )
-        elif (
-            isinstance(e, ast.Attr)
-            and key_attr is not None
-            and e.name == key_attr.name
-        ):
-            ra = resolver.resolve(e)
-            proj_map.append(("key",))
-            out_fields.append(
-                OutputField(item.output_name(), ra.atype, ra.table)
-            )
-        else:
-            raise SiddhiQLError(
-                "#window.session select items must be the session key "
-                "or aggregations (a closed session has no single "
-                "current event to read other attributes from)"
-            )
-    if not collector.aggs:
-        raise SiddhiQLError(
-            "#window.session without aggregation emits nothing; "
-            "aggregate the session (e.g. count())"
-        )
-    for a in collector.aggs:
-        if a.kind not in ("count", "sum", "avg", "min", "max"):
-            raise SiddhiQLError(
-                f"{a.kind}() is not supported over #window.session"
-            )
-    art = SessionWindowArtifact(
-        name=name,
-        output_schema=OutputSchema(q.output_stream, tuple(out_fields)),
-        stream_code=stream_codes[inp.stream_id],
-        filter_fns=filter_fns,
-        gap_ms=int(gap_ms),
-        code_key=code_key,
-        encoder=encoder,
-        aggs=collector.aggs,
-        arg_fns=collector.arg_fns,
-        arg_types=collector.arg_types,
-        proj_map=proj_map,
-    )
-    art.encoded_columns = encoded
-    return art

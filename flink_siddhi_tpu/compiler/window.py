@@ -86,6 +86,7 @@ class _AggCollector:
         self.aggs: List[_Agg] = []
         self.arg_fns: List[Callable] = []
         self.arg_types: List[AttributeType] = []
+        self.arg_exprs: List[ast.Expr] = []
         self._agg_keys: Dict[str, int] = {}
         self._arg_keys: Dict[str, int] = {}
 
@@ -103,6 +104,7 @@ class _AggCollector:
         self._arg_keys[key] = i
         self.arg_fns.append(ce.fn)
         self.arg_types.append(ce.atype)
+        self.arg_exprs.append(expr)
         return i, ce.atype
 
     def intern(self, call: ast.Call) -> _Agg:
@@ -1766,18 +1768,25 @@ def _window_of(inp: ast.StreamInput):
         return ("hop", (w.args[0], _time_arg(w.args[1]),
                         _time_arg(w.args[2])))
     if lname == "session":
-        if not w.args or len(w.args) > 2:
+        # (gap[, key]) reads the event's own timestamp; (tsAttribute,
+        # gap, key) names the event-time attribute first, as hop does
+        args = w.args
+        if not args or len(args) > 3:
             raise SiddhiQLError(
-                "#window.session needs (gap[, keyAttribute])"
+                "#window.session needs (gap[, keyAttribute]) or "
+                "(tsAttribute, gap, keyAttribute)"
             )
-        key = None
-        if len(w.args) == 2:
-            if not isinstance(w.args[1], ast.Attr):
-                raise SiddhiQLError(
-                    "#window.session key must be an attribute"
-                )
-            key = w.args[1]
-        return ("session", (_time_arg(w.args[0]), key))
+        ts_attr = None
+        if len(args) == 3:
+            ts_attr, args = args[0], args[1:]
+        if not all(isinstance(a, ast.Attr) for a in (ts_attr, *args[1:])
+                   if a is not None):
+            raise SiddhiQLError(
+                "#window.session: the time attribute and the key must "
+                "be attributes"
+            )
+        key = args[1] if len(args) == 2 else None
+        return ("session", (_time_arg(args[0]), key, ts_attr))
     if lname == "delay":
         if len(w.args) != 1:
             raise SiddhiQLError("#window.delay needs one time argument")
@@ -1931,6 +1940,7 @@ def compile_window_query(
         return compile_scan_window(
             q, name, window, resolver, schemas, stream_codes, extensions,
             config, filter_fns, rewritten, collector, having_re,
+            host_filters,
         )
 
     if q.partition_with and window is not None and window[0] == "time":

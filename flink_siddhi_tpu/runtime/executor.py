@@ -96,6 +96,19 @@ _LAZY_ORD_WRAP = 1 << 30  # reset lazy ordinal space before int32 wrap
 _LOG = logging.getLogger(__name__)
 
 
+def _clock_rows(schema, rows, epoch: int):
+    """``rows`` with the fields that are times on the job's clock
+    (``OutputField.on_clock``) as epoch ms, as the rows' stamps leave."""
+    clock = [i for i, f in enumerate(schema.fields) if f.on_clock]
+    if not clock:
+        return rows
+    return [
+        (ts, tuple(v + epoch if i in clock else v
+                   for i, v in enumerate(row)))
+        for ts, row in rows
+    ]
+
+
 def _wire_sig(wire):
     """Structural signature of a wire tape: pytree aux + leaf layouts.
     Two tapes with equal signatures can stack into one scanned axis
@@ -2899,11 +2912,12 @@ class Job:
         if not rows:
             return
         sid = schema.stream_id
+        epoch = self._epoch_ms or 0
         if self._loopback and sid in self._loopback:
             # shared-prefix mid stream: pure host-side plumbing into
             # the consumer suffixes — no counters, no traces, no sinks
             # (per-tenant conservation counts member emissions only)
-            self._feed_loopback(schema, rows)
+            self._feed_loopback(schema, _clock_rows(schema, rows, epoch))
             return
         if rate_limit:
             limiter = self._rate_limiters.get(sid)
@@ -2911,8 +2925,8 @@ class Job:
                 rows = limiter.feed(rows)
                 if not rows:
                     return
+        rows = _clock_rows(schema, rows, epoch)
         self.output_fields.setdefault(sid, schema.field_names)
-        epoch = self._epoch_ms or 0
         # rows surfacing to a consumer complete their event's trace
         # (post-rate-limit: a thinned row is not visible, so it
         # must not stop the clock)
@@ -2991,6 +3005,12 @@ class Job:
                 return
         self.output_fields.setdefault(sid, schema.field_names)
         epoch = self._epoch_ms or 0
+        clock = [f.name for f in schema.fields if f.on_clock]
+        if clock:  # times on the job's clock leave as epoch ms
+            cb = type(cb)(cb.ts, {
+                **cb.cols,
+                **{k: cb.cols[k] + np.int64(epoch) for k in clock},
+            })
         # rows surfacing to a consumer complete their event's trace
         # (post-rate-limit, same contract as the row path)
         self.tracer.complete_ts(epoch, cb.ts)
@@ -4159,7 +4179,12 @@ class Job:
                         )
                     else:
                         decoded = a.decode_packed(int(count), block)
+                    counters = getattr(a, "drain_counters", None)
                     for sch, payload in decoded:
+                        if counters is not None and self.telemetry.enabled:
+                            # rows that leave outside a drain (a flush)
+                            for name, n in counters(payload).items():
+                                self.telemetry.inc(name, n)
                         if isinstance(payload, ColumnBatch):
                             self._emit_columns(sch, payload)
                         else:

@@ -515,15 +515,19 @@ def _check_i32_span(key: str, vals: np.ndarray, origin: int, why: str):
 
 
 def _intern_groups(spec, batches, cols, stream, total, order, identity,
-                   known=None):
+                   known=None, ts=None):
     """Group keys -> dense codes (``spec.encoded``), added to ``cols``.
     ``known`` (out_key -> the codes an earlier build of these batches
     interned) are taken as they are: a table whose slots expire hands
-    out other slots the second time."""
+    out other slots the second time. ``ts``: the tape's own timestamps,
+    a tick column under the name ``@ts`` (a session window that reads
+    the event's timestamp)."""
     if not spec.encoded:
         return
     cap = len(stream)
     view = {k: v[:total] for k, v in cols.items()}
+    if ts is not None:
+        view["@ts"] = ts[:total]
 
     def selected(stream_code, select_fn):
         select = stream[:total] == stream_code
@@ -703,7 +707,7 @@ def build_tape(
 
     with (intern_span or contextlib.nullcontext)():
         _intern_groups(spec, batches, cols, stream, total, order, identity,
-                       codes)
+                       codes, ts)
     # wire predicate pushdown: evaluate each host predicate over the
     # merged-order RAW host columns (f64 where the schema says DOUBLE)
     # and add the result as a bool pseudo-column — it ships bit-packed,

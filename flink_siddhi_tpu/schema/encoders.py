@@ -97,7 +97,9 @@ class GroupEncoder:
             self._sweep()
             pos = np.flatnonzero(select)
             if len(pos):
-                tick = int(tick_col[pos[-1]]) // tick_ms
+                # never behind an earlier batch's (a late last row)
+                tick = max(int(tick_col[pos[-1]]) // tick_ms,
+                           self._tick or 0)
         if len(cols) == 1 and cols[0].dtype != object:
             # vectorized single-column path: unique once (distinct group
             # count, not row count), nothing per row or per key in Python

@@ -36,7 +36,10 @@ TINY_Q5 = {"event_time_rate": 2_000, "batch": 2_000, "fused_segment_len": 2,
            "engine_config": {"hop_group_slots": 8_192}}
 # (nexmark_q8.replay: a window is ten batches, the pool two windows, and
 # the run's 40 batches close three)
-POOL_BATCHES = {"nexmark_q5.replay": 20, "nexmark_q8.replay": 20}
+# (nexmark_q11.replay: the gap is ten batches; sessions close from the
+# eleventh batch on, through drains and at the flush)
+POOL_BATCHES = {"nexmark_q5.replay": 20, "nexmark_q8.replay": 20,
+                "nexmark_q11.replay": 20}
 WARM_BATCHES, RUN_BATCHES = 8, 40
 
 # Names a sound tiny run does not book, or books only when the timing

@@ -56,6 +56,16 @@ TINY = {
         "whole_batches": 30, "fused_segment_len": 2,
         "engine_config": {"hop_group_slots": 4_096},
     },
+    # the same stream: the 10 s gap is ten batches. A person is among
+    # the newest 1,000 for 25 s here (50 ms at the cell's rate), so a
+    # bidder comes back, inside the gap and after it, and has several
+    # sessions. The flush closes what the stream's end leaves open; those
+    # rows are stamped past the stream and are not settled
+    "nexmark_q11": {
+        "event_time_rate": 2_000, "batch": 2_000, "pool": 40_000,
+        "whole_batches": 30, "fused_segment_len": 2,
+        "engine_config": {"hop_group_slots": 4_096},
+    },
 }
 
 

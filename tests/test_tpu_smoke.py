@@ -196,9 +196,10 @@ def test_pallas_compiled_not_interpreted(on_tpu):
     assert pallas_ops.warmup_shard()
 
 
-def test_session_window_scan_engine_on_device(on_tpu):
-    # round-5 verdict item 8: the per-event lax.scan engine (session /
-    # sort / unique windows) had never run on real hardware
+def test_session_window_fold_and_close_on_device(on_tpu):
+    # the session window's vectorised fold (scatters over slot codes)
+    # and its close on the stream's clock (compiler/session_window.py),
+    # on real hardware; a batch here holds several sessions of a key
     ids = np.array([0, 1, 0, 0, 1, 0, 1, 1], dtype=np.int32)
     ts = np.array(
         [1000, 1002, 1005, 1040, 1041, 1100, 1101, 1150],
