@@ -267,8 +267,9 @@ class _Sequence:
 
 class _LengthWindowGroupBy:
     """``#window.length(C) select ... group by k``: ring of the last C
-    events + per-group running aggregates (add on arrival, subtract on
-    eviction), emitting the group's row per event — siddhi-core's
+    events + per-group running sums and counts (add on arrival,
+    subtract on eviction; min / max read the ring's members of the
+    group), emitting the group's row per event — siddhi-core's
     LengthWindowProcessor + GroupByKeyGenerator shape."""
 
     def __init__(self, q: ast.Query, capacity: int):
@@ -279,14 +280,15 @@ class _LengthWindowGroupBy:
             k.split(".", 1)[-1] for k in q.selector.group_by
         ]
         self.ring: deque = deque()
-        self.sums: Dict[Any, float] = {}
+        self.sums: Dict[Any, List[float]] = {}
         self.counts: Dict[Any, int] = {}
-        # each select item: ('group', fn) | ('sum', fn) | ('count',)
+        # each select item: ('group' | 'sum' | 'min' | 'max', fn) |
+        # ('count', None)
         self.items = []
         for it in q.selector.items:
             e = it.expr
-            if isinstance(e, ast.Call) and e.name == "sum":
-                self.items.append(("sum", _compile_scalar(e.args[0])))
+            if isinstance(e, ast.Call) and e.name in ("sum", "min", "max"):
+                self.items.append((e.name, _compile_scalar(e.args[0])))
             elif isinstance(e, ast.Call) and e.name == "count":
                 self.items.append(("count", None))
             else:
@@ -298,23 +300,32 @@ class _LengthWindowGroupBy:
             if not f(ev):
                 return
         key = tuple(ev[k] for k in self.group_keys)
-        sv = 0.0
-        for kind, fn in self.items:
-            if kind == "sum":
-                sv = fn(ev)
-        self.ring.append((key, sv))
-        self.sums[key] = self.sums.get(key, 0.0) + sv
+        # an aggregate's argument per item (None for the others)
+        vals = [
+            fn(ev) if kind in ("sum", "min", "max") else None
+            for kind, fn in self.items
+        ]
+        self.ring.append((key, vals))
+        sums = self.sums.setdefault(key, [0.0] * len(vals))
         self.counts[key] = self.counts.get(key, 0) + 1
-        if len(self.ring) > self.cap:
-            okey, osv = self.ring.popleft()
-            self.sums[okey] -= osv
-            self.counts[okey] -= 1
+        evicted = (
+            self.ring.popleft() if len(self.ring) > self.cap else None
+        )
+        if evicted is not None:
+            self.counts[evicted[0]] -= 1
         row = []
-        for kind, fn in self.items:
+        for i, (kind, fn) in enumerate(self.items):
             if kind == "sum":
-                row.append(self.sums[key])
+                sums[i] += vals[i]
+                if evicted is not None:
+                    self.sums[evicted[0]][i] -= evicted[1][i]
+                row.append(sums[i])
             elif kind == "count":
                 row.append(self.counts[key])
+            elif kind in ("min", "max"):
+                # the window's members of this group, oldest first
+                members = [v[i] for k, v in self.ring if k == key]
+                row.append(min(members) if kind == "min" else max(members))
             else:
                 row.append(fn(ev))
         emit(self.out, ts, tuple(row))

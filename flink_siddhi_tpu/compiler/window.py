@@ -49,6 +49,12 @@ from .expr import (
     promote,
 )
 from .output import OutputField, OutputSchema
+from .window_merge import (
+    blocked_tiling,
+    ranked_merge,
+    static_merge,
+    tile_fold,
+)
 
 # Bounded slot counts for data-dependent structures (documented limits; a
 # production config system can raise them per plan).
@@ -362,9 +368,11 @@ class SlidingWindowArtifact:
 
     def _blocked(self) -> bool:
         """Sort-free tiled path: per-group running sums over the merged
-        arrival/expiry sequence via one-hot / lower-triangular matmuls
-        (MXU work) instead of multi-key argsorts (the slow op class on
-        TPU — ~5 sorts of 2(C+E) elements dominated this step).
+        arrival/expiry sequence via one-hot / same-group matmuls (MXU
+        work) instead of multi-key argsorts (the slow op class on TPU —
+        ~5 sorts of 2(C+E) elements dominated this step). The merge is
+        static for a length window and ranked for a processing-time
+        window (``merge_form``, window_merge.py).
 
         Integer sum/avg arguments run EXACTLY through the same matmuls
         by base-2^11 digit decomposition (each digit plane's tile sum
@@ -391,6 +399,17 @@ class SlidingWindowArtifact:
             elif a.kind not in ("count", "sum", "avg", "stddev"):
                 return False
         return True
+
+    @property
+    def merge_form(self) -> Optional[str]:
+        """Which merge of arrivals and expiries the step compiles
+        (window_merge.py): ``'static'`` for a length window on the
+        blocked path, ``'ranked'`` for a processing-time window there,
+        None off it. The query fixes it; the run loop books it per
+        dispatched batch (``window.merge_steps``, ``..._static``)."""
+        if not self._blocked():
+            return None
+        return "static" if self.window_mode == "length" else "ranked"
 
     @jax.named_scope("fst.window_fold")
     # fst:hotpath device=state,tape
@@ -478,19 +497,24 @@ class SlidingWindowArtifact:
 
     # -- blocked (sort-free) sliding aggregation ---------------------------
     def _step_blocked(self, state: Dict, tape) -> Tuple[Dict, Tuple]:
-        """Windowed per-group sums with ZERO sorts.
+        """Windowed per-group sums without a sort over the tape.
 
         Same semantics as ``_step_matrix`` (window = last C matching
         events / time span; aggregates over the emitting event's group),
-        new machinery: arrivals compact via scatter (not argsort), or not
-        at all where the mask is a prefix already (compact.py); the
-        arrival(+v)/expiry(-v) sequences are each already sorted by
-        merge key, so their interleave comes from two searchsorteds; and
-        the per-group running sum of the merged sequence is computed in
-        tiles — a [t,G] one-hot matmul gives per-tile group totals whose
-        exclusive scan is the across-tile carry, and a [t,t] same-group
-        lower-triangular matmul gives the within-tile prefix. All the
-        heavy work is matmul (MXU), not sort."""
+        other machinery. Arrivals front-compact via scatter (not
+        argsort), or not at all where the mask is a prefix already
+        (compact.py). The concat sequence, ring ++ arrivals, is then
+        merged with its own expiries (window_merge.py): a length
+        window's order is fixed by C and E, so ``static_merge`` cuts
+        the tiles from the sequence with slices and no index array
+        exists; a processing-time window's comes from one
+        ``searchsorted`` (``_expiry_ranks``) and ``ranked_merge``
+        scatters and gathers through it. ``tile_fold`` computes the
+        per-group running sum of the merged sequence in tiles: a [t,G]
+        one-hot matmul gives per-tile group totals whose exclusive scan
+        is the across-tile carry (read back with the one gather a
+        length window's step has left), and a same-group matmul under
+        the merge's precedence matrix gives the within-tile prefix."""
         env: ColumnEnv = dict(tape.cols)
         mask = tape.valid & (tape.stream == self.stream_code)
         for f in self.filter_fns:
@@ -584,7 +608,6 @@ class SlidingWindowArtifact:
             rv = ring[f"a{j}"].astype(jnp.float32)
             plane(f"q{j}", v * v, rv * rv)
         plane("cnt", jnp.ones(E, jnp.float32), jnp.ones(C, jnp.float32))
-        K = len(vcols)
 
         if self.code_key is not None:
             codes_b = arrivals["gc"]
@@ -608,136 +631,19 @@ class SlidingWindowArtifact:
             axis=1,
         )  # [N, K]
 
-        pos = jnp.arange(N, dtype=jnp.int32)
+        # the merge of arrivals and expiries: a length window's order is
+        # a fact of C and E, a time window's is ranked from the data
+        tile, chunk = blocked_tiling()
         if self.window_mode == "length":
-            exp_rank = pos + C
+            merged = static_merge(codes, live, V_n, C, tile, chunk)
         else:
-            ts_c = ts_n.astype(jnp.int32)
-            mono = lax.cummax(ts_c)
-            tgt = ts_c + jnp.int32(self.time_ms)
-            tgt = jnp.where(tgt < ts_c, jnp.int32(2 ** 31 - 1), tgt)
-            # 'sort' lowers to ONE sort; the default 'scan' method costs
-            # ~100ms at this width on TPU
-            exp_rank = jnp.searchsorted(
-                mono, tgt, side="left", method="sort"
-            ).astype(jnp.int32)
-            exp_rank = jnp.maximum(exp_rank, pos + 1)
-
-        # merge two sorted streams without sorting or searching: arrival
-        # p has key 2p+1, expiry of p has key 2*exp_rank[p] (ties:
-        # expiry first). Both key sequences are nondecreasing, so merge
-        # ranks are direct counts: an expiry at rank r precedes arrivals
-        # p >= r (histogram + cumsum), and arrivals q < exp_rank[p]
-        # precede expiry p (clip).
-        exp_clip = jnp.clip(exp_rank, 0, N)
-        hist = (
-            jnp.zeros(N + 1, jnp.int32).at[exp_clip].add(1, mode="drop")
-        )
-        cum = jnp.cumsum(hist)
-        m_arr = pos + cum[pos]
-        m_exp = pos + exp_clip
-        N2 = 2 * N
-        src = (
-            jnp.zeros(N2, jnp.int32)
-            .at[m_arr]
-            .set(pos)
-            .at[m_exp]
-            .set(pos + N)
-        )
-        is_arr = src < N
-        idx = jnp.where(is_arr, src, src - N)
-        m_code = codes[idx]
-        m_live = live[idx]
-        sign = jnp.where(is_arr, 1.0, -1.0).astype(jnp.float32)
-        V2 = jnp.where(
-            m_live[:, None], V_n[idx] * sign[:, None], 0.0
-        )  # [2N, K]
-
-        # tiled running per-own-group sums. All tiles are independent
-        # matmul work (MXU): a [t,G] one-hot contraction gives per-tile
-        # group totals, a same-group lower-triangular [t,t] contraction
-        # gives within-tile prefixes; the only sequential piece is a
-        # [T,G,K] cumsum across tiles. Tiles run in CHUNKS of batched
-        # matmuls — a per-tile lax.scan would pay ~2000 iterations of
-        # dispatch overhead for microscopic matmuls.
-        import os as _os
-
-        t = int(_os.environ.get("FST_BLOCKED_TILE", 512))
-        chunk = int(_os.environ.get("FST_BLOCKED_CHUNK", 16))
-        pad = (-N2) % (t * chunk)
-        if pad:
-            m_code = jnp.concatenate(
-                [m_code, jnp.zeros(pad, jnp.int32)]
+            merged = ranked_merge(
+                codes, live, V_n, self._expiry_ranks(ts_n), tile, chunk
             )
-            V2 = jnp.concatenate(
-                [V2, jnp.zeros((pad, K), jnp.float32)]
-            )
-        T = (N2 + pad) // t
-        codes_t = m_code.reshape(T, t)
-        V_t = V2.reshape(T, t, K)
-        tril = jnp.tril(jnp.ones((t, t), jnp.float32))
-        giota = jnp.arange(G, dtype=jnp.int32)
-
-        def chunk_body(inp):
-            c, v = inp  # [chunk, t] codes, [chunk, t, K] signed values
-            onehot = (
-                c[:, :, None] == giota[None, None, :]
-            ).astype(jnp.float32)
-            # HIGHEST precision: the TPU's default matmul precision
-            # truncates f32 operands to bf16 passes — a window SUM must
-            # not lose mantissa (caught by the real-device smoke lane)
-            tile_sums = jnp.einsum(
-                "cig,cik->cgk", onehot, v,
-                precision=lax.Precision.HIGHEST,
-            )
-            eq = (
-                c[:, :, None] == c[:, None, :]
-            ).astype(jnp.float32) * tril[None]
-            partial = jnp.einsum(
-                "cij,cjk->cik", eq, v,
-                precision=lax.Precision.HIGHEST,
-            )
-            return tile_sums, partial
-
-        S, partial = lax.map(
-            chunk_body,
-            (
-                codes_t.reshape(T // chunk, chunk, t),
-                V_t.reshape(T // chunk, chunk, t, K),
-            ),
-        )
-        S = S.reshape(T, G, K)
-        partial = partial.reshape(T * t, K)
-        tile_of = jnp.arange(T * t, dtype=jnp.int32) // t
-
-        def carried(S_, partial_):
-            # exclusive across-tile scan; laid out scan-axis-last
-            # (cumsum along a large-stride leading axis is ~30x slower
-            # on TPU); per concat-arrival windowed totals
-            Kx = S_.shape[-1]
-            cum = jnp.cumsum(S_.reshape(T, G * Kx).T, axis=1)
-            carry = cum.T.reshape(T, G, Kx) - S_
-            flat = carry.reshape(T * G, Kx)
-            R = flat[tile_of * G + m_code] + partial_
-            return R[m_arr]
-
-        int_set = set(int_planes)
-        f_order = [k for k in range(K) if k not in int_set]
-        win_f = carried(S[..., f_order], partial[:, f_order])
-        win_i = None
-        if int_planes:
-            # digit planes accumulate in MODULAR int32 (f32 tile sums
-            # are exact below 2^24; the running totals are not)
-            win_i = carried(
-                jnp.round(S[..., int_planes]).astype(jnp.int32),
-                jnp.round(partial[:, int_planes]).astype(jnp.int32),
-            )
+        planes = tile_fold(merged, G, int_planes, chunk)
 
         def wcol(name):
-            k = vmap[name]
-            if k in int_set:
-                return win_i[:, int_planes.index(k)]
-            return win_f[:, f_order.index(k)]
+            return planes[vmap[name]]
 
         def int_sum_of(j):
             return (
@@ -835,6 +741,21 @@ class SlidingWindowArtifact:
             "groups": state["groups"],
         }
         return new_state, (out_mask, tape.ts, cols)
+
+    def _expiry_ranks(self, ts_n):
+        """Per concat row of a processing-time window, the arrival it
+        expires ahead of: the first whose time is ``time_ms`` later."""
+        pos = jnp.arange(ts_n.shape[0], dtype=jnp.int32)
+        ts_c = ts_n.astype(jnp.int32)
+        mono = lax.cummax(ts_c)
+        tgt = ts_c + jnp.int32(self.time_ms)
+        tgt = jnp.where(tgt < ts_c, jnp.int32(2 ** 31 - 1), tgt)
+        # 'sort' lowers to ONE sort; the default 'scan' method costs
+        # ~100ms at this width on TPU
+        exp_rank = jnp.searchsorted(
+            mono, tgt, side="left", method="sort"
+        ).astype(jnp.int32)
+        return jnp.maximum(exp_rank, pos + 1)
 
     def _blocked_extrema(
         self, minmax, ring, codes, live, arrivals, cnt, N

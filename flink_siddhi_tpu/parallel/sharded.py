@@ -270,6 +270,7 @@ class ShardedJob(Job):
             if rt.dirty_since is None:
                 rt.dirty_since = time.monotonic()
         tel.inc("shard.cycles")
+        self._count_merges(rt)
         # shared no-overflow contract (Job._update_drain_hint); strip the
         # leading shard axis via shape metadata only
         self._update_drain_hint(

@@ -3843,6 +3843,19 @@ class Job:
                 rt.group_stats[name] = total
         tel.gauge("groups.live", sum(e.encoder.live for e in encoded))
 
+    def _count_merges(self, rt: _PlanRuntime) -> None:
+        """One dispatched batch: per blocked sliding-window artifact a
+        ``window.merge_steps``, and a ``window.merge_steps_static`` where
+        its merge order is the trace-time one (a length window;
+        compiler/window_merge.py). The step does not decide, the
+        compiled query did, so the host books it."""
+        for a in rt.plan.artifacts:
+            form = getattr(a, "merge_form", None)
+            if form is not None:
+                self.telemetry.inc("window.merge_steps")
+                if form == "static":
+                    self.telemetry.inc("window.merge_steps_static")
+
     def _grow_states(self, rt: _PlanRuntime) -> None:
         """Host interning may have discovered more group keys than the
         state tables hold: re-bucket them before the jitted step (a
@@ -3900,6 +3913,7 @@ class Job:
                 }
             )
             self.telemetry.inc("fusion.batches")
+            self._count_merges(rt)
         if len(rt.seg_pending) >= self._fused_k(rt):
             self._dispatch_segment(rt)
 
@@ -4084,6 +4098,7 @@ class Job:
             rt.acc_dirty = True
             if rt.dirty_since is None:
                 rt.dirty_since = time.monotonic()
+            self._count_merges(rt)
             if tel.enabled:
                 # host-side enqueue time of one dispatch (the device
                 # wall hides behind the ticket: leg.device carries

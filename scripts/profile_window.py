@@ -23,6 +23,20 @@ mask is a prefix (a select, a slice):
   aggregates back from concat order to tape order;
 * ``fold_batch_rows[prefix|hole]``: the helper for that way back
   (``batch_rows``: one gather of rows where the mask is no prefix);
+* the fold's merge of arrivals and expiries in its two forms
+  (compiler/window_merge.py; PR 34), on the cell's concat sequence
+  (ring 1,000 + tape 524,288 rows, two value planes, 1,024 group slots):
+  ``merge_order_scatter`` (the ranked form's order: a histogram
+  scatter-add, a cumsum, a gather, two scatters over 2N; the static form
+  builds no order, ``merge_order_static`` is a line that says so),
+  ``merge_read_gather`` (codes, live flags and value rows gathered
+  through that order) against ``merge_read_halves`` (the static form's
+  tiles: slices and concatenations) and ``merge_read_interleave`` (the
+  layout not taken: the same rows interleaved in memory with
+  ``stack(...).reshape``), ``merge_back_gather`` (``R[m_arr]``) against
+  ``merge_back_slice`` (``R[:N]``; for the interleave the odd rows of
+  ``R[C : C + 2E]``), and ``tile_fold[ranked|static]``, the tiled sums
+  both share, on each form's tiles;
 * ``step_acc[prefix|hole]``: the whole step of ``window1k``'s query on a
   tape whose mask is a prefix (the cell's) and on the same tape with one
   row invalid, which takes the scatters; under each, from a profiler
@@ -32,7 +46,9 @@ mask is a prefix (a select, a slice):
 
 Usage (the chip tool): python scripts/profile_window.py
 (a number cuts the tape for a rehearsal on the CPU; ``--step-only``
-leaves the pieces out).
+leaves the pieces out; ``compile`` runs nothing: it compiles the step
+for a described v5e, no chip, and lists the gathers, scatters and sorts
+the compiled program holds, each beside its scope).
 Run from another checkout's root, it times that checkout's step (one
 that lacks compiler/compact.py gives the pieces it has).
 One line per piece, ``<name> <ms>``, then one JSON line naming the
@@ -178,7 +194,96 @@ def pieces(rng):
     timed("fold_batch_rows[hole]", way_back, hole, seqs)
 
 
-def whole_step(rng):
+def merge_pieces(rng):
+    try:
+        from flink_siddhi_tpu.compiler.window_merge import (
+            blocked_tiling, merge_order, ranked_merge, static_merge,
+            tile_fold)
+    except ImportError:
+        print("merge: this checkout has no compiler/window_merge.py")
+        return
+    N, K, G = C + E, 2, 1_024
+    tile, chunk = blocked_tiling()
+    codes = jnp.asarray(rng.integers(0, 1_000, N).astype(np.int32))
+    live = jnp.asarray(np.arange(N) < N - 37)
+    V_n = jnp.asarray(
+        np.stack([rng.random(N) * 100.0, np.ones(N)], 1).astype(np.float32))
+    exp_rank = jnp.arange(N, dtype=jnp.int32) + C  # a length window's
+    m_arr, src = jax.jit(merge_order)(exp_rank)
+
+    @jax.jit
+    def read_gather(codes, live, V_n, src):
+        is_arr = src < N
+        idx = jnp.where(is_arr, src, src - N)
+        sign = jnp.where(is_arr, 1.0, -1.0).astype(jnp.float32)
+        return codes[idx], jnp.where(
+            live[idx][:, None], V_n[idx] * sign[:, None], 0.0)
+
+    def weave(x, neg):
+        # head ++ interleave(expiry of p, arrival of p + C) ++ tail
+        pairs = jnp.stack([neg(x[:E]), x[C:]], axis=1)
+        return jnp.concatenate(
+            [x[:C], pairs.reshape((2 * E,) + x.shape[1:]), neg(x[E:])])
+
+    @jax.jit
+    def read_interleave(codes, live, V_n):
+        V = jnp.where(live[:, None], V_n, 0.0)
+        return weave(codes, lambda c: c), weave(V, lambda v: -v)
+
+    @jax.jit
+    def read_halves(codes, live, V_n):
+        return static_merge(codes, live, V_n, C, tile, chunk)[:2]
+
+    @jax.jit
+    def back_gather(R, m_arr):
+        return R[m_arr]
+
+    @jax.jit
+    def back_weave(R):
+        return jnp.concatenate([R[:C], R[C + 1:C + 2 * E:2]])
+
+    @jax.jit
+    def back_slice(R):
+        return R[:N]
+
+    @jax.jit
+    def fold_ranked(codes, live, V_n, exp_rank):
+        return tile_fold(ranked_merge(codes, live, V_n, exp_rank, tile,
+                                      chunk), G, (), chunk)
+
+    @jax.jit
+    def fold_static(codes, live, V_n):
+        return tile_fold(static_merge(codes, live, V_n, C, tile, chunk),
+                         G, (), chunk)
+
+    timed("merge_order_scatter", jax.jit(merge_order), exp_rank)
+    print("merge_order_static 0.000 ms (no device work: the order is the "
+          "tiles' constant precedence matrix)")
+    timed("merge_read_gather", read_gather, codes, live, V_n, src)
+    timed("merge_read_halves", read_halves, codes, live, V_n)
+    timed("merge_read_interleave", read_interleave, codes, live, V_n)
+    got, want = read_interleave(codes, live, V_n), read_gather(
+        codes, live, V_n, src)
+    dead = np.asarray(want[1] == 0).all(axis=1)  # a dead row's code is free
+    print("  interleave == gather:", bool(
+        (np.asarray(got[0]) == np.asarray(want[0]))[~dead].all()
+        and (np.asarray(got[1]) == np.asarray(want[1])).all()))
+    R2 = jnp.asarray(rng.random((2 * N, K)).astype(np.float32))
+    timed("merge_back_gather", back_gather, R2, m_arr)
+    timed("merge_back_slice[interleave]", back_weave, R2)
+    timed("merge_back_slice[halves]", back_slice, R2)
+    timed("tile_fold[ranked]", fold_ranked, codes, live, V_n, exp_rank)
+    timed("tile_fold[static]", fold_static, codes, live, V_n)
+    a, b = fold_ranked(codes, live, V_n, exp_rank), fold_static(
+        codes, live, V_n)
+    keep = np.asarray(live)
+    print("  static == ranked: counts", bool(
+        (np.asarray(a[1])[keep] == np.asarray(b[1])[keep]).all()),
+        "sums within", float(np.abs(
+            np.asarray(a[0])[keep] - np.asarray(b[0])[keep]).max()))
+
+
+def plan_and_tape(rng):
     from flink_siddhi_tpu.compiler.plan import compile_plan
     from flink_siddhi_tpu.runtime.tape import build_tape
     from flink_siddhi_tpu.schema.batch import EventBatch
@@ -199,6 +304,41 @@ def whole_step(rng):
     }
     batch = EventBatch("inputStream", schema, cols, ts)
     tape, _ = build_tape(plan.spec, [batch], 0, capacity=E, want_prov=False)
+    return plan, tape
+
+
+def compile_only(rng):
+    """The step compiled for a described v5e (no chip, nothing runs):
+    which gathers, scatters and sorts the program holds, by scope."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    one = SingleDeviceSharding(topo.devices[0])
+    plan, tape = plan_and_tape(rng)
+    args = (plan.init_state(), jax.eval_shape(plan.init_acc), tape)
+    shaped = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype, sharding=one),
+        args)
+    t0 = time.perf_counter()
+    compiled = jax.jit(plan.step_acc, donate_argnums=(0, 1)).lower(
+        *shaped).compile()
+    print(f"compiled in {time.perf_counter() - t0:.1f} s")
+    print(compiled.memory_analysis())
+    seen = collections.Counter(
+        (kind, shape.split("{")[0], scope.split("step_acc)/")[-1])
+        for shape, kind, scope in re.findall(
+            r'= (\S+) (gather|scatter|sort)\([^\n]*?op_name="([^"]*)"',
+            compiled.as_text()))
+    for (kind, shape, scope), n in sorted(seen.items()):
+        print(f"  {n:3d} x {kind:8s}{shape:24s} {scope}")
+
+
+def whole_step(rng):
+    plan, tape = plan_and_tape(rng)
     valid = np.asarray(tape.valid).copy()
     valid[11] = False
     tapes = {
@@ -256,12 +396,15 @@ def device_ops(name, fn, states, acc, tape, scope_of, steps=5, top=24):
 
 def main():
     global E
+    if sys.argv[1:] == ["compile"]:
+        return compile_only(np.random.default_rng(32))
     args = [a for a in sys.argv[1:] if a != "--step-only"]
     if args:
         E = int(args[0])
     rng = np.random.default_rng(32)
     if "--step-only" not in sys.argv:
         pieces(rng)
+        merge_pieces(rng)
     whole_step(rng)
     print(json.dumps({"device": str(jax.devices()[0]), "root": ROOT}))
 
