@@ -263,9 +263,18 @@ class ShardedJob(Job):
         # (drained in bulk through Job's drain queue and fetch thread).
         # The tape is committed, one row per chip: the call moves nothing
         with tel.span("dispatch"):
+            self._issue_step()
             rt.states, rt.acc = rt.jitted_acc(
                 rt.states, rt.acc, stacked_tape
             )
+            clock = self._starve_clock()
+            if clock is not None:
+                # a ticket for the starvation clock alone (no ticket
+                # window, no segment record on a mesh): the step's own
+                # count prefix, ready when every shard has finished the
+                # step. The next step donates it, after the clock has
+                # dropped it for that step's (StarveClock.watch)
+                clock.watch(rt.acc["meta"])
             rt.acc_dirty = True
             if rt.dirty_since is None:
                 rt.dirty_since = time.monotonic()

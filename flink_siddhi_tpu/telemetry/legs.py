@@ -28,8 +28,12 @@ histograms, one sample per batch weighted by its events:
 
 The stamps are turned into whole microseconds before they are
 differenced, so the five legs of a batch sum to its ``leg.total``
-exactly. ``complete`` has the resolution of the polls that take it: one
-run cycle (docs/observability.md).
+exactly. ``complete`` is stamped by the starvation clock's polls
+(telemetry/starve.py ``stamp_complete``: every span boundary of the run
+loop while work is queued), so it has the resolution of one span, not
+of one run cycle: ``leg.device`` ends, and ``leg.drain_wait`` or
+``leg.drain`` begins, at the first boundary after the segment's ticket
+turned ready (docs/observability.md).
 """
 
 from __future__ import annotations
@@ -47,8 +51,7 @@ class SegmentRecord:
     """The host stamps of one dispatched segment."""
 
     __slots__ = (
-        "seg", "arrival", "staged", "events", "dispatch", "ticket",
-        "complete",
+        "seg", "arrival", "staged", "events", "dispatch", "complete",
     )
 
     def __init__(
@@ -64,19 +67,10 @@ class SegmentRecord:
         self.staged = staged  # per batch
         self.events = events  # per batch
         self.dispatch = dispatch
-        # the segment's ticket, held until the host has seen it ready
-        self.ticket = None
+        # the first boundary at which the host saw the segment's ticket
+        # ready (the starvation clock holds the ticket), or the drain's
+        # meta if that was seen ready first
         self.complete: Optional[float] = None
-
-    def poll_complete(self, now: float) -> bool:
-        """Stamps ``complete`` if the ticket is ready; whether the
-        record is complete."""
-        if self.complete is None:
-            if not self.ticket.is_ready():
-                return False
-            self.complete = now
-        self.ticket = None
-        return True
 
 
 def _us(seconds) -> np.ndarray:

@@ -21,13 +21,20 @@ Every span is also a ``jax.profiler.TraceAnnotation`` named
 the clock of the device planes (plane ``/host:CPU``, line ``python``),
 with the keyword stats the caller gave (``seg=<ordinal>``). With no
 session open an annotation costs under a microsecond.
+
+A span's enter and exit on the run-loop thread are also the boundaries
+of the job's starvation clock (telemetry/starve.py), where it has one:
+a span entered while nothing is queued on the device carries the stat
+``starved=1``. The clock's own work at a boundary falls inside the span.
 """
 
 from __future__ import annotations
 
 import threading
 import time
-from typing import Dict
+from typing import Dict, Optional
+
+from .starve import StarveClock
 
 from jax.profiler import TraceAnnotation
 
@@ -48,25 +55,39 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_st", "_name", "_t0", "_nested", "_ann")
+    __slots__ = ("_st", "_name", "_stats", "_t0", "_nested", "_ann",
+                 "_clock")
 
     def __init__(self, st: "StageTimes", name: str, stats: Dict) -> None:
         self._st = st
         self._name = name
-        self._ann = TraceAnnotation("fst." + name, **stats)
+        self._stats = stats
 
     def __enter__(self):
-        tls = self._st._tls
+        st = self._st
+        tls = st._tls
         depth = getattr(tls, "depth", 0)
         self._nested = depth > 0
         tls.depth = depth + 1
-        self._ann.__enter__()
         self._t0 = time.perf_counter()
+        stats = self._stats
+        clock = st.starve
+        if clock is not None and clock.owner != threading.get_ident():
+            clock = None  # not the run loop's thread
+        self._clock = clock
+        if clock is not None and clock.enter(self._name, self._nested):
+            stats = dict(stats, starved=1)
+        self._ann = TraceAnnotation("fst." + self._name, **stats)
+        self._ann.__enter__()
         return self
 
     def __exit__(self, *exc):
-        dt = time.perf_counter() - self._t0
+        if self._clock is not None:
+            # before the annotation closes: the onset marker of a queue
+            # that ran empty in this span lies inside it
+            self._clock.exit(self._nested)
         self._ann.__exit__(*exc)
+        dt = time.perf_counter() - self._t0
         self._st._tls.depth -= 1
         self._st.add(self._name, dt, nested=self._nested)
         return False
@@ -80,6 +101,9 @@ class StageTimes:
         self._totals: Dict[str, float] = {}
         self._counts: Dict[str, int] = {}
         self._tls = threading.local()
+        # the job's starvation clock, while it has one (the executor
+        # makes and drops it: Job._starve_clock)
+        self.starve: Optional[StarveClock] = None
 
     def span(self, name: str, **stats) -> _Span:
         """``stats`` go to the profiler annotation only."""
