@@ -20,6 +20,11 @@ sees ``EventBatch.timestamps``) and emit (which sees row timestamps)
 agree on the sample with no id plumbed through the device path — the
 jitted program is untouched, same as every other telemetry hook.
 
+The rule is evaluated in the stamps' own width: the epoch is folded
+into a scalar residue (``(ts + epoch) % N == 0`` iff ``ts % N ==
+-epoch % N``), so no int64 copy of a delivery is made, and a
+power-of-two ``sample_every`` is tested with ``&``.
+
 Semantics of a completion: emitted rows are keyed by their emission
 timestamp, which for filters/patterns is the timestamp of the event
 that *completed* the match. A trace therefore measures "ingest of the
@@ -80,8 +85,18 @@ class TraceSampler:
         return self.sample_every > 0 and self.registry.enabled
 
     # -- sampling rule -----------------------------------------------------
-    def _mask(self, abs_ts: np.ndarray) -> np.ndarray:
-        return (abs_ts % self.sample_every) == 0
+    def _hits(self, ts: np.ndarray, epoch: int = 0) -> np.ndarray:
+        """Positions of the stamps with ``(ts + epoch) % sample_every
+        == 0``."""
+        every = self.sample_every
+        if ts.dtype.kind != "i" or every > np.iinfo(ts.dtype).max:
+            ts = ts.astype(np.int64)
+        scalar = ts.dtype.type
+        if every & (every - 1) == 0:
+            residue = ts & scalar(every - 1)
+        else:
+            residue = ts % scalar(every)
+        return np.flatnonzero(residue == scalar(-epoch % every))
 
     # -- ingest ------------------------------------------------------------
     def stamp_ingest(self, timestamps) -> None:
@@ -92,7 +107,7 @@ class TraceSampler:
         ts = np.asarray(timestamps)
         if ts.size == 0:
             return
-        hits = ts[self._mask(ts)]
+        hits = ts[self._hits(ts)]
         if hits.size == 0:
             return
         now = time.monotonic()
@@ -158,15 +173,14 @@ class TraceSampler:
         with self._lock:
             if not self._pending:
                 return
-        abs_ts = rel.astype(np.int64) + int(epoch_ms)
-        idx = np.nonzero(self._mask(abs_ts))[0]
+        epoch = int(epoch_ms)
+        idx = self._hits(rel, epoch)
         if idx.size == 0:
             return
         now = time.monotonic()
         samples: List[float] = []
         with self._lock:
-            for i in idx.tolist():
-                t = int(abs_ts[i])
+            for t in (rel[idx].astype(np.int64) + epoch).tolist():
                 t0 = self._pending.pop(t, None)
                 if t0 is None:
                     continue  # already completed (or never sampled here)
