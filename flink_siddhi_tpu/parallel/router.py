@@ -28,6 +28,7 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from ..query.planner import StreamPartition
+from ..runtime.tape import TapeRows
 from ..schema.batch import EventBatch
 
 _FNV_OFFSET = np.uint64(1469598103934665603)
@@ -35,8 +36,11 @@ _FNV_PRIME = np.uint64(1099511628211)
 
 
 def hash_columns(cols: Sequence[np.ndarray], n: int) -> np.ndarray:
-    """Vectorized FNV-1a-style mix over the key columns -> uint64[n]."""
+    """Vectorized FNV-1a-style mix over the key columns -> uint64[n].
+    Mixed in place: a batch's worth of words is past what the allocator
+    keeps mapped, so every temporary of that size is paged in anew."""
     h = np.full(n, _FNV_OFFSET, dtype=np.uint64)
+    shifted = np.empty(n, dtype=np.uint64)
     with np.errstate(over="ignore"):
         for c in cols:
             if c.dtype.kind == "f":
@@ -49,8 +53,10 @@ def hash_columns(cols: Sequence[np.ndarray], n: int) -> np.ndarray:
                 v = c.astype(np.uint64)
             else:
                 v = np.ascontiguousarray(c, dtype=np.int64).view(np.uint64)
-            h = (h ^ v) * _FNV_PRIME
-            h ^= h >> np.uint64(33)
+            np.bitwise_xor(h, v, out=h)
+            np.multiply(h, _FNV_PRIME, out=h)
+            np.right_shift(h, np.uint64(33), out=shifted)
+            np.bitwise_xor(h, shifted, out=h)
     return h
 
 
@@ -97,18 +103,31 @@ class Router:
                 return [None] * S
             bounds = self._segment_bounds([batch.timestamps])
             return self._split_segments(batch, bounds)
-        if part.kind == "groupby" and part.keys:
-            cols = [batch.columns[k] for k in part.keys]
-            assign = (hash_columns(cols, n) % np.uint64(S)).astype(np.int64)
-        else:  # shuffle
-            start = self._rr.get(batch.stream_id, 0)
-            assign = (start + np.arange(n, dtype=np.int64)) % S
-            self._rr[batch.stream_id] = int((start + n) % S)
+        assign = self._keyed_shards(batch, part)
         out: List[Optional[EventBatch]] = []
         for s in range(S):
             idx = np.nonzero(assign == s)[0]
             out.append(batch.take(idx) if len(idx) else None)
         return out
+
+    def _keyed_shards(
+        self, batch: EventBatch, part: StreamPartition
+    ) -> np.ndarray:
+        """The shard of each row of a ``groupby`` stream (its key's
+        hash) or a ``shuffle`` stream (round-robin from the stream's
+        cursor, which moves on)."""
+        n = len(batch)
+        S = self.n_shards
+        if part.kind == "groupby" and part.keys:
+            h = hash_columns([batch.columns[k] for k in part.keys], n)
+            if S & (S - 1) == 0:
+                # a power of two: the remainder is the low bits, at a
+                # tenth of what the division of 64-bit words costs
+                return np.bitwise_and(h, np.uint64(S - 1), out=h)
+            return np.remainder(h, np.uint64(S), out=h)
+        start = self._rr.get(batch.stream_id, 0)
+        self._rr[batch.stream_id] = int((start + n) % S)
+        return (start + np.arange(n, dtype=np.int64)) % S
 
     def route_all(
         self, batches: Sequence[EventBatch]
@@ -120,14 +139,7 @@ class Router:
         involved stream covers the same time slice — the contract the
         segment-parallel chain matcher's shard-to-shard handoff needs."""
         shards: List[List[EventBatch]] = [[] for _ in range(self.n_shards)]
-        seg = [
-            b
-            for b in batches
-            if self.partition_of(b.stream_id).kind == "segment"
-        ]
-        bounds = None
-        if seg and self.n_shards > 1:
-            bounds = self._segment_bounds([b.timestamps for b in seg])
+        bounds = self._shared_bounds(batches)
         for b in batches:
             if (
                 bounds is not None
@@ -145,6 +157,68 @@ class Router:
         for s, pieces in enumerate(shards):
             self.routed[s] += sum(len(p) for p in pieces)
         return shards
+
+    def select(self, batches: Sequence[EventBatch]) -> TapeRows:
+        """``route_all`` without the copies: which rows of ``batches``
+        (numbered end to end) each shard receives, in the order
+        ``build_tape`` puts the shard's pieces — by timestamp, ties by
+        arrival. One stable sort of the per-row shard number; no column
+        is gathered. Counts, cursors and ``segment`` boundaries are
+        ``route_all``'s."""
+        S = self.n_shards
+        small = np.min_scalar_type(S - 1)  # a byte a row: a radix sort
+        bounds = self._shared_bounds(batches)
+        shard, fanned = [], []
+        for b in batches:
+            n = len(b)
+            part = self.partition_of(b.stream_id)
+            fanned.append(S > 1 and part.kind == "replicate")
+            if S == 1 or part.kind == "broadcast":
+                to = np.zeros(n, dtype=small)
+            elif part.kind == "replicate":
+                to = np.repeat(np.arange(S, dtype=small), n)
+            elif part.kind == "segment":
+                # left-closed: an event equal to a boundary goes right
+                to = np.searchsorted(bounds, b.timestamps, side="right")
+            else:
+                to = self._keyed_shards(b, part)
+            shard.append(to.astype(small, copy=False))
+
+        def end_to_end(arrays):  # one batch: as it lies, no copy
+            return arrays[0] if len(arrays) == 1 else np.concatenate(arrays)
+
+        shard = end_to_end(shard)
+        ts = end_to_end([b.timestamps for b in batches])
+        # the rows as they lie, unless some go to every shard or the
+        # batches' stamps interleave
+        rows = None
+        if any(fanned):
+            starts = np.cumsum([0] + [len(b) for b in batches])
+            rows = np.concatenate([
+                np.tile(np.arange(at, at + len(b)), S if fan else 1)
+                for b, at, fan in zip(batches, starts, fanned)
+            ])
+            ts = ts[rows]
+        if not np.all(ts[1:] >= ts[:-1]):
+            by_ts = np.argsort(ts, kind="stable")
+            shard = shard[by_ts]
+            rows = by_ts if rows is None else rows[by_ts]
+        order = np.argsort(shard, kind="stable")
+        offsets = np.searchsorted(shard[order], np.arange(S + 1))
+        self.routed += np.diff(offsets)
+        return TapeRows(order if rows is None else rows[order], offsets)
+
+    def _shared_bounds(self, batches) -> Optional[np.ndarray]:
+        """The boundary timestamps a cycle's ``segment`` streams share
+        (None: no such stream, or one shard)."""
+        seg = [
+            b.timestamps
+            for b in batches
+            if self.partition_of(b.stream_id).kind == "segment"
+        ]
+        if not seg or self.n_shards == 1:
+            return None
+        return self._segment_bounds(seg)
 
     def _segment_bounds(self, ts_arrays: List[np.ndarray]) -> np.ndarray:
         """Equal-count quantile boundary timestamps over the union of the

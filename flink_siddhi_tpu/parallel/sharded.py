@@ -6,8 +6,8 @@ hosting a full copy of every execution plan (AbstractSiddhiOperator.java:
 with a ``NamedSharding`` so each device owns its shard; the jitted step is a
 ``jax.shard_map`` that advances every shard's plan in ONE SPMD program. Events
 reach shards through the host Router (key-hash / round-robin / broadcast —
-the DynamicPartitioner contract) as per-shard tapes stacked to a common
-bucketed capacity.
+the DynamicPartitioner contract) as one selection of a cycle's rows, built
+once into tapes of a common bucketed capacity, one row of each leaf a shard.
 
 On a real TPU slice the ``shards`` axis rides ICI; in tests it is an 8-device
 virtual CPU mesh (the MiniCluster analog, SURVEY.md §4).
@@ -240,7 +240,7 @@ class ShardedJob(Job):
             return
         router = self._routers[plan.plan_id]
         with tel.span("route"):
-            shards = router.route_all(involved)
+            rows = router.select(involved)
         # per-shard placement visibility: a skewed key distribution
         # shows up here long before it shows up as one hot shard
         tel.gauge(
@@ -251,10 +251,10 @@ class ShardedJob(Job):
         # shape instead of bucketing down into a fresh XLA executable
         rt.tape_capacity = max(
             rt.tape_capacity,
-            bucket_size(max(sum(len(b) for b in sh) for sh in shards) or 1),
+            bucket_size(int(rows.counts.max()) or 1),
         )
         with tel.span("tape_build"):
-            stacked_tape = self._stage_tapes(rt, shards)
+            stacked_tape = self._stage_tapes(rt, involved, rows)
         # host-driven re-bucketing after group growth is staging-class
         # work (device_get + per-shard rebuild + explicit device_put)
         with _staging_allow():
@@ -293,20 +293,20 @@ class ShardedJob(Job):
             ),
         )
 
-    def _stage_tapes(self, rt: _PlanRuntime, shards):
-        """One cycle's per-shard tapes, stacked leaf by leaf in host
-        memory to ``[n_shards, capacity]`` and uploaded in ONE explicit
-        sharded put: row ``s`` goes straight to the chip that steps
-        shard ``s``, and no eager device program runs per leaf."""
+    def _stage_tapes(self, rt: _PlanRuntime, involved, rows):
+        """One cycle's tapes from one ``build_tape`` call with the
+        router's selection: each leaf comes out ``[n_shards,
+        capacity]``, row ``s`` filled in place with the tape of the
+        rows shard ``s`` receives, and is uploaded in ONE explicit
+        sharded put: row ``s`` goes straight to the chip that steps shard
+        ``s``, and no eager device program runs per leaf."""
         tel = self.telemetry
-        tapes = [
-            build_tape(
-                rt.plan.spec, sh, self._epoch_ms, rt.tape_capacity,
-                want_prov=False,
+        with tel.span("shard_build"):
+            stacked = build_tape(
+                rt.plan.spec, involved, self._epoch_ms, rt.tape_capacity,
+                want_prov=False, rows=rows,
             )[0]
-            for sh in shards
-        ]
-        stacked = jax.tree.map(lambda *xs: np.stack(xs), *tapes)
+        tel.inc("shard.tape_builds")
         with tel.span("shard_put"):
             stacked = jax.device_put(stacked, self._state_sharding)
         tel.inc("shard.tape_puts")

@@ -177,6 +177,23 @@ class Tape:
         return cls(ts, stream, valid, cols, time_off)
 
 
+@dataclass(frozen=True)
+class TapeRows:
+    """The rows of a cycle's batches that each of several tapes takes:
+    tape ``r`` holds rows ``order[offsets[r]:offsets[r + 1]]`` of the
+    batches laid end to end, in that order (the router's: by timestamp,
+    ties by arrival). A row may appear in several tapes. ``build_tape``
+    fills them all in one call and returns ``[tapes, capacity]``
+    leaves, row ``r`` being the tape of run ``r``'s rows alone."""
+
+    order: np.ndarray  # intp[R]
+    offsets: np.ndarray  # intp[tapes + 1], offsets[0] == 0
+
+    @property
+    def counts(self) -> np.ndarray:
+        return np.diff(self.offsets)
+
+
 # --------------------------------------------------------------------------
 # Wire tape: the narrow host->device format
 # --------------------------------------------------------------------------
@@ -479,13 +496,17 @@ def _merged_stream_values(
     batch carries the stream. THE single implementation of the
     batches->merged-order scatter (device columns and host-predicate
     inputs both go through it). Native host dtype unless ``dtype`` is
-    given. Single-batch results may alias the batch's column — callers
-    must copy before retaining."""
+    given. ``total`` counts the batches' rows; ``order`` may take some
+    of them, or one twice (a run of ``TapeRows.order``). Single-batch
+    results may alias the batch's column — callers must copy before
+    retaining."""
     if len(batches) == 1:
         b = batches[0]
         if b.stream_id != stream_id:
             return None
         col = b.columns[field]
+        if not identity:
+            col = col[order]
         return col if dtype is None else col.astype(dtype, copy=False)
     merged = None
     offset = 0
@@ -514,9 +535,11 @@ def _check_i32_span(key: str, vals: np.ndarray, origin: int, why: str):
         )
 
 
-def _intern_groups(spec, batches, cols, stream, total, order, identity,
-                   known=None, ts=None):
+def _intern_groups(spec, merged, new, cols, stream, total, known=None,
+                   ts=None):
     """Group keys -> dense codes (``spec.encoded``), added to ``cols``.
+    ``merged(stream_id, field, dtype)`` reads a host column in the
+    tape's order (``_merged_stream_values``), ``new`` makes a leaf.
     ``known`` (out_key -> the codes an earlier build of these batches
     interned) are taken as they are: a table whose slots expire hands
     out other slots the second time. ``ts``: the tape's own timestamps,
@@ -524,7 +547,6 @@ def _intern_groups(spec, batches, cols, stream, total, order, identity,
     the event's timestamp)."""
     if not spec.encoded:
         return
-    cap = len(stream)
     view = {k: v[:total] for k, v in cols.items()}
     if ts is not None:
         view["@ts"] = ts[:total]
@@ -541,8 +563,8 @@ def _intern_groups(spec, batches, cols, stream, total, order, identity,
             # the raw column was pruned off the wire (group values
             # travel as codes); intern from the host batches
             sid_k, fld_k = k.split(".", 1)
-            col = _merged_stream_values(
-                batches, sid_k, fld_k, total, order, identity,
+            col = merged(
+                sid_k, fld_k,
                 spec.column_types[k].device_dtype
                 if k in spec.column_types
                 else None,
@@ -576,9 +598,34 @@ def _intern_groups(spec, batches, cols, stream, total, order, identity,
             )
         if not enc.materialize:
             continue  # interning side effect only
-        col = np.zeros(cap, dtype=np.int32)
+        col = new(enc.out_key, np.int32)
         col[:total] = codes
         cols[enc.out_key] = col
+
+
+def _new_leaf(shape, dtype, fill=0) -> np.ndarray:
+    if fill:
+        return np.full(shape, fill, dtype=dtype)
+    return np.zeros(shape, dtype=dtype)
+
+
+class _LeafRows:
+    """The leaves of several tapes, ``[tapes, cap]`` each, handed out a
+    row at a time (``leaves`` by name: ``ts``, ``stream``, ``valid`` and
+    the columns' keys, which all hold a ``.`` or an ``@``): tape ``row``
+    is filled in place, and what no tape writes of a zeroed leaf stays
+    the zero page it was mapped as."""
+
+    def __init__(self, tapes: int, cap: int) -> None:
+        self.shape = (tapes, cap)
+        self.leaves: Dict[str, np.ndarray] = {}
+        self.row = 0
+
+    def __call__(self, name, dtype, fill=0) -> np.ndarray:
+        leaf = self.leaves.get(name)
+        if leaf is None:
+            leaf = self.leaves[name] = _new_leaf(self.shape, dtype, fill)
+        return leaf[self.row]
 
 
 def build_tape(
@@ -589,6 +636,7 @@ def build_tape(
     want_prov: bool = True,
     intern_span=None,
     codes=None,
+    rows: Optional[TapeRows] = None,
 ) -> Tuple[Tape, np.ndarray]:
     """Merge per-stream batches into one padded, ts-sorted host tape.
 
@@ -600,12 +648,19 @@ def build_tape(
     group interning: the executor's nested ``group_intern`` span.
     ``codes``: group codes that an earlier build of the same batches
     interned (``_intern_groups``), for a rebuild.
+    ``rows`` (the mesh's): several tapes of ``capacity`` each, every one
+    of its own run of the batches' rows (``TapeRows``). Each leaf comes
+    back ``[tapes, capacity]``, row ``r`` filled in place by the rules
+    that fill one tape (so run after run meets the encoders as tape
+    after tape would), and the provenance in the runs' order, end to
+    end. No batch is copied and no tape stacked on the way.
     Arrays are numpy; the jitted step's donate/commit moves them to device.
     """
     total = sum(len(b) for b in batches)
-    cap = capacity if capacity is not None else bucket_size(total)
-    if total > cap:
-        raise ValueError(f"{total} events exceed tape capacity {cap}")
+    widest = total if rows is None else int(rows.counts.max(initial=0))
+    cap = capacity if capacity is not None else bucket_size(widest)
+    if widest > cap:
+        raise ValueError(f"{widest} events exceed tape capacity {cap}")
 
     ts_all = np.empty(total, dtype=np.int64)
     stream_all = np.empty(total, dtype=np.int32)
@@ -624,6 +679,25 @@ def build_tape(
             prov[offset : offset + n, 1] = np.arange(n)
         offset += n
 
+    def fill(order, identity, new):
+        return _fill_tape(
+            spec, batches, epoch_ms, ts_all, stream_all, order, identity,
+            cap, new, intern_span, codes,
+        )
+
+    if rows is not None:
+        cuts = rows.offsets.tolist()
+        new = _LeafRows(len(cuts) - 1, cap)
+        for r in range(len(cuts) - 1):
+            new.row = r
+            one = fill(rows.order[cuts[r] : cuts[r + 1]], False, new)
+        leaves = new.leaves
+        tape = Tape(
+            leaves["ts"], leaves["stream"], leaves["valid"],
+            {k: leaves[k] for k in one.cols}, one.time_off,
+        )
+        return tape, prov if prov is None else prov[rows.order]
+
     # per-stream batches arrive time-sorted (the reorder buffer sorts on
     # release), so a single-batch cycle — and any multi-batch cycle whose
     # concatenation happens to interleave in order — needs no argsort at
@@ -631,38 +705,55 @@ def build_tape(
     # and, more importantly, all the gather copies behind it
     identity = total == 0 or bool(np.all(ts_all[1:] >= ts_all[:-1]))
     order = None
+    if not identity:
+        order = np.argsort(ts_all, kind="stable")
+        if prov is not None:
+            prov = prov[order]
+    return fill(
+        order, identity,
+        lambda name, dtype, fill=0: _new_leaf(cap, dtype, fill),
+    ), prov
+
+
+def _fill_tape(spec, batches, epoch_ms, ts_all, stream_all, order, identity,
+               cap, new, intern_span, codes) -> Tape:
+    """One tape's leaves (``new(name, dtype, fill)`` makes each, ``cap``
+    long) from the rows ``order`` takes of the batches laid end to end
+    (``identity``: all of them, as they lie; ``ts_all`` and
+    ``stream_all`` are their stamps and stream codes)."""
     if identity:
         ts_sorted = ts_all
         stream_sorted = stream_all
     else:
-        order = np.argsort(ts_all, kind="stable")
         ts_sorted = ts_all[order]
         stream_sorted = stream_all[order]
-        if prov is not None:
-            prov = prov[order]
+    total = len(ts_sorted)
 
-    ts = np.zeros(cap, dtype=np.int32)
+    def merged(stream_id, field, dtype=None):
+        return _merged_stream_values(
+            batches, stream_id, field, len(ts_all), order, identity, dtype
+        )
+
+    ts = new("ts", np.int32)
     ts[:total] = (ts_sorted - epoch_ms).astype(np.int32)
     # padding gets the max timestamp so time-window logic never treats
     # padding as "newest event"
     if total and total < cap:
         ts[total:] = ts[total - 1]
-    stream = np.full(cap, -1, dtype=np.int32)
+    stream = new("stream", np.int32, -1)
     stream[:total] = stream_sorted
-    valid = np.zeros(cap, dtype=np.bool_)
+    valid = new("valid", np.bool_)
     valid[:total] = True
 
     cols: Dict[str, np.ndarray] = {}
     for key in spec.built_columns():
         stream_id, field = key.split(".", 1)
         dtype = spec.column_types[key].device_dtype
-        col = np.zeros(cap, dtype=dtype)
+        col = new(key, dtype)
         if key in spec.time_columns:
             # read as a value too: the raw long, which has to fit the
             # device's int32 (the window's read does not use it)
-            vals = _merged_stream_values(
-                batches, stream_id, field, total, order, identity, np.int64
-            )
+            vals = merged(stream_id, field, np.int64)
             if vals is not None and total:
                 _check_i32_span(
                     key, vals, 0,
@@ -671,9 +762,7 @@ def build_tape(
                     "read of it as time rides the job's clock",
                 )
         else:
-            vals = _merged_stream_values(
-                batches, stream_id, field, total, order, identity, dtype
-            )
+            vals = merged(stream_id, field, dtype)
         if vals is not None:
             col[:total] = vals
         cols[key] = col
@@ -684,10 +773,8 @@ def build_tape(
     for key in spec.time_columns:
         # the host's int64 value, rebased: never cut to 32 bits
         stream_id, field = key.split(".", 1)
-        col = np.zeros(cap, dtype=np.int32)
-        vals = _merged_stream_values(
-            batches, stream_id, field, total, order, identity, np.int64
-        )
+        col = new(time_key(key), np.int32)
+        vals = merged(stream_id, field, np.int64)
         if vals is not None and total:
             vals = vals - origin
             if len(spec.stream_codes) > 1:
@@ -706,8 +793,7 @@ def build_tape(
         cols[time_key(key)] = col
 
     with (intern_span or contextlib.nullcontext)():
-        _intern_groups(spec, batches, cols, stream, total, order, identity,
-                       codes, ts)
+        _intern_groups(spec, merged, new, cols, stream, total, codes, ts)
     # wire predicate pushdown: evaluate each host predicate over the
     # merged-order RAW host columns (f64 where the schema says DOUBLE)
     # and add the result as a bool pseudo-column — it ships bit-packed,
@@ -720,9 +806,7 @@ def build_tape(
                 henv[key] = ts_sorted[:total]
                 continue
             stream_id, fname = key.split(".", 1)
-            vals = _merged_stream_values(
-                batches, stream_id, fname, total, order, identity
-            )
+            vals = merged(stream_id, fname)
             henv[key] = (
                 vals
                 if vals is not None
@@ -732,8 +816,8 @@ def build_tape(
             res = np.broadcast_to(
                 np.asarray(hp.fn(henv), dtype=hp.dtype), (total,)
             )
-            col = np.zeros(cap, dtype=hp.dtype)
+            col = new(hp.out_key, hp.dtype)
             col[:total] = res
             cols[hp.out_key] = col
 
-    return Tape(ts, stream, valid, cols, time_off), prov
+    return Tape(ts, stream, valid, cols, time_off)

@@ -285,6 +285,47 @@ def test_sharded_job_double_recovery_roundtrip(tmp_path):
     )
 
 
+def test_sharded_shuffle_cursor_survives_a_checkpoint():
+    """A round-robin stream's cursor is router state: restored from a
+    snapshot taken mid-turn (batches of 7 over 4 shards), the second
+    life deals the next event to the shard the first would have, so the
+    two lives place every event where one uninterrupted run does."""
+    from flink_siddhi_tpu.compiler.plan import compile_plan
+    from flink_siddhi_tpu.parallel import ShardedJob, make_cep_mesh
+
+    events = make_events(49)
+    cql = "from S[id == 2] select id, name, price insert into out"
+
+    def build(evs):
+        env = CEPEnvironment(batch_size=7)
+        env.register_stream("S", evs, FIELDS)
+        plan = compile_plan(
+            cql, {"S": env.schemas["S"]}, extensions=env.extensions
+        )
+        job = ShardedJob(
+            [plan], [env.sources["S"]], mesh=make_cep_mesh(4), batch_size=7
+        )
+        (router,) = job._routers.values()
+        assert router.partition_of("S").kind == "shuffle"
+        return job, router
+
+    full, full_router = build(events)
+    full.run()
+    j1, r1 = build(events[:21])
+    j1.run()
+    snap = j1.snapshot()
+    assert r1.state_dict() == {"rr": {"S": 21 % 4}}
+    j2, r2 = build(events)
+    j2.restore(snap)
+    assert r2.state_dict() == r1.state_dict()
+    j2.run()
+    assert list(r1.routed + r2.routed) == list(full_router.routed)
+    assert r2.state_dict() == full_router.state_dict()
+    assert sorted(
+        j1.results_with_ts("out") + j2.results_with_ts("out")
+    ) == sorted(full.results_with_ts("out"))
+
+
 def test_sharded_checkpoint_with_drains_pending_is_a_barrier():
     """A snapshot taken while sharded drains are pending (swapped out,
     queued behind the fetch thread, not yet emitted) completes them

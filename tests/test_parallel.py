@@ -208,6 +208,54 @@ def test_router_shuffle_balance_and_broadcast_pin():
     assert all(p is None for p in pieces[1:])
 
 
+@pytest.mark.parametrize("n_shards", [1, 3, 4, 8])
+@pytest.mark.parametrize(
+    "kind", ["groupby", "shuffle", "broadcast", "replicate", "segment"]
+)
+def test_select_names_the_rows_route_all_copies(kind, n_shards):
+    """``Router.select`` is ``route_all`` without the copies: for every
+    shard the same rows in the order ``build_tape`` merges the shard's
+    pieces in (by timestamp, ties by arrival), the same counts and the
+    same cursors, over three cycles of two streams whose stamps
+    interleave and tie."""
+    import numpy as np
+
+    keys = ("id",) if kind == "groupby" else ()
+    parts = {
+        "a": StreamPartition(kind, keys), "b": StreamPartition(kind, keys)
+    }
+    one, twin = Router(n_shards, parts), Router(n_shards, parts)
+    a = _batch(make_events(90, id_mod=11, step=100))
+    b = _batch(make_events(75, start_ts=1050, id_mod=5, step=150))
+    a.stream_id, b.stream_id = "a", "b"
+    for lo in (0, 30, 60):
+        cycle = [a.slice(lo, lo + 30), b.slice(lo // 2, lo // 2 + 25)]
+        rows = one.select(cycle)
+        shards = twin.route_all(cycle)
+        ts = np.concatenate([p.timestamps for p in cycle])
+        ids = np.concatenate([p.columns["id"] for p in cycle])
+        src = np.repeat([0, 1], [len(p) for p in cycle])
+        assert rows.offsets[0] == 0 and len(rows.offsets) == n_shards + 1
+        for s, pieces in enumerate(shards):
+            got = rows.order[rows.offsets[s]:rows.offsets[s + 1]]
+            if not pieces:
+                assert len(got) == 0
+                continue
+            p_ts = np.concatenate([p.timestamps for p in pieces])
+            merge = np.argsort(p_ts, kind="stable")
+            want = [
+                np.concatenate([p.columns["id"] for p in pieces])[merge],
+                p_ts[merge],
+                np.concatenate([
+                    np.full(len(p), p.stream_id == "b") for p in pieces
+                ])[merge],
+            ]
+            for exp, col in zip(want, (ids, ts, src)):
+                np.testing.assert_array_equal(col[got], exp)
+        assert list(one.routed) == list(twin.routed)
+        assert one.state_dict() == twin.state_dict()
+
+
 @pytest.mark.slow  # full-mesh-8 shard_map: minutes of XLA CPU compile on the 2-core tier-1 lane (mesh-4 sharded coverage stays tier-1)
 def test_sharded_stacked_chain_group():
     """A plan whose chain queries auto-stack must run under ShardedJob
@@ -397,39 +445,14 @@ def test_segment_plus_nonsegmentable_pattern_compiles():
 
 
 # -------------------------------------------------------------------------
-# tape staging: host stack, one sharded put (mesh-4, tier-1)
+# tape staging: one build a cycle, one sharded put (mesh-4, tier-1)
 # -------------------------------------------------------------------------
 
 _GROUPBY_CQL = (
     "from S select id, sum(price) as total, count() as cnt "
     "group by id insert into out"
 )
-_STAGING_CASES = {
-    # name: (cql, events, partition kind the planner must have chosen)
-    "groupby": (_GROUPBY_CQL, make_events(300, id_mod=13), "groupby"),
-    "shuffle": (
-        "from S[id == 2] select id, name, price insert into out",
-        make_events(300),
-        "shuffle",
-    ),
-    # a quantified chain does not split by time: owner-pinned
-    "broadcast": (
-        "from every a1 = S[id == 1]<2:3> -> a2 = S[id == 2] "
-        "select a1[0].price as p1, a2.price as p2 insert into out",
-        make_events(300),
-        "broadcast",
-    ),
-    "segment": (
-        "from every s1 = S[id == 2] -> s2 = S[id == 3] "
-        "select s1.price as p1, s2.price as p2 insert into out",
-        make_events(300),
-        "segment",
-    ),
-    # one key: three of the four shards receive no event in any cycle
-    "groupby_empty_shard": (
-        _GROUPBY_CQL, make_events(300, id_mod=1), "groupby",
-    ),
-}
+_EPOCH = 1_700_000_000_000  # epoch ms, for plans whose windows read a long
 
 
 def _mesh4_job(cql, events, batch_size=64):
@@ -444,42 +467,205 @@ def _mesh4_job(cql, events, batch_size=64):
     )
 
 
+def _events_case(cql, events, kind):
+    """A case that steps: 300 events of ``S`` in batches of 64."""
+    return lambda: (_mesh4_job(cql, events), {"S": kind}, True)
+
+
+def _cycles_job(cql, fields, cycles):
+    """A mesh-4 job over prebuilt batches, stream by stream and cycle
+    by cycle: ``cycles[i][sid]`` is ``(columns, timestamps)`` of what
+    ``sid`` brings to cycle ``i`` (processing time releases what it
+    pulled), the columns in the host's widths (a ``long`` int64, a
+    ``double`` float64). Only the staging runs: the step is a stub, so a plan a
+    mesh cannot step (a hop window) stages all the same."""
+    import numpy as np
+
+    from flink_siddhi_tpu.runtime.sources import BatchSource
+    from flink_siddhi_tpu.schema.stream_schema import StreamSchema
+
+    schemas = {sid: StreamSchema(f) for sid, f in fields.items()}
+    plan = compile_plan(cql, schemas)
+
+    def batches(sid):
+        for cyc in cycles:
+            cols, ts = cyc.get(sid) or (
+                {n: np.zeros(0, np.int64)
+                 for n in schemas[sid].field_names}, []
+            )
+            yield EventBatch(sid, schemas[sid], dict(cols), ts)
+
+    job = ShardedJob(
+        [plan],
+        [BatchSource(sid, schemas[sid], batches(sid)) for sid in fields],
+        mesh=make_cep_mesh(4), batch_size=512, time_mode="processing",
+    )
+    (rt,) = job._plans.values()
+    rt.jitted_acc = lambda states, acc, tape: (states, acc)
+    return job
+
+
+_LONG2 = [("id", "long"), ("t", "long")]
+_IPT = [("id", "int"), ("price", "double"), ("timestamp", "long")]
+
+
+def _replicate_case():
+    import numpy as np
+
+    def side(i, off, n=40):
+        ts = 1000 + off + 100 * (np.arange(n) + i * n)
+        return ({"id": np.arange(n) % 7, "price": ts / 8.0,
+                 "timestamp": ts}, ts)
+
+    cql = (
+        "from L#window.time(300 millisec) as a "
+        "join R#window.time(300 millisec) as b on a.price < b.price "
+        "select a.id, b.id as rid insert into out"
+    )
+    cycles = [{"L": side(i, 0), "R": side(i, 50)} for i in range(5)]
+    return (_cycles_job(cql, {"L": _IPT, "R": _IPT}, cycles),
+            {"L": "shuffle", "R": "replicate"}, False)
+
+
+def _interleaved_case():
+    """Two keyed streams whose stamps alternate inside every cycle (the
+    merge is no concatenation), each under its own time attribute, one
+    slot table fed by both with slots that expire."""
+    import numpy as np
+
+    def side(i, off, n=48):
+        ts = _EPOCH + off + 700 * (np.arange(n) + i * n)
+        return ({"id": (np.arange(n) * 5 + i) % 11, "t": ts}, ts)
+
+    cql = (
+        "from People#window.hop(t, 10 sec, 10 sec) as p join "
+        "Sales[id > 0]#window.hop(t, 10 sec, 10 sec) as a "
+        "on a.id == p.id select a.id as who, count() as n "
+        "group by p.id insert into out"
+    )
+    cycles = [{"People": side(i, 0), "Sales": side(i, 350)}
+              for i in range(5)]
+    return (_cycles_job(cql, {"People": _LONG2, "Sales": _LONG2}, cycles),
+            {"People": "groupby", "Sales": "groupby"}, False)
+
+
+def _time_bool_double_case():
+    """A ``long`` read as time (rebased, its slots expiring tick by
+    tick), a ``bool`` and a ``double`` device column."""
+    import numpy as np
+
+    def cyc(i, n=90):
+        k = np.arange(n) + i * n
+        ts = _EPOCH + 130 * k
+        return {"S": ({"id": k % 17, "ok": k % 3 != 0,
+                       "price": k / 4.0, "timestamp": ts}, ts)}
+
+    cql = (
+        "from S[ok == true and price > 1.5]"
+        "#window.hop(timestamp, 4 sec, 2 sec) "
+        "select id, count() as n group by id insert into out"
+    )
+    fields = {"S": [("id", "int"), ("ok", "bool"), ("price", "double"),
+                    ("timestamp", "long")]}
+    return (_cycles_job(cql, fields, [cyc(i) for i in range(6)]),
+            {"S": "groupby"}, False)
+
+
+def _random_cycles_case(n_cycles=500, seed=41):
+    """Seeded cycles of one to three streams (keyed, round-robin and
+    owner-pinned in one plan), 0 to 300 events each, stamps drawn so
+    that the streams interleave and tie."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    cql = (
+        "from A select id, sum(price) as total group by id insert into oa; "
+        "from B[id == 2] select id, price insert into ob; "
+        "from every c1 = C[id == 1]<2:3> -> c2 = C[id == 2] "
+        "select c1[0].price as p1, c2.price as p2 insert into oc"
+    )
+    cycles, t0 = [], 1000
+    for _ in range(n_cycles):
+        cyc = {}
+        for sid in rng.permutation(["A", "B", "C"])[: rng.integers(1, 4)]:
+            n = int(rng.integers(0, 301))
+            ts = t0 + np.sort(rng.integers(0, 400, n))
+            cyc[sid] = ({"id": rng.integers(0, 40, n),
+                         "price": rng.random(n) * 100,
+                         "timestamp": ts}, ts)
+        cycles.append(cyc)
+        t0 += 400
+    return (_cycles_job(cql, {"A": _IPT, "B": _IPT, "C": _IPT}, cycles),
+            {"A": "groupby", "B": "shuffle", "C": "broadcast"}, False)
+
+
+_STAGING_CASES = {
+    # name: () -> (job, partition kinds the planner must have chosen,
+    # whether the step runs)
+    "groupby": _events_case(
+        _GROUPBY_CQL, make_events(300, id_mod=13), "groupby"
+    ),
+    "shuffle": _events_case(
+        "from S[id == 2] select id, name, price insert into out",
+        make_events(300), "shuffle",
+    ),
+    # a quantified chain does not split by time: owner-pinned
+    "broadcast": _events_case(
+        "from every a1 = S[id == 1]<2:3> -> a2 = S[id == 2] "
+        "select a1[0].price as p1, a2.price as p2 insert into out",
+        make_events(300), "broadcast",
+    ),
+    "segment": _events_case(
+        "from every s1 = S[id == 2] -> s2 = S[id == 3] "
+        "select s1.price as p1, s2.price as p2 insert into out",
+        make_events(300), "segment",
+    ),
+    # one key: one shard gets every event of every cycle, three none
+    "groupby_empty_shard": _events_case(
+        _GROUPBY_CQL, make_events(300, id_mod=1), "groupby"
+    ),
+    "replicate": _replicate_case,
+    "two_streams_interleaved": _interleaved_case,
+    "time_bool_double": _time_bool_double_case,
+    "random_500_cycles": _random_cycles_case,
+}
+
+
 @pytest.mark.parametrize("case", sorted(_STAGING_CASES))
 def test_staged_tape_is_the_four_tapes_one_row_per_device(case):
-    """What the step is called on: the per-shard ``build_tape`` results,
-    leaf for leaf and row for row, already laid one row per device."""
+    """What the step is called on: row ``s`` of every staged leaf is
+    ``build_tape`` of what ``Router.route_all`` hands shard ``s``, bit
+    for bit and already on shard ``s``'s device. The oracle is a twin:
+    a router in the same cursor state and a copy of the spec with its
+    own encoders, fed the same cycles shard after shard, so the group
+    codes are held to the order the per-shard builds interned in."""
+    import copy
+
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from flink_siddhi_tpu.parallel.mesh import SHARD_AXIS
     from flink_siddhi_tpu.runtime.tape import build_tape
 
-    cql, events, kind = _STAGING_CASES[case]
-    job = _mesh4_job(cql, events)
+    job, kinds, steps = _STAGING_CASES[case]()
     (rt,) = job._plans.values()
-    assert job._routers[rt.plan.plan_id].partition_of("S").kind == kind
-    staged = []
-    stage = job._stage_tapes
-
-    def spy(rt_, shards):
-        out = stage(rt_, shards)
-        staged.append((shards, out))
-        return out
-
-    job._stage_tapes = spy
-    job.run()
-    assert len(staged) >= 4  # 300 events in batches of 64
-    if case == "groupby_empty_shard":
-        assert all(
-            sorted(map(len, shards))[:3] == [0, 0, 0]
-            for shards, _ in staged
-        )
+    router = job._routers[rt.plan.plan_id]
+    assert {
+        sid: router.partition_of(sid).kind for sid in kinds
+    } == kinds
+    twin_spec = copy.deepcopy(rt.plan.spec)
+    twin = Router(4, router.partitions)
     want = NamedSharding(job.mesh, P(SHARD_AXIS))
     devices = list(job.mesh.devices.flat)
-    for shards, tape in staged:
+    stage, cycles = job._stage_tapes, []
+
+    def staged_and_checked(rt_, involved, rows):
+        tape = stage(rt_, involved, rows)
+        shards = twin.route_all(involved)
+        cycles.append((involved, [sum(map(len, sh)) for sh in shards]))
         cap = tape.capacity
         refs = [
-            build_tape(rt.plan.spec, sh, job._epoch_ms, cap)[0]
+            build_tape(twin_spec, sh, job._epoch_ms, cap)[0]
             for sh in shards
         ]
         assert tape.time_off == refs[0].time_off
@@ -498,6 +684,56 @@ def test_staged_tape_is_the_four_tapes_one_row_per_device(case):
                 }[s]
                 assert row.device == devices[s]
                 np.testing.assert_array_equal(np.asarray(row.data)[0], exp)
+        assert list(router.routed) == list(twin.routed)
+        assert router.state_dict() == twin.state_dict()
+        return tape
+
+    job._stage_tapes = staged_and_checked
+    job.run()
+    if steps:
+        assert len(cycles) >= 4  # 300 events in batches of 64
+    counts = [c for _, c in cycles]
+    if case == "groupby_empty_shard":
+        assert all(sorted(c)[:3] == [0, 0, 0] for c in counts)
+    if case == "replicate":
+        assert all(min(c) >= 40 for c in counts)  # R whole, everywhere
+    if case == "two_streams_interleaved":
+        # no concatenation of a cycle's batches is in time order
+        for involved, _ in cycles:
+            ts = np.concatenate([b.timestamps for b in involved])
+            assert len(involved) == 2 and (np.diff(ts) < 0).any()
+    if case == "time_bool_double":
+        spec = rt.plan.spec
+        assert spec.time_columns == ("S.timestamp",)
+        assert {
+            k: np.dtype(spec.column_types[k].device_dtype)
+            for k in spec.built_columns()
+        } == {"S.id": np.int32, "S.ok": np.bool_, "S.price": np.float32}
+        # slots expired on the way: the encoder's ticks are each tape's
+        assert spec.encoded[0].encoder.stats["expired"] > 0
+    if case == "random_500_cycles":
+        sizes = {len(inv) for inv, _ in cycles}
+        assert len(cycles) > 400 and sizes == {1, 2, 3}
+        assert any(0 in c for c in counts)
+
+
+def test_a_cycle_is_built_once_inside_tape_build():
+    """``shard.tape_builds`` counts the builds of a tape's columns: one
+    a cycle (``n_shards`` a cycle would mean per-shard builds were
+    back), under the nested span ``shard_build``, which with
+    ``shard_put`` makes up ``tape_build``."""
+    job = _mesh4_job(_GROUPBY_CQL, make_events(300, id_mod=13))
+    job.run()
+    telemetry = job.metrics()["telemetry"]
+    counters, stages = telemetry["counters"], telemetry["stages"]
+    cycles = counters["shard.cycles"]
+    assert counters["shard.tape_builds"] == cycles > 0
+    assert stages["nested.shard_build"]["count"] == cycles
+    assert stages["tape_build"]["count"] == cycles
+    assert "shard_build" not in stages  # never a top-level span
+    inside = (stages["nested.shard_build"]["seconds"]
+              + stages["nested.shard_put"]["seconds"])
+    assert inside <= stages["tape_build"]["seconds"]
 
 
 def test_sharded_run_under_transfer_guard_puts_once_a_cycle(monkeypatch):
