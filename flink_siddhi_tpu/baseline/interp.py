@@ -331,6 +331,95 @@ class _LengthWindowGroupBy:
         emit(self.out, ts, tuple(row))
 
 
+def _window_aggregate(kind: str, vals: list):
+    """One aggregate over a window's members, oldest first."""
+    if kind == "count":
+        return len(vals)
+    if kind == "sum":
+        return sum(vals)
+    if kind in ("min", "max"):
+        return min(vals) if kind == "min" else max(vals)
+    mean = sum(vals) / len(vals)
+    if kind == "avg":
+        return mean
+    if kind == "stddev":  # of the window's members, not a sample's
+        return (sum((v - mean) ** 2 for v in vals) / len(vals)) ** 0.5
+    raise SiddhiQLError(f"baseline interpreter: unsupported {kind}()")
+
+
+class _PerKeyLengthWindow:
+    """``partition with (k of S) begin from S[f]#window.length(C) select
+    ... [having ...] end``: per key a deque of that key's last C rows
+    (siddhi-core runs one LengthWindowProcessor per partition
+    instance), every aggregate recomputed from the deque on each
+    arrival, one row per event that passes ``having``. ``@purge`` is
+    applied per event on the stream's clock (the timestamps): a key
+    whose last event lies ``idle.period + interval`` or more behind is
+    forgotten before its event is taken. Siddhi's purge, and the
+    engine's, may forget a key from ``idle.period`` on: inside that
+    band the answer is not specified (docs/partition_window.md), and
+    this takes its upper end."""
+
+    def __init__(self, q: ast.Query, capacity: int):
+        inp = q.input
+        self.filters = [_compile_scalar(f) for f in inp.filters]
+        self.cap = capacity
+        self.key = dict(q.partition_with)[inp.stream_id]
+        purge = q.partition_purge
+        self.forget_after = sum(purge) if purge else None
+        self.aggs = []  # (slot, kind, argument fn or None)
+        self.items = [
+            (it.output_name(), _compile_scalar(self._lift(it.expr)))
+            for it in q.selector.items
+        ]
+        self.having = (
+            _compile_scalar(self._lift(q.selector.having))
+            if q.selector.having is not None else None
+        )
+        self.out = q.output_stream
+        self.rows: Dict[Any, deque] = {}
+        self.last: Dict[Any, int] = {}
+
+    def _lift(self, e):
+        """Aggregate calls -> slots of the event's env."""
+        if ast.is_aggregate_call(e):
+            slot = f"@agg{len(self.aggs)}"
+            self.aggs.append((
+                slot, e.name.lower(),
+                _compile_scalar(e.args[0]) if e.args else None,
+            ))
+            return ast.Attr(slot)
+        if isinstance(e, ast.Unary):
+            return ast.Unary(e.op, self._lift(e.operand))
+        if isinstance(e, ast.Binary):
+            return ast.Binary(e.op, self._lift(e.left), self._lift(e.right))
+        return e
+
+    def on_event(self, ev, ts, emit):
+        for f in self.filters:
+            if not f(ev):
+                return
+        key = ev[self.key]
+        if (
+            self.forget_after is not None and key in self.last
+            and ts - self.last[key] >= self.forget_after
+        ):
+            del self.rows[key]
+        self.last[key] = ts
+        rows = self.rows.setdefault(key, deque(maxlen=self.cap))
+        rows.append(ev)
+        env = dict(ev)
+        for slot, kind, fn in self.aggs:
+            env[slot] = _window_aggregate(
+                kind, [fn(r) if fn is not None else 1 for r in rows])
+        row = []
+        for alias, fn in self.items:
+            env[alias] = fn(env)
+            row.append(env[alias])
+        if self.having is None or self.having(env):
+            emit(self.out, ts, tuple(row))
+
+
 class _HopWindowGroupBy:
     """``#window.hop(ts, size, slide) select k, count() ... group by k
     [having ... windowMax(x) ...]``: a dict of per-group counts per pane
@@ -586,7 +675,8 @@ class _SessionWindow:
 class BaselineEngine:
     """Per-event interpreter for the benchmark CQL surface: stateless
     filters, every-chains with within, strict sequences (quantifiers +
-    absence), sliding length-window group-by aggregation, the hop
+    absence), sliding length-window group-by aggregation, the per-key
+    length window of a partition (with ``@purge``), the hop
     window with its per-window maximum, the session window (both
     spellings and ``partition with``; ``flush()`` closes what is open)
     and the tumbling-window join
@@ -622,7 +712,8 @@ class BaselineEngine:
                     cap = win.args[0]
                     assert isinstance(cap, ast.Literal)
                     self.handlers.append(
-                        _LengthWindowGroupBy(q, int(cap.value))
+                        (_PerKeyLengthWindow if q.partition_with
+                         else _LengthWindowGroupBy)(q, int(cap.value))
                     )
                 else:
                     self.handlers.append(_Select(q))

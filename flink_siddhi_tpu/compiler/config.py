@@ -28,11 +28,14 @@ class EngineConfig:
     time_window_capacity: int = 512
     # max distinct timeBatch windows touched per micro-batch
     time_batch_slots: int = 64
-    # #window.hop, the window join and #window.session: the group slots
-    # their state starts with. Slots expire and are reused, so the table
-    # needs the keys a window (a session's gap) holds, not the keys ever
-    # seen: set it from the window and the rate at which keys open, and
-    # the step never re-buckets (a recompile) inside a steady stream
+    # #window.hop, the window join, #window.session and the per-key
+    # length window of a partition ('partition with' + #window.length):
+    # the group slots their state starts with. Slots expire and are
+    # reused (the per-key window's under @purge), so the table needs the
+    # keys a window (a session's gap, a partition's idle.period) holds,
+    # not the keys ever seen: set it from the window and the rate at
+    # which keys open, and the step never re-buckets (a recompile)
+    # inside a steady stream
     hop_group_slots: int = 64
     # join ring slots per side (time/unbounded windows)
     join_window_capacity: int = 128

@@ -978,6 +978,22 @@ def _rewrite_partitioned(q: ast.Query, schemas) -> ast.Query:
         return q
     keymap = dict(q.partition_with)
     inp = q.input
+    # refuses what it would ignore (the parser has refused any other
+    # annotation): @purge where the partition's state is not the
+    # per-key length window's
+    if q.partition_purge is not None and not (
+        isinstance(inp, ast.StreamInput)
+        and [w.name.split(".")[-1].lower() for w in inp.windows]
+        == ["length"]
+        and q.output_events == "current"
+        and any(ast.contains_aggregate(i.expr) for i in q.selector.items)
+    ):
+        raise SiddhiQLError(
+            "@purge on a partition is honoured for a per-key length "
+            "window with aggregates ('partition with (k of S) begin from "
+            "S#window.length(n) select ... end': docs/partition_window.md)"
+            "; this partition's state does not expire"
+        )
     if isinstance(inp, ast.StreamInput):
         if inp.stream_id not in keymap:
             raise SiddhiQLError(

@@ -1887,16 +1887,43 @@ def compile_window_query(
                 "query is not supported yet (the partition key is the "
                 "grouping)"
             )
+        cap = int(window[1])
+        if cap < 1:
+            raise SiddhiQLError("#window.length needs a positive length")
+        for a in collector.aggs:
+            if a.kind not in ("count", "sum", "avg", "stddev", "min", "max"):
+                raise SiddhiQLError(
+                    f"{a.kind}() is not supported over a per-partition "
+                    "#window.length (count, sum, avg, stddev, min and max "
+                    "are)"
+                )
+        if cap > PERKEY_RING_MAX and any(
+            a.kind in ("min", "max") for a in collector.aggs
+        ):
+            raise SiddhiQLError(
+                "min() / max() over a per-partition #window.length read "
+                f"the key's last values one by one: at most "
+                f"{PERKEY_RING_MAX} of them (group by outside the "
+                "partition for a longer window)"
+            )
+        # @purge: the slots expire on the stream's clock (the events'
+        # timestamps) and a reused one starts anew on the device
+        purge = q.partition_purge
+        tick_ms, retain = purge_ticks(*purge) if purge else (0, None)
         code_key, encoder, encoded = _group_encoding(
             name, group_resolved, sc, filter_fns,
-            host_filters=host_filters,
+            encoder=GroupEncoder(
+                retain_ticks=retain, mark_new=purge is not None),
+            host_filters=host_filters, tick_ms=tick_ms,
         )
         art = PerKeyWindowArtifact(
             name=name,
             output_schema=out_schema,
             stream_code=sc,
             filter_fns=filter_fns,
-            capacity=int(window[1]),
+            capacity=cap,
+            group_slots=max(
+                MIN_GROUP_CAPACITY, int(config.hop_group_slots)),
             aggs=collector.aggs,
             arg_fns=collector.arg_fns,
             arg_types=collector.arg_types,
@@ -2153,6 +2180,7 @@ def _group_encoding(
     filter_fns: Sequence[Callable] = (),
     encoder: Optional[GroupEncoder] = None,
     host_filters: Optional[Sequence[Callable]] = None,
+    tick_ms: int = 0,
 ):
     """Dense group codes for state-table artifacts. Single-column int-like
     keys could index directly, but interning keeps tables dense for arbitrary
@@ -2160,7 +2188,8 @@ def _group_encoding(
     filters so rejected events never grow the table: ``host_filters``
     (``host_filter_fns``) where the filters compile to numpy, else the
     device's own ``filter_fns``, which cost the host a round trip to the
-    device for every batch."""
+    device for every batch. ``tick_ms``: the tick of an ``encoder`` whose
+    slots expire, on the clock of the events' timestamps."""
     if not group_resolved:
         return None, None, ()
     if encoder is None:
@@ -2175,6 +2204,9 @@ def _group_encoding(
         stream_code=stream_code,
         encoder=encoder,
         select_fn=select_fn,
+        # an encoder whose slots expire reads the events' timestamps
+        tick_key="@ts" if tick_ms else None,
+        tick_ms=tick_ms,
     )
     return out_key, encoder, (enc,)
 
@@ -2445,19 +2477,52 @@ def compile_expired_window(
 # Per-key sliding windows: `partition with (k of S) begin ...#window.length`
 # --------------------------------------------------------------------------
 
+# the widest per-key window whose min / max the step reads: it gathers
+# C - 1 ring values an event, one gather each
+PERKEY_RING_MAX = 64
+
+
+def purge_ticks(interval_ms: int, idle_ms: int) -> Tuple[int, int]:
+    """(tick_ms, retain_ticks) of a purged partition's slots: a tick is
+    the purge interval, and a slot is freed once ``idle.period`` and a
+    tick have passed the batch that last touched it, as the batches
+    before the one being staged saw the clock. So a key idle for less
+    than ``idle.period`` is never forgotten, and one is always forgotten
+    once the batch before its return ends ``idle.period + interval``
+    after the batch of its last event (docs/partition_window.md has the
+    band in between)."""
+    return interval_ms, -(-idle_ms // interval_ms) + 1
+
+
 @dataclass
 class PerKeyWindowArtifact:
     """``partition with (k of S) ... #window.length(C)``: EVERY key has
     its own window of its own last C matching events (Siddhi partition
     semantics — NOT a group-by over one shared window; the round-3
     verdict's canonical partition carve-out).
+    ``docs/partition_window.md`` has the query form and the state.
 
-    TPU shape: per-key windows are per-group LOCAL prefix differences —
-    windowed_g(n) = S_g(n) - S_g(n - C) where S_g is the key's running
-    (Neumaier-compensated) sum and n its local arrival ordinal. State is
-    a [G] running-total table plus a [G, C] ring of the last C prefix
-    CHECKPOINTS per key; a batch needs one group-sort, segmented scans,
-    and two gathers — no per-event work, no window matrix."""
+    TPU shape: one stable sort of the batch by slot code (the arguments
+    ride along), segmented scans for each event's ordinal ``n`` in its
+    key's stream, and per slot of a host-interned table
+    (``schema/encoders.py``):
+
+    * ``cnt``: the key's arrivals so far; ``count()`` is
+      ``min(n + 1, C)``;
+    * sums (``sum`` / ``avg`` / ``stddev``): per-group LOCAL prefix
+      differences, windowed_g(n) = S_g(n) - S_g(n - C), where S_g is
+      the key's running (Neumaier-compensated) float32 sum: a [G]
+      running total and a [G, C] ring of the last C prefix CHECKPOINTS;
+    * ``min`` / ``max``: a ring of the key's last C RAW values in the
+      argument's own type (an int stays an int: no float32 round trip),
+      ``[C * G]`` flat, ring position major. An event reads the C - 1
+      values before it: those of its own batch from the sorted column
+      shifted (no gather), the older ones from the ring.
+
+    Under ``@purge`` the slots expire (``purge_ticks``) and the encoder
+    marks the rows of a key it gave a slot (``mark_new``: ``~slot``):
+    the step counts such a key from zero, so nothing the slot's last
+    key left is read (every ring read is gated by the count)."""
 
     name: str
     output_schema: OutputSchema
@@ -2471,18 +2536,21 @@ class PerKeyWindowArtifact:
     encoder: GroupEncoder
     proj_fns: List
     having_fn: Optional[Callable]
+    # the table's first size (EngineConfig.hop_group_slots)
+    group_slots: int = MIN_GROUP_CAPACITY
     output_mode: str = "aligned"
 
     def _stats(self) -> Dict[int, set]:
         return _acc_stats_for(self.aggs)
 
     def _G(self) -> int:
-        return _bucket(len(self.encoder), MIN_GROUP_CAPACITY)
+        return _bucket(len(self.encoder), self.group_slots)
 
     def cost_info(self) -> Dict:
         """Admission-cost descriptor: per-key count-evicted windows —
-        one row per event; state grows with key cardinality (bucketed
-        [G, C] re-buckets as keys intern)."""
+        one row per event; state grows with key cardinality (under
+        ``@purge``: with the keys ``idle.period`` holds; the table
+        re-buckets where they outgrow ``hop_group_slots``)."""
         return {
             "name": self.name,
             "kind": "perkey_window",
@@ -2491,6 +2559,21 @@ class PerKeyWindowArtifact:
             "grows_with": "keys",
         }
 
+    def safe_cycles(self, tape_capacity: int, state: Dict, cap: int) -> int:
+        """Cycles the accumulator of ``cap`` rows holds without a swap.
+        An aligned block is as wide as the tape whatever ``having``
+        keeps, and ``k`` cycles leave at most ``k`` tapes of rows, so
+        the next block finds room while ``k * tape_capacity <= cap``:
+        the worst case itself, so it takes the whole accumulator;
+        rounded down to a power of two, a swap falls on a segment's
+        end."""
+        k = cap // max(tape_capacity, 1)
+        return 1 << (max(k, 1).bit_length() - 1)
+
+    def drain_counters(self, payload) -> Dict[str, int]:
+        """What a drain delivered: the rows past ``having``."""
+        return {"perkey.rows": len(payload)}
+
     def init_state(self) -> Dict:
         G, C = self._G(), self.capacity
         st = {
@@ -2498,22 +2581,20 @@ class PerKeyWindowArtifact:
             "cnt": jnp.zeros(G, jnp.int32),  # arrivals ever, per key
         }
         for arg_idx, stats in self._stats().items():
-            for s in stats:
-                if s not in ("sum", "sumsq"):
-                    raise SiddhiQLError(
-                        "per-partition windows support sum/count/avg/"
-                        "stddev aggregates (min/max need the window "
-                        "matrix; group by outside the partition instead)"
-                    )
+            for s in sorted(stats & {"sum", "sumsq"}):
                 st[f"S_{s}{arg_idx}"] = jnp.zeros(G, jnp.float32)
                 st[f"kc_{s}{arg_idx}"] = jnp.zeros(G, jnp.float32)
                 st[f"ring_{s}{arg_idx}"] = jnp.zeros(
                     (G, C), jnp.float32
                 )
+            if stats & {"min", "max"}:
+                st[f"vals{arg_idx}"] = jnp.zeros(
+                    C * G, self.arg_types[arg_idx].device_dtype
+                )
         return st
 
     def grow_state(self, state: Dict) -> Dict:
-        G = state["cnt"].shape[0]
+        G, C = state["cnt"].shape[0], self.capacity
         need = self._G()
         if need <= G:
             return state
@@ -2521,13 +2602,18 @@ class PerKeyWindowArtifact:
         for k, v in state.items():
             if k == "enabled":
                 continue
+            if k.startswith("vals"):  # [C * G], ring position major
+                v = v.reshape(C, G)
+                pad = jnp.zeros((C, need - G), v.dtype)
+                out[k] = jnp.concatenate([v, pad], axis=1).reshape(-1)
+                continue
             pad_shape = (need - G,) + v.shape[1:]
             out[k] = jnp.concatenate(
                 [v, jnp.zeros(pad_shape, v.dtype)]
             )
         return out
 
-    @jax.named_scope("fst.window_fold")
+    @jax.named_scope("fst.perkey_fold")
     # fst:hotpath device=state,tape
     def step(self, state: Dict, tape) -> Tuple[Dict, Tuple]:
         env: ColumnEnv = dict(tape.cols)
@@ -2538,50 +2624,74 @@ class PerKeyWindowArtifact:
         E = tape.capacity
         C = self.capacity
         G = state["cnt"].shape[0]
+        stats = self._stats()
 
-        g = env[self.code_key].astype(jnp.int32)
-        segkey = jnp.where(mask, g, G)
-        order = jnp.argsort(segkey)  # stable: groups contiguous
-        inv = jnp.argsort(order)
-        g_s = segkey[order]
+        # a negative code is ``~slot``: a key this batch was given the
+        # slot for, whose state starts anew (``mark_new``)
+        code = env[self.code_key].astype(jnp.int32)
+        segkey = jnp.where(mask, jnp.where(code < 0, ~code, code), G)
+        # one stable sort makes a key's events contiguous and in order;
+        # the tape position, the code and the arguments ride along
+        arg_ids = sorted(stats)
+        g_s, order, code_s, *arg_s = lax.sort(
+            [segkey, jnp.arange(E, dtype=jnp.int32), code] + [
+                jnp.broadcast_to(
+                    jnp.asarray(self.arg_fns[j](env)), (E,)
+                ).astype(self.arg_types[j].device_dtype)
+                for j in arg_ids
+            ],
+            num_keys=1, is_stable=True,
+        )
+        arg_s = dict(zip(arg_ids, arg_s))
+        mask_s = g_s < G
+        fresh_s = mask_s & (code_s < 0)
         flags = jnp.concatenate(
             [jnp.ones(1, bool), g_s[1:] != g_s[:-1]]
         )
-        gather_g = jnp.clip(g_s, 0, G - 1)
-        mask_s = mask[order]
+        ends = jnp.concatenate([flags[1:], jnp.ones(1, bool)])
+        gather_g = jnp.minimum(g_s, G - 1)
 
         ones = jnp.ones(E, jnp.int32)
         seg_rank = _seg_scan(flags, ones, jnp.add) - 1  # 0-based local
-        local_n = state["cnt"][gather_g] + seg_rank  # per-key ordinal
+        # the key's events still to come in this batch: its last
+        # min(C, all) are the ones the rings keep
+        to_come = (_seg_scan(ends[::-1], ones, jnp.add) - 1)[::-1]
+        is_tail = mask_s & (to_come < C)
+        local_n = (
+            jnp.where(fresh_s, 0, state["cnt"][gather_g]) + seg_rank
+        )  # per-key ordinal
         pos = jnp.arange(E, dtype=jnp.int32)
 
         new_state = dict(state)
-        seg_tot = jax.ops.segment_sum(
-            mask.astype(jnp.int32), segkey, num_segments=G + 1
-        )[:G]
-        new_state["cnt"] = state["cnt"] + seg_tot
+        new_state["cnt"] = state["cnt"].at[
+            jnp.where(mask_s & ends, g_s, G)
+        ].set(local_n + 1, mode="drop")
 
         # windowed count has a closed form: min(local_n + 1, C)
-        stats_env: Dict[str, jnp.ndarray] = {
-            "cnt": jnp.minimum(local_n + 1, C)[inv]
+        stats_s: Dict[str, jnp.ndarray] = {
+            "cnt": jnp.minimum(local_n + 1, C)
         }
+        fresh_slot = None
 
-        for arg_idx, stats in self._stats().items():
-            v = self.arg_fns[arg_idx](env)
-            v = jnp.broadcast_to(jnp.asarray(v), (E,)).astype(
-                jnp.float32
-            )
-            v_s = jnp.where(mask_s, v[order], 0.0)
-            for s in stats:
-                if s == "sumsq":
-                    vals = v_s * v_s
-                else:
-                    vals = v_s
+        for arg_idx in arg_ids:
+            sums = sorted(stats[arg_idx] & {"sum", "sumsq"})
+            if sums and fresh_slot is None:
+                # the slots that start anew: their running totals too
+                fresh_slot = jnp.zeros(G, bool).at[
+                    jnp.where(fresh_s & ends, g_s, G)
+                ].set(True, mode="drop")
+            v_f = jnp.where(
+                mask_s, arg_s[arg_idx].astype(jnp.float32), 0.0
+            ) if sums else None
+            for s in sums:
+                vals = v_f * v_f if s == "sumsq" else v_f
                 Skey, kckey, rkey = (
                     f"S_{s}{arg_idx}", f"kc_{s}{arg_idx}",
                     f"ring_{s}{arg_idx}",
                 )
-                base = state[Skey] + state[kckey]
+                acc = jnp.where(fresh_slot, 0.0, state[Skey])
+                kc = jnp.where(fresh_slot, 0.0, state[kckey])
+                base = acc + kc
                 p_scan, c_scan = _seg_scan_sum_kahan(flags, vals)
                 pref = p_scan + c_scan
                 S_at = base[gather_g] + pref  # S_g(local_n), inclusive
@@ -2597,17 +2707,9 @@ class PerKeyWindowArtifact:
                     prev_batch,
                     jnp.where(local_n >= C, prev_ring, 0.0),
                 )
-                stats_env[f"{s}{arg_idx}"] = (S_at - S_prev)[inv]
+                stats_s[f"{s}{arg_idx}"] = S_at - S_prev
                 # ring update: each key's LAST min(C, seg_len) arrivals
                 # checkpoint S(n) into slot n mod C (distinct slots)
-                seg_len = jax.ops.segment_sum(
-                    mask_s.astype(jnp.int32),
-                    jnp.where(mask_s, gather_g, G),
-                    num_segments=G + 1,
-                )[:G]
-                is_tail = mask_s & (
-                    seg_rank >= seg_len[gather_g] - C
-                )
                 wslot = local_n % C
                 flat = ring.reshape(G * C)
                 widx = jnp.where(
@@ -2616,17 +2718,13 @@ class PerKeyWindowArtifact:
                 flat = flat.at[widx].set(S_at, mode="drop")
                 new_state[rkey] = flat.reshape(G, C)
                 # carry totals forward (two-sum)
-                tot_ends = jnp.concatenate(
-                    [flags[1:], jnp.ones(1, bool)]
-                )
-                gi = jnp.where(tot_ends & (g_s < G), g_s, G)
+                gi = jnp.where(ends & mask_s, g_s, G)
                 tot = jnp.zeros(G + 1, jnp.float32).at[gi].add(
-                    jnp.where(tot_ends, p_scan, 0.0), mode="drop"
+                    jnp.where(ends, p_scan, 0.0), mode="drop"
                 )[:G]
                 tot_c = jnp.zeros(G + 1, jnp.float32).at[gi].add(
-                    jnp.where(tot_ends, c_scan, 0.0), mode="drop"
+                    jnp.where(ends, c_scan, 0.0), mode="drop"
                 )[:G]
-                acc = state[Skey]
                 t = acc + tot
                 err = jnp.where(
                     jnp.abs(acc) >= jnp.abs(tot),
@@ -2634,7 +2732,43 @@ class PerKeyWindowArtifact:
                     (tot - t) + acc,
                 )
                 new_state[Skey] = t
-                new_state[kckey] = state[kckey] + err + tot_c
+                new_state[kckey] = kc + err + tot_c
+            kinds = sorted(stats[arg_idx] & {"min", "max"})
+            if not kinds:
+                continue
+            # the key's last C raw values: the event's own, the k-th
+            # before it from this batch where the key has that many
+            # here (the sorted column shifted by k), else from the ring
+            # at ordinal n - k, which the count says this key wrote
+            rkey = f"vals{arg_idx}"
+            ring, v_s = state[rkey], arg_s[arg_idx]
+            members = [(v_s, mask_s)]
+            for k in range(1, C):
+                here = jnp.concatenate(
+                    [jnp.zeros(k, v_s.dtype), v_s[:E - k]]
+                ) if k < E else jnp.zeros_like(v_s)
+                nth = local_n - k
+                old = ring[(nth % C) * G + gather_g]
+                members.append(
+                    (jnp.where(seg_rank >= k, here, old), nth >= 0)
+                )
+            for kind in kinds:
+                ident = _identity(kind, v_s.dtype)
+                red = jnp.minimum if kind == "min" else jnp.maximum
+                out = jnp.where(members[0][1], members[0][0], ident)
+                for val, ok in members[1:]:
+                    out = red(out, jnp.where(ok, val, ident))
+                stats_s[f"{kind}{arg_idx}"] = out
+            new_state[rkey] = ring.at[
+                jnp.where(is_tail, (local_n % C) * G + g_s, C * G)
+            ].set(v_s, mode="drop")
+
+        # back to tape order: a sort by the tape position
+        names = sorted(stats_s)
+        _order, *back = lax.sort(
+            [order] + [stats_s[n] for n in names], num_keys=1
+        )
+        stats_env = dict(zip(names, back))
 
         for agg in self.aggs:
             env[agg.slot] = _agg_from_stats(agg, stats_env).astype(

@@ -288,6 +288,15 @@ class OutputRate:
 
 
 @dataclass(frozen=True)
+class Annotation:
+    """``@name(key='value', 'value', ...)`` as written: the elements in
+    order, a bare value under the key None. Kept for the statements
+    whose plan reads them (a partition's ``@purge``)."""
+    name: str
+    elements: Tuple[Tuple[Optional[str], str], ...] = ()
+
+
+@dataclass(frozen=True)
 class Query:
     input: InputClause
     selector: Selector
@@ -299,6 +308,11 @@ class Query:
     # `partition with (attr of Stream, ...) begin ... end`: per-key
     # isolated execution — (stream_id -> key attribute) for this query
     partition_with: Tuple[Tuple[str, str], ...] = ()
+    # the annotations in front of that partition as written, ``@info``
+    # apart, and what its ``@purge`` asks for where it is switched on:
+    # (interval, idle.period) in ms (query/parser.py resolves it, once)
+    partition_annotations: Tuple[Annotation, ...] = ()
+    partition_purge: Optional[Tuple[int, int]] = None
     # output event category: 'current' (default) | 'expired' | 'all' —
     # ``insert expired events into O`` emits events as they LEAVE the
     # window, not as they arrive

@@ -69,7 +69,8 @@ def parse_plan(text: str) -> ast.ExecutionPlan:
     while ts.current.kind != "EOF":
         if ts.accept_op(";"):
             continue
-        pending_name = _parse_annotations(ts)
+        annots = _parse_annotations(ts)
+        pending_name = _info_name(annots)
         if ts.at_keyword("define"):
             kind, d = _parse_definition(ts)
             if kind == "stream":
@@ -77,7 +78,10 @@ def parse_plan(text: str) -> ast.ExecutionPlan:
             else:
                 table_defs.append(d)
         elif ts.at_keyword("partition"):
-            queries.extend(_parse_partition(ts, name=pending_name))
+            queries.extend(_parse_partition(
+                ts, pending_name,
+                tuple(a for a in annots if a.name.lower() != "info"),
+            ))
         elif ts.at_keyword("from"):
             queries.append(_parse_query(ts, name=pending_name))
         else:
@@ -102,29 +106,100 @@ def parse_query(text: str) -> ast.Query:
 # statements
 # --------------------------------------------------------------------------
 
-def _parse_annotations(ts: TokenStream) -> Optional[str]:
-    """Consume leading @annotations; return @info(name='...') if present."""
-    name = None
+def _parse_annotations(ts: TokenStream) -> List[ast.Annotation]:
+    """Consume leading @annotations: each with its elements at the top
+    level of its parentheses (``key = value``, a key may be dotted, or
+    a bare value); what a nested annotation holds is skipped."""
+    out: List[ast.Annotation] = []
     while ts.current.kind == "ANNOT":
         annot = ts.advance().text[1:]
+        elements: List[Tuple[Optional[str], str]] = []
         if ts.accept_op("("):
             depth = 1
-            last_key = None
+            key: List[str] = []
             while depth > 0 and ts.current.kind != "EOF":
                 tok = ts.advance()
                 if tok.kind == "OP" and tok.text == "(":
                     depth += 1
                 elif tok.kind == "OP" and tok.text == ")":
                     depth -= 1
-                elif tok.kind == "ID":
-                    last_key = tok.text
-                elif (
-                    tok.kind == "STRING"
-                    and annot.lower() == "info"
-                    and last_key == "name"
+                elif depth > 1:
+                    continue
+                elif tok.kind == "ID" or (
+                    tok.kind == "OP" and tok.text == "." and key
                 ):
-                    name = tok.text[1:-1]
+                    key.append(tok.text)
+                elif tok.kind == "STRING":
+                    elements.append(("".join(key) or None, tok.text[1:-1]))
+                    key = []
+                elif tok.kind in ("INT", "FLOAT"):
+                    elements.append(("".join(key) or None, tok.text))
+                    key = []
+                elif tok.kind == "OP" and tok.text == ",":
+                    key = []
+        out.append(ast.Annotation(annot, tuple(elements)))
+    return out
+
+
+def _info_name(annots: List[ast.Annotation]) -> Optional[str]:
+    """``@info(name='...')``'s name, where one of ``annots`` gives it."""
+    name = None
+    for a in annots:
+        if a.name.lower() == "info":
+            name = dict(a.elements).get("name", name)
     return name
+
+
+def parse_duration(text: str) -> int:
+    """``'30 sec'`` (an annotation's value) -> milliseconds."""
+    ts = TokenStream(tokenize(text))
+    ms = _parse_time_duration(ts)
+    if ts.current.kind != "EOF":
+        ts.error(f"expected a time duration, found {text!r}")
+    return ms
+
+
+def _partition_purge(
+    annotations: Tuple[ast.Annotation, ...],
+) -> Optional[Tuple[int, int]]:
+    """``(interval_ms, idle_ms)`` of the ``@purge(enable='true',
+    interval='..', idle.period='..')`` in front of a partition, None
+    where there is none or it is switched off. Nothing else may stand
+    there: an annotation that would be ignored is refused."""
+    purge = None
+    for a in annotations:
+        if a.name.lower() != "purge":
+            raise SiddhiQLError(
+                f"annotation @{a.name} on a partition is not supported "
+                "(@purge and @info are)"
+            )
+        el = dict(a.elements)
+        unknown = set(el) - {"enable", "interval", "idle.period"}
+        if unknown or len(el) != len(a.elements):
+            raise SiddhiQLError(
+                "@purge takes enable, interval and idle.period, each "
+                f"once; found {[k for k, _v in a.elements]}"
+            )
+        enable = el.get("enable", "true").lower()
+        if enable not in ("true", "false"):
+            raise SiddhiQLError("@purge: enable is 'true' or 'false'")
+        if enable == "false":
+            continue
+        if "interval" not in el or "idle.period" not in el:
+            raise SiddhiQLError(
+                "@purge needs interval and idle.period (e.g. "
+                "@purge(enable='true', interval='30 sec', "
+                "idle.period='90 sec'))"
+            )
+        interval, idle = (
+            parse_duration(el["interval"]), parse_duration(el["idle.period"])
+        )
+        if interval <= 0 or idle <= 0:
+            raise SiddhiQLError(
+                "@purge: interval and idle.period must be positive"
+            )
+        purge = (interval, idle)
+    return purge
 
 
 def _parse_definition(
@@ -167,11 +242,14 @@ def _parse_query(ts: TokenStream, name: Optional[str] = None) -> ast.Query:
 
 
 def _parse_partition(
-    ts: TokenStream, name: Optional[str] = None
+    ts: TokenStream, name: Optional[str] = None,
+    annotations: Tuple[ast.Annotation, ...] = (),
 ) -> List[ast.Query]:
     """``partition with (attr of Stream, ...) begin <query>+ end``:
     per-key isolated execution of the enclosed queries (Siddhi partition
-    semantics). Each enclosed query carries the key map."""
+    semantics). Each enclosed query carries the key map, the
+    partition's ``annotations`` and what its ``@purge`` asks for."""
+    purge = _partition_purge(annotations)
     ts.expect_keyword("partition")
     ts.expect_keyword("with")
     ts.expect_op("(")
@@ -192,13 +270,14 @@ def _parse_partition(
         ts.accept_op(";")
         if ts.at_keyword("end"):
             break
-        inner_name = _parse_annotations(ts) or (
+        inner_name = _info_name(_parse_annotations(ts)) or (
             f"{name}_{len(out)}" if name else None
         )
         q = _parse_query(ts, name=inner_name)
-        out.append(
-            dataclasses.replace(q, partition_with=tuple(keys))
-        )
+        out.append(dataclasses.replace(
+            q, partition_with=tuple(keys),
+            partition_annotations=annotations, partition_purge=purge,
+        ))
         ts.accept_op(";")
     ts.expect_keyword("end")
     if not out:

@@ -173,6 +173,13 @@ class _PlanRuntime:
     # the oldest is waited on once the window is full, so the device
     # stays <= max_inflight_cycles behind without sawtooth stalls
     tickets: deque = field(default_factory=deque)
+    # how deep that window may be (Job._inflight_depth): tickets ever
+    # made, the last one waited on as (ordinal, when it was ready), and
+    # the device's time for one dispatch as two waits in a row measured
+    # it (None until then)
+    dispatched: int = 0
+    waited: Optional[Tuple[int, float]] = None
+    dispatch_s: Optional[float] = None
     # async drain pipeline: swapped-out accumulators whose meta/data
     # fetches are in flight (see Job._drain_request/_drain_poll)
     drain_q: deque = field(default_factory=deque)
@@ -2216,6 +2223,7 @@ class Job:
             rt.dirty_since = None
             rt.seg_pending = []
             rt.tickets.clear()
+            rt.waited = None
             rt.seg_open = []
             if getattr(rt, "lazy", None) is not None:
                 rt.lazy = _LazyRing(rt.lazy.budget)
@@ -3168,7 +3176,14 @@ class Job:
             # staleness budget, dispatch short — visibility latency
             # stays bounded by ~interval + drain time, fused or not.
             # (`is None` check, not `or`: drain_interval_ms=0 means
-            # "tightest visibility", which must not round up to 500ms)
+            # "tightest visibility", which must not round up to 500ms).
+            # Not while the ticket window is full: the run loop would
+            # wait at that dispatch for the device, staging nothing, and
+            # the segment would go out a batch or two short for good (at
+            # 0.24 s a batch four tapes take 0.4-0.5 s to stage: three
+            # tapes and a padding tape cost the device 0.85 s, the
+            # fourth and three paddings 0.64 s more); left open it fills
+            # while the device works
             age_s = (
                 500.0
                 if self.drain_interval_ms is None
@@ -3178,7 +3193,7 @@ class Job:
             for rt in self._plans.values():
                 if rt.seg_pending and (
                     now0 - rt.seg_pending[0]["t"] >= age_s
-                ):
+                ) and not self._window_full(rt):
                     self._dispatch_segment(rt)
         now = time.monotonic()
         if self.drain_interval_ms is not None:
@@ -4050,6 +4065,42 @@ class Job:
         rt.seg_open.append(rec)
         return rec, seg
 
+    # device work that may wait behind the dispatch that is running
+    MAX_QUEUED_S = 1.0
+
+    def _inflight_depth(self, rt: _PlanRuntime) -> int:
+        """Dispatches the ticket window lets wait on the device:
+        ``max_inflight_cycles`` of them, and no more than about
+        ``MAX_QUEUED_S`` seconds of work, one at the least. A deep
+        queue hides the host's jitter behind a fast step (six segments
+        of 60 ms); behind a slow one it hides nothing more and delays
+        every drain by its whole length: a drain's data slice is
+        dispatched when its count prefix is back, so it runs after all
+        that was queued meanwhile, the fetch thread takes one drain at a
+        time, a swap follows every segment whose accumulator holds no
+        more, and at ``MAX_PENDING_DRAINS`` the run loop then waits for
+        a fetch that waits for the queue to run dry, and races ahead
+        again once it has (six segments of 0.97 s: 6 s without a
+        delivery, then 3 s, at start-up and after any hiccup since).
+        The device's time for a dispatch is what two waits in a row
+        measure; until they have, the first dispatch that finds another
+        still running waits for both."""
+        if rt.dispatch_s is None:
+            return 1 if len(rt.tickets) < 2 else 0
+        return max(1, min(
+            self.max_inflight_cycles,
+            int(self.MAX_QUEUED_S / max(rt.dispatch_s, 1e-4)),
+        ))
+
+    def _window_full(self, rt: _PlanRuntime) -> bool:
+        """Whether a dispatch now would make the run loop wait in the
+        ticket window (never, before the device's pace is known)."""
+        if rt.dispatch_s is None:
+            return False
+        while rt.tickets and rt.tickets[0].is_ready():
+            rt.tickets.popleft()
+        return len(rt.tickets) >= self._inflight_depth(rt)
+
     def _ticket_window(
         self, rt: _PlanRuntime, rec: Optional[SegmentRecord], seg_id: int
     ) -> None:
@@ -4073,9 +4124,19 @@ class Job:
         # the depth of the queue the device works through, this segment
         # included: over fusion.dispatches, its mean
         tel.inc("segments.inflight_sum", len(rt.tickets))
-        if len(rt.tickets) > self.max_inflight_cycles:
+        rt.dispatched += 1
+        depth = self._inflight_depth(rt)
+        while len(rt.tickets) > depth:
+            ordinal = rt.dispatched - len(rt.tickets) + 1
             with tel.span("backpressure_wait", seg=seg_id):
                 jax.block_until_ready(rt.tickets.popleft())
+            now = time.monotonic()
+            if rt.waited is not None and rt.waited[0] == ordinal - 1:
+                # the device went from the last ticket waited on
+                # straight to this one: its time for one dispatch
+                rt.dispatch_s = now - rt.waited[1]
+                depth = self._inflight_depth(rt)
+            rt.waited = (ordinal, now)
             while rt.tickets and rt.tickets[0].is_ready():
                 rt.tickets.popleft()
         clock = self._starve_clock()

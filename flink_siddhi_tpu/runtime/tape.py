@@ -544,7 +544,7 @@ def _intern_groups(spec, merged, new, cols, stream, total, known=None,
     interned) are taken as they are: a table whose slots expire hands
     out other slots the second time. ``ts``: the tape's own timestamps,
     a tick column under the name ``@ts`` (a session window that reads
-    the event's timestamp)."""
+    the event's timestamp; a purged partition's per-key window)."""
     if not spec.encoded:
         return
     view = {k: v[:total] for k, v in cols.items()}

@@ -18,6 +18,12 @@ stamped with the tick of the last batch that touched it, and once
 ``retain_ticks`` ticks have passed that stamp the slot is freed and handed
 to the next new key. The table then stays at the size of the keys a window
 holds, however many keys the stream has seen.
+
+A table whose device state outlives a slot's key (the per-key length
+window's count and ring: nothing on the device closes them) is built with
+``mark_new``: every row of a key that the call gave a slot (a fresh or a
+reused one) carries ``~slot``, a negative code, and the step starts that
+slot's state anew before the key's first event.
 """
 
 from __future__ import annotations
@@ -48,8 +54,10 @@ class GroupEncoder:
     Anything else (several columns, object values) goes through a dict,
     one Python step per selected row."""
 
-    def __init__(self, retain_ticks: Optional[int] = None) -> None:
+    def __init__(self, retain_ticks: Optional[int] = None,
+                 mark_new: bool = False) -> None:
         self.retain_ticks = retain_ticks
+        self.mark_new = mark_new
         self._n = 0  # slots ever handed out (the table's high-water mark)
         # array mode: slot -> key, and the live keys sorted
         self._slot_key: Optional[np.ndarray] = None
@@ -87,7 +95,8 @@ class GroupEncoder:
         False get code 0 and are NOT interned (they belong to other streams
         and must not grow the table). ``tick_col`` (the owning window's
         rebased time column) and ``tick_ms`` drive expiry: the batch's tick
-        is that of its last selected row."""
+        is that of its last selected row. Under ``mark_new`` the rows of
+        a key interned by this call get ``~slot``."""
         n = len(select)
         out = np.zeros(n, dtype=np.int32)
         if not n:
@@ -114,22 +123,28 @@ class GroupEncoder:
                 seen = np.zeros(span, dtype=np.bool_)
                 seen[rel] = True
                 present = np.flatnonzero(seen)
-                slots = self._intern_unique(
+                slots, codes = self._intern_unique(
                     (present + lo).astype(sel_vals.dtype)
                 )
                 lut = np.zeros(span, dtype=np.int32)
-                lut[present] = slots
+                lut[present] = codes
                 out[select] = lut[rel]
             else:
                 uniq = np.unique(sel_vals)
-                slots = self._intern_unique(uniq)
-                out[select] = slots[np.searchsorted(uniq, sel_vals)]
+                slots, codes = self._intern_unique(uniq)
+                out[select] = codes[np.searchsorted(uniq, sel_vals)]
         else:
             idx = np.nonzero(select)[0]
             slots = np.empty(len(idx), dtype=np.int32)
+            fresh = []
             for j, i in enumerate(idx):
+                born = self.stats["interned"]
                 slots[j] = self._intern_key(tuple(c[i].item() for c in cols))
+                if self.stats["interned"] != born:
+                    fresh.append(slots[j])
             out[idx] = slots
+            if self.mark_new and fresh:
+                out[idx] = np.where(np.isin(slots, fresh), ~slots, slots)
         if tick is not None:
             self._last_tick[slots] = tick
             self._tick = tick if self._tick is None else max(tick, self._tick)
@@ -181,8 +196,10 @@ class GroupEncoder:
             ])
         return slots
 
-    def _intern_unique(self, uniq: np.ndarray) -> np.ndarray:
-        """Slots of the sorted distinct values ``uniq`` (array mode)."""
+    def _intern_unique(self, uniq: np.ndarray):
+        """(slots, codes) of the sorted distinct values ``uniq`` (array
+        mode): the codes are the slots, under ``mark_new`` ``~slot`` for
+        the values interned here."""
         if self._skeys is None:
             self._to_arrays(uniq.dtype)
         sk, ss = self._skeys, self._sslots
@@ -205,7 +222,9 @@ class GroupEncoder:
             slots[new] = got
             self._skeys = np.insert(sk, pos[new], uniq[new])
             self._sslots = np.insert(ss, pos[new], got)
-        return slots
+            if self.mark_new:
+                return slots, np.where(new, ~slots, slots)
+        return slots, slots
 
     def _intern_key(self, key: Tuple) -> int:
         if self._skeys is not None:

@@ -38,9 +38,18 @@ TINY_Q5 = {"event_time_rate": 2_000, "batch": 2_000, "fused_segment_len": 2,
 # the run's 40 batches close three)
 # (nexmark_q11.replay: the gap is ten batches; sessions close from the
 # eleventh batch on, through drains and at the flush)
+# (linear_road_rows4.replay: tests/test_cells_reference_cpu.py's cut: one
+# expressway at 16 reports a second, a batch 5 s, a pool eight rounds;
+# trips of 3-9 reports, so slots are purged and reused inside the run's
+# 20 rounds, and accidents of 6 reports give rows)
+TINY_LR = {"expressways": 1, "reports_per_s_per_xway": 16,
+           "trip_reports_min": 3, "trip_reports_max": 9,
+           "accident_every_s": 120, "accident_reports": 6, "batch": 80,
+           "engine_config": {"hop_group_slots": 1_024}}
 POOL_BATCHES = {"nexmark_q5.replay": 20, "nexmark_q8.replay": 20,
-                "nexmark_q11.replay": 20}
+                "nexmark_q11.replay": 20, "linear_road_rows4.replay": 48}
 WARM_BATCHES, RUN_BATCHES = 8, 40
+LONGER_RUNS = {"linear_road_rows4.replay": 120}
 
 # Names a sound tiny run does not book, or books only when the timing
 # falls so, and why. Their cases stay: they assert that the package still
@@ -78,7 +87,9 @@ class _TinyRun:
     run takes (``bmlib/cell.py``): after warm-up, and at the end."""
 
     def __init__(self, workload):
-        over = dict(TINY_Q5 if workload.startswith("nexmark") else TINY)
+        over = dict(TINY_Q5 if workload.startswith("nexmark")
+                    else TINY_LR if workload.startswith("linear_road")
+                    else TINY)
         self.cell, self.cfg, params = bmcell.load_cell(workload, over)
         batch = self.cfg["batch"]
         pool = bmcell.make_pool(
@@ -94,7 +105,7 @@ class _TinyRun:
         while self.source.served < WARM_BATCHES:
             job.run_cycle()
         self.snap0, self.served0 = bmcell._snapshot(job), self.source.served
-        while self.source.served < RUN_BATCHES:
+        while self.source.served < LONGER_RUNS.get(workload, RUN_BATCHES):
             job.run_cycle()
         self.source.stop()
         while not job.finished:

@@ -66,6 +66,19 @@ TINY = {
         "whole_batches": 30, "fused_segment_len": 2,
         "engine_config": {"hop_group_slots": 4_096},
     },
+    # Linear Road on one expressway at 16 reports a second: a tick of 1 s
+    # holds 16 events, a round of 30 s 480, a batch 5 s as in the cell.
+    # Trips of 3-9 reports, so vids die, their slots are purged (idle 90 s
+    # + 30 s) and reused inside the 25 rounds run; accidents of 6 reports
+    # every two minutes, so each gives three rows a vehicle
+    "linear_road_rows4": {
+        "expressways": 1, "reports_per_s_per_xway": 16,
+        "trip_reports_min": 3, "trip_reports_max": 9,
+        "accident_every_s": 120, "accident_reports": 6,
+        "batch": 80, "pool": 3_840, "whole_batches": 150,
+        "fused_segment_len": 4,
+        "engine_config": {"hop_group_slots": 1_024},
+    },
 }
 
 

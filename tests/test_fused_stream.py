@@ -375,5 +375,75 @@ def test_fused_h2d_overlap_counters(monkeypatch):
     # uploads count SEGMENTS (one device_put per stacked segment):
     # 10 batches at segment 3 -> 4 dispatches (3+3+3+1 partial)
     assert counters.get("fusion.h2d_uploads", 0) == 4
-    # every upload after the first saw in-flight compute
-    assert counters.get("fusion.h2d_overlapped", 0) == 3
+    # every upload after the first saw in-flight compute, but the one
+    # that follows the two waits in which the window measures the
+    # device's time for a dispatch (Job._inflight_depth)
+    assert counters.get("fusion.h2d_overlapped", 0) == 2
+
+
+@pytest.mark.parametrize(
+    "step_s, depth", [(0.97, 1), (0.4, 2), (0.06, 6), (0.002, 6)],
+    ids=["a_second_a_dispatch", "two_fit", "sixty_ms", "host_bound"],
+)
+def test_ticket_window_holds_about_a_second_of_device_work(
+    monkeypatch, step_s, depth
+):
+    """``Job._inflight_depth``: the window lets ``max_inflight_cycles``
+    dispatches wait behind the running one, and no more than about
+    ``MAX_QUEUED_S`` of device work. The device here is a clock: it
+    takes ``step_s`` a dispatch, in order; the host stages one in 10 ms
+    and so runs ahead as far as the window lets it."""
+    from flink_siddhi_tpu.runtime import executor
+
+    class _Clock:
+        t = 100.0
+        free = 0.0  # when the device has done all it was given
+
+    class _Ticket:
+        def __init__(self):
+            _Clock.free = self.done = max(_Clock.free, _Clock.t) + step_s
+
+        def is_ready(self):
+            return _Clock.t >= self.done
+
+        def block_until_ready(self):
+            _Clock.t = max(_Clock.t, self.done)
+
+    monkeypatch.setattr(executor.time, "monotonic", lambda: _Clock.t)
+    monkeypatch.setattr(
+        Job, "_make_ticket", classmethod(lambda cls, states: _Ticket()))
+    monkeypatch.setattr(
+        executor.jax, "block_until_ready", lambda t: t.block_until_ready())
+    cql, _n = CASES["filter"]
+    schema = _schema()
+    job = Job([compile_plan(cql, {"inputStream": schema})],
+              [BatchSource("inputStream", schema, iter(()))],
+              batch_size=BATCH, time_mode="processing")
+    job.telemetry.enabled = False  # no starvation clock beside this one
+    rt, = job._plans.values()
+    waiting = []
+    for i in range(40):
+        _Clock.t += 0.010
+        job._ticket_window(rt, None, i)
+        waiting.append(len(rt.tickets))
+    if step_s < 0.010:
+        # the host is the slower side: no dispatch ever finds another
+        # running, nothing is measured and nothing waits
+        assert rt.dispatch_s is None and max(waiting) == 1
+        return
+    # measured by the second dispatch (it found the first still running
+    # and waited for both), exact to the clock
+    assert rt.dispatch_s == pytest.approx(step_s)
+    assert waiting[1] == 0 and max(waiting[2:]) == depth
+    assert waiting[-1] == depth  # and the host is kept that far ahead
+    # a segment that is short of tapes stays open while the window is
+    # full (the run loop's age check asks), and goes once it is not
+    assert job._window_full(rt)
+    _Clock.t = _Clock.free
+    assert not job._window_full(rt) and not rt.tickets
+    # a device that turns out faster is found out at the next two waits
+    step_s /= 4
+    for i in range(40, 80):
+        _Clock.t += 0.010
+        job._ticket_window(rt, None, i)
+    assert rt.dispatch_s == pytest.approx(step_s)
