@@ -313,9 +313,9 @@ class CompiledPlan:
                     [to_word(r)
                      for r in [ts] + [jnp.asarray(c) for c in cols]]
                 )
-                # all rows compact through ONE scatter (per-fusion launch
-                # overhead dominates at micro-batch sizes), or through
-                # none where the mask is a prefix already
+                # all rows compact through ONE sort keyed on the mask and
+                # one gather, or through neither where the mask is a
+                # prefix already
                 n, block, is_prefix = front_compact(mask, src)
                 compactions = compactions.at[ai].add(1)
                 identity = identity.at[ai].add(is_prefix.astype(jnp.int32))

@@ -495,15 +495,17 @@ class SlidingWindowArtifact:
                 cols[f.name] = f.decode_column_np(raw)
         return [(schema, ColumnBatch(ts_out, cols))]
 
-    # -- blocked (sort-free) sliding aggregation ---------------------------
+    # -- blocked (no sort by group) sliding aggregation -------------------
     def _step_blocked(self, state: Dict, tape) -> Tuple[Dict, Tuple]:
-        """Windowed per-group sums without a sort over the tape.
+        """Windowed per-group sums without a sort of the tape by group.
 
         Same semantics as ``_step_matrix`` (window = last C matching
         events / time span; aggregates over the emitting event's group),
-        other machinery. Arrivals front-compact via scatter (not
-        argsort), or not at all where the mask is a prefix already
-        (compact.py). The concat sequence, ring ++ arrivals, is then
+        other machinery. Arrivals front-compact through one sort of the
+        tape's positions keyed on the mask alone and one gather of the
+        fold's columns in that order, or not at all where the mask is a
+        prefix already (compact.py). The concat sequence, ring ++
+        arrivals, is then
         merged with its own expiries (window_merge.py): a length
         window's order is fixed by C and E, so ``static_merge`` cuts
         the tiles from the sequence with slices and no index array

@@ -130,6 +130,10 @@ class WarmSlot:
         # aval signature -> loaded (or fallback-compiled) executable
         # fst:threadsafe GIL-atomic dict get/set; the run loop and the warm-compile pool thread may race one signature — the loser's executable is identical and a lost insert recompiles once
         self._exes: Dict[str, object] = {}
+        # aval signature -> serialized payload of an executable compiled
+        # here (same threads, same argument); kept, like the executable,
+        # so that a store swept by gc() is written again
+        self._payloads: Dict[str, bytes] = {}
         self._scope: Dict[str, Optional[str]] = {
             "plan": None, "tenant": None,
         }
@@ -174,6 +178,12 @@ class WarmSlot:
 
     def _compile(self, args, sig: str):
         exe = self._wrapper.lower(*args).compile()
+        # serialized here, before its first call: XLA's CPU backend
+        # refuses an executable whose sort has run ("`LessThan` is not
+        # serializable"). The bytes wait for persist_entry
+        payload = self._store._serialize(self._slot, sig, exe)
+        if payload is not None:
+            self._payloads[sig] = payload
         self._exes[sig] = exe
         self._store._count_miss(
             self._key, self._slot, sig, **self._scope
@@ -184,8 +194,8 @@ class WarmSlot:
     def adopt(self, sig: str, exe) -> None:
         self._exes[sig] = exe
 
-    def signatures(self) -> Dict[str, object]:
-        return dict(self._exes)
+    def payloads(self) -> Dict[str, bytes]:
+        return dict(self._payloads)
 
 
 class WarmStartStore:
@@ -278,26 +288,26 @@ class WarmStartStore:
         return os.path.join(self.key_dir(key), f"{slot}@{sig}.exe")
 
     # -- raw executable i/o -----------------------------------------------
-    def _write_exe(self, key, slot: str, sig: str, compiled) -> bool:
+    def _serialize(self, slot: str, sig: str, compiled) -> Optional[bytes]:
         from jax.experimental import serialize_executable as se
 
-        path = self._exe_path(key, slot, sig)
-        if os.path.exists(path):
-            return False
         try:
             device_ids = [
                 d.id
                 for d in compiled.runtime_executable().local_devices()
             ]
-            payload = pickle.dumps(
-                (*se.serialize(compiled), device_ids)
-            )
+            return pickle.dumps((*se.serialize(compiled), device_ids))
         except Exception as e:  # noqa: BLE001 — best-effort persist
             _LOG.warning(
                 "could not serialize %s/%s (%s: %s)",
                 slot, sig, type(e).__name__, e,
             )
             self._count_error()
+            return None
+
+    def _write_exe(self, key, slot: str, sig: str, payload: bytes) -> bool:
+        path = self._exe_path(key, slot, sig)
+        if os.path.exists(path):
             return False
         os.makedirs(os.path.dirname(path), exist_ok=True)
         tmp = f"{path}.tmp-{os.getpid()}"
@@ -500,9 +510,10 @@ class WarmStartStore:
         self, key, entry, acc_example=None,
         plan_id: Optional[str] = None, tenant: Optional[str] = None,
     ) -> int:
-        """Serialize every executable the bundle's warm slots hold to
-        disk (skipping ones already there — persisting at each
-        checkpoint boundary is cheap once the store is caught up). Pack
+        """Write every executable the bundle's warm slots compiled to
+        disk (each was serialized as it was compiled, WarmSlot._compile),
+        skipping ones already there — persisting at each checkpoint
+        boundary is cheap once the store is caught up. Pack
         programs are re-lowered from ``acc_example`` at persist time —
         off the hot path, outside any compile-attribution scope — only
         for widths not on disk yet. Returns how many files were
@@ -512,8 +523,8 @@ class WarmStartStore:
             fn = getattr(entry, name)
             if not isinstance(fn, WarmSlot):
                 continue
-            for sig, exe in fn.signatures().items():
-                if self._write_exe(key, name, sig, exe):
+            for sig, payload in fn.payloads().items():
+                if self._write_exe(key, name, sig, payload):
                     self._count_persist(key, name, sig, plan_id, tenant)
                     wrote += 1
         if acc_example is not None:
@@ -542,7 +553,10 @@ class WarmStartStore:
                     width, type(e).__name__, e,
                 )
                 continue
-            if self._write_exe(key, slot, sig, compiled):
+            payload = self._serialize(slot, sig, compiled)
+            if payload is not None and self._write_exe(
+                key, slot, sig, payload
+            ):
                 self._count_persist(key, slot, sig, plan_id, tenant)
                 wrote += 1
         return wrote

@@ -2682,7 +2682,7 @@ class Job:
         """Rows 2 and 3 of the count prefix (plan.py ``init_acc``), booked
         at each drain: the accumulator's aligned appends since the last
         one and, of them, those whose mask was a prefix already, so that
-        the front-compaction scattered nothing (compiler/compact.py).
+        the front-compaction moved nothing (compiler/compact.py).
         The step decides on the device, so the host learns it here."""
         tel.inc("acc.compactions", int(aligned.sum()))
         tel.inc("acc.compactions_identity", int(identity.sum()))

@@ -15,7 +15,13 @@ whatever device JAX picked, at ``linear_road_rows4``'s sizes: a
 * ``step``: the artifact's whole step on that tape, from
   ``compile_plan`` of the configuration's query;
 * ``step_acc``: the step and the accumulator's append (the
-  front-compaction of twelve rows of the tape's width).
+  front-compaction of twelve rows of the tape's width);
+* ``append[nine rows]``: that front-compaction alone
+  (compiler/compact.py ``front_compact`` on a ``[12, 2^20]`` block of
+  which nine scattered rows pass: the sort branch, a sort of the
+  positions and a gather of rows); ``append[no row]``:
+  the identity branch; ``append_x4_in_scan``: four of the first as the
+  body of one ``lax.scan``, as the fused segment runs them.
 
 Usage (the chip tool): python scripts/profile_perkey.py [dense]
 (``dense``: a batch's slots lie side by side, as a young table hands
@@ -168,6 +174,7 @@ def main():
         lambda r, g: r.at[(iota % C) * G + g].set(iota, mode="drop")),
         ring, g)
 
+    append_pieces(rng)
     states, acc = jax.jit(plan.init_state)(), jax.jit(plan.init_acc)()
     timed("step", jax.jit(plan.step), states, tape)
     timed("step_acc", jax.jit(plan.step_acc), states, acc, tape)
@@ -178,14 +185,37 @@ def main():
                           "peak_bytes_in_use", 0)}))
 
 
+NINE_ROWS = np.arange(9) * 50_021 + 17  # the lanes that pass ``having``
+
+
+def append_pieces(rng):
+    from flink_siddhi_tpu.compiler.compact import front_compact
+
+    block = jnp.asarray(
+        rng.integers(-(1 << 31), 1 << 31, (12, E)).astype(np.int32))
+    nine = jnp.zeros(E, bool).at[NINE_ROWS].set(True)
+    timed("append[nine rows]", jax.jit(front_compact), nine, block)
+    timed("append[no row]", jax.jit(front_compact), jnp.zeros(E, bool),
+          block)
+
+    def in_scan(block, masks):
+        def body(blk, mask):  # each append's block hangs on the one before
+            return blk ^ front_compact(mask, blk)[1], None
+
+        return lax.scan(body, block, masks)[0]
+
+    timed("append_x4_in_scan", jax.jit(in_scan), block,
+          jnp.stack([nine] * 4))
+
+
 def device_ops(plan, art, tape, states, acc, steps=6, top=28):
     """Device time of ``step_acc`` by XLA operation, from a profiler
     trace, each beside the ``op_name`` (scopes included) the compiled
     program gives it, on tapes as the cell's: every vehicle moves on
     from step to step but nine, which stand still, so from the fourth
     step on nine rows pass ``having`` and the accumulator's append takes
-    ``compact.py``'s scatter branch (a tape of which no row passes is a
-    prefix of length 0 and scatters nothing: ``step_acc`` above)."""
+    ``compact.py``'s sort branch (a tape of which no row passes is a
+    prefix of length 0 and sorts nothing: ``step_acc`` above)."""
     import collections
     import dataclasses
     import glob
@@ -196,7 +226,7 @@ def device_ops(plan, art, tape, states, acc, steps=6, top=28):
     from jax.profiler import ProfileData
 
     pos_key = next(k for k in tape.cols if k.endswith(".pos"))
-    still = jnp.zeros(E, bool).at[jnp.arange(9) * 50_021 + 17].set(True)
+    still = jnp.zeros(E, bool).at[NINE_ROWS].set(True)
 
     def moved(t):
         return dataclasses.replace(tape, cols={

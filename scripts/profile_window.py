@@ -8,7 +8,10 @@ The twin of ``scripts/profile_hop.py``; PERF.md's split of
 Both sites front-compact the tape's selected rows (compiler/compact.py).
 Each is timed in the form the step had before PR 32 (a scatter by
 ``cumsum(mask) - 1``, a gather back) and in the form it takes when the
-mask is a prefix (a select, a slice):
+mask is a prefix (a select, a slice); since PR 43 a mask that is no
+prefix takes a sort of the positions and a gather of rows in place of
+that scatter, which the helper's ``[hole]`` lines and
+``step_acc[hole]`` time:
 
 * ``prefix_check``: what deciding costs: ``sum``, compare with
   ``iota < n``, ``all``;
@@ -39,7 +42,8 @@ mask is a prefix (a select, a slice):
   both share, on each form's tiles;
 * ``step_acc[prefix|hole]``: the whole step of ``window1k``'s query on a
   tape whose mask is a prefix (the cell's) and on the same tape with one
-  row invalid, which takes the scatters; under each, from a profiler
+  row invalid, which takes the sorts and the row gathers; under
+  each, from a profiler
   trace of five more calls, the device time of its costliest XLA
   operations, each beside the scope the compiled program names for it
   (``fst.window_fold``, ``fst.acc_append``, ``cond/branch_...``).

@@ -426,7 +426,13 @@ def test_fleet_epoch_and_handoff_ride_the_checkpoint(tmp_path):
     assert job2._last_handoff["reason"] == "drain"
 
 
-def test_standalone_dynamic_plan_restores_warm_from_store(tmp_path):
+@pytest.mark.parametrize("cql", [
+    "from S[id == 0] select id, price insert into out",
+    "from S select id, price insert into out",
+    "partition with (id of S) begin from S#window.length(2) "
+    "select id, sum(price) as total insert into out; end",
+], ids=["filtered_select", "select", "perkey_window"])
+def test_standalone_dynamic_plan_restores_warm_from_store(tmp_path, cql):
     """Regression: a NON-chain dynamic tenant (filter/select — no
     DynamicChainGroup wrap, so it replays through _replay_dynamic's
     standalone branch, not the group loop) must stay cacheable across
@@ -434,27 +440,45 @@ def test_standalone_dynamic_plan_restores_warm_from_store(tmp_path):
     bootstrap can only warm it from the persistent store if the replay
     does too. Before the fix the standalone branch replayed via plain
     add_plan (cacheable=False) and the warm store was silently skipped
-    for every non-chain tenant."""
+    for every non-chain tenant.
+
+    The filtered select's append front-compacts through a sort
+    (compiler/compact.py), the per-key window's fold sorts by key, and
+    both have run by the time of persist_warm; XLA's CPU backend cannot
+    serialize an executable whose sort has run, so the store serializes
+    each as it is compiled (warmstore.py ``WarmSlot._compile``) and
+    nothing here is missed."""
     store_dir = str(tmp_path / "store")
     src, ctrl = CallbackSource("S", SCHEMA), ControlQueueSource()
     job = _make_job(src, ctrl, WarmStartStore(store_dir))
     plane = ControlPlane(job, ctrl, gate=AdmissionGate(compiler))
-    plane.admit(
-        "from S[id == 0] select id, price insert into out",
-        plan_id="flt0", tenant="t0",
-    )
+    plane.admit(cql, plan_id="flt0", tenant="t0")
     for i in range(8):
         src.emit(Rec(i % 2, float(i), 1000 + i), 1000 + i)
     job.run_cycle()
     job.persist_warm()
     assert job.warm_store.stats()["persists"] >= 1
+    assert job.warm_store.stats()["errors"] == 0
     ckpt = str(tmp_path / "ckpt")
     job.save_checkpoint(ckpt)
 
-    src2, ctrl2 = CallbackSource("S", SCHEMA), ControlQueueSource()
+    def resume(store):
+        src2, ctrl2 = CallbackSource("S", SCHEMA), ControlQueueSource()
+        job2 = _make_job(src2, ctrl2, store)
+        job2.restore(ckpt)
+        return src2, job2
+
+    # a replica with no store compiles cold: the rows to expect
+    src_cold, job_cold = resume(None)
+    for i in range(8):
+        src_cold.emit(Rec(i % 2, float(i), 2000 + i), 2000 + i)
+    job_cold.run_cycle()
+    job_cold.drain_outputs()
+    want = [tuple(r) for r in job_cold.results("out")]
+    assert want
+
     store2 = WarmStartStore(store_dir)
-    job2 = _make_job(src2, ctrl2, store2)
-    job2.restore(ckpt)
+    src2, job2 = resume(store2)
     rt = job2._plans["flt0"]
     assert rt.warm_key is not None  # replayed cacheable → store-wrapped
     # the preload walked the executables process A persisted
@@ -467,7 +491,7 @@ def test_standalone_dynamic_plan_restores_warm_from_store(tmp_path):
     # a loaded executable that rejected its inputs would have been
     # counted here and served by the jit wrapper instead
     assert store2.stats()["errors"] == 0
-    assert len(job2.results("out")) > 0
+    assert [tuple(r) for r in job2.results("out")] == want
 
 
 # -- the headline: cross-process zero-lowering warm start --------------------

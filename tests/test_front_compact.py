@@ -1,5 +1,6 @@
-"""Front-compaction that does not scatter where the mask is a prefix
-(compiler/compact.py): the helper against numpy on both branches, the
+"""Front-compaction that moves nothing where the mask is a prefix and
+sorts by the mask where it is not (compiler/compact.py): the helper
+against numpy on both branches, alone and as the body of a scan, the
 accumulator append around the ``fits`` boundary, the blocked length window
 against the per-event interpreter with and without a filter, and the two
 counters that say how often the identity engaged. Nothing here is a rate
@@ -28,11 +29,17 @@ N_VALID = 500
 def _masks():
     """name -> (mask, is it a prefix). ``disabled`` is what a plan whose
     ``enabled`` flag is off hands over: its mask is all false, and the
-    empty mask is the prefix of length 0."""
+    empty mask is the prefix of length 0. From ``nine_rows`` on, what
+    only the sort branch meets."""
     iota = np.arange(E)
     valid = iota < N_VALID
     hole = valid.copy()
     hole[123] = False
+    nine = np.zeros(E, bool)
+    nine[np.arange(9) * 67 + 17] = True
+    draws = np.random.default_rng(2).random((3, 4_001))  # no power of two
+    sparse, dense = draws[0] < 0.001, draws[2] < 0.999
+    sparse[-1], dense[5] = True, False  # neither is empty nor a prefix
     return {
         "full": (np.ones(E, bool), True),
         "proper_prefix": (valid, True),
@@ -40,19 +47,31 @@ def _masks():
         "one_hole": (hole, False),
         "suffix": (iota >= E - N_VALID, False),
         "disabled": (valid & np.asarray(False), True),
+        "nine_rows": (nine, False),
+        "last_row_alone": (iota == E - 1, False),
+        "all_but_the_first": (iota > 0, False),
+        "alternating": (iota % 2 == 1, False),
+        "random_0.001": (sparse, False),
+        "random_0.5": (draws[1] < 0.5, False),
+        "random_0.999": (dense, False),
     }
 
 
 MASKS = _masks()
 
 
-def _rows(rng):
-    col = rng.standard_normal(E).astype(np.float32)
+def _rows(rng, e=E):
+    col = rng.standard_normal(e).astype(np.float32)
     col[::7] = -0.0  # a selected -0.0 keeps its sign bit on both branches
+    bits = col.view(np.int32)  # and a NaN its sign and payload
+    bits[1::5] = 0x7FC12345
+    bits[2::11] = -0x3FFFB3  # 0xFFC0004D
+    bits[3::13] = 0x7F800001  # signalling
     return {
-        "block": rng.integers(-(1 << 31), 1 << 31, (4, E)).astype(np.int32),
+        "block": rng.integers(-(1 << 31), 1 << 31, (4, e)).astype(np.int32),
+        "words": rng.integers(-(1 << 31), 1 << 31, (12, e)).astype(np.int32),
         "col": col,
-        "flag": rng.random(E) < 0.5,
+        "flag": rng.random(e) < 0.5,
     }
 
 
@@ -71,17 +90,40 @@ def _bits(a):
     return a.view(np.int32) if a.dtype == np.float32 else a
 
 
+def _in_a_scan(masks, rows):
+    """Four front-compactions as the body of one scan, the fused
+    segment's shape: a step's mask and rows are its slice of ``xs``."""
+    return jax.lax.scan(
+        lambda _c, x: (None, front_compact(*x)), None, (masks, rows)
+    )[1]
+
+
+@pytest.mark.parametrize("shape", ["jit", "scan_of_four"])
 @pytest.mark.parametrize("name", list(MASKS))
-def test_the_helper_equals_a_numpy_front_compaction(name):
+def test_the_helper_equals_a_numpy_front_compaction(name, shape):
     mask, prefix = MASKS[name]
-    rows = _rows(np.random.default_rng(3))
-    want_n, want = _np_front_compact(mask, rows)
-    n, got, is_prefix = jax.jit(front_compact)(jnp.asarray(mask), rows)
-    assert n.dtype == jnp.int32 and int(n) == want_n
-    assert bool(is_prefix) is prefix
-    for k in rows:
-        assert got[k].dtype == rows[k].dtype
-        np.testing.assert_array_equal(_bits(got[k]), _bits(want[k]), k)
+    e, rng = len(mask), np.random.default_rng(3)
+    assert prefix is np.array_equal(mask, np.arange(e) < mask.sum())
+    if shape == "jit":
+        masks, steps = [mask], [_rows(rng, e)]
+        outs = [jax.jit(front_compact)(jnp.asarray(mask), steps[0])]
+    else:  # both branches in one scan where the mask's complement flips
+        masks = [mask, ~mask, np.roll(mask, 1), mask]
+        steps = [_rows(rng, e) for _ in masks]
+        outs = jax.jit(_in_a_scan)(
+            jnp.asarray(np.stack(masks)),
+            {k: np.stack([r[k] for r in steps]) for k in steps[0]},
+        )
+        outs = [jax.tree.map(lambda x: x[t], outs) for t in range(4)]
+    for t, (m, rows, out) in enumerate(zip(masks, steps, outs)):
+        n, got, is_prefix = out
+        want_n, want = _np_front_compact(m, rows)
+        assert n.dtype == jnp.int32 and int(n) == want_n
+        assert bool(is_prefix) is np.array_equal(m, np.arange(e) < want_n)
+        for k in rows:
+            assert got[k].dtype == rows[k].dtype
+            np.testing.assert_array_equal(
+                _bits(got[k]), _bits(want[k]), f"{k}, step {t}")
 
 
 @pytest.mark.parametrize("name", [k for k, v in MASKS.items() if v[1]])
@@ -102,18 +144,18 @@ def test_a_prefix_gives_the_scatters_bits(name):
 def test_batch_rows_brings_each_selected_row_its_value(name):
     mask, prefix = MASKS[name]
     rng = np.random.default_rng(5)
-    offset = 17
+    e, offset = len(mask), 17
     seqs = {
-        "f": rng.standard_normal(offset + E).astype(np.float32),
-        "i": rng.integers(0, 1 << 20, offset + E).astype(np.int32),
-        "b": rng.random(offset + E) < 0.5,
+        "f": rng.standard_normal(offset + e).astype(np.float32),
+        "i": rng.integers(0, 1 << 20, offset + e).astype(np.int32),
+        "b": rng.random(offset + e) < 0.5,
     }
     got = jax.jit(batch_rows, static_argnums=3)(
         jnp.asarray(mask), jnp.asarray(prefix), seqs, offset
     )
     rank = np.cumsum(mask) - 1
     for k, v in seqs.items():
-        assert got[k].dtype == v.dtype and got[k].shape == (E,)
+        assert got[k].dtype == v.dtype and got[k].shape == (e,)
         np.testing.assert_array_equal(
             _bits(got[k])[mask], _bits(v)[offset + rank[mask]], k
         )
