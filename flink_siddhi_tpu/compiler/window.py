@@ -2479,9 +2479,13 @@ def compile_expired_window(
 # Per-key sliding windows: `partition with (k of S) begin ...#window.length`
 # --------------------------------------------------------------------------
 
-# the widest per-key window whose min / max the step reads: it gathers
-# C - 1 ring values an event, one gather each
+# the widest per-key window whose min / max the step reads: a slot's
+# record holds C words an argument, and an event reduces over them
 PERKEY_RING_MAX = 64
+# a row of the per-key record table: the lanes of a TPU vector
+_LANES = 128
+# the tape rows whose table rows the per-key step holds at once
+_PERKEY_BLOCK = 1 << 16
 
 
 def purge_ticks(interval_ms: int, idle_ms: int) -> Tuple[int, int]:
@@ -2496,6 +2500,46 @@ def purge_ticks(interval_ms: int, idle_ms: int) -> Tuple[int, int]:
     return interval_ms, -(-idle_ms // interval_ms) + 1
 
 
+def _perkey_read(table, slots, n, W: int, need: int):
+    """The first ``need`` words of the records of ``slots`` (ascending;
+    the first ``n`` count) in ``table``, rows of ``R`` records of ``W``
+    words: a list of ``need`` columns, a word each. One gather of table
+    rows by ``slot // R``; the gathered rows are turned a tile of 128 by
+    128 at a time, so that the slots lie along the lanes, and of the R
+    records a row holds the slot's own is taken in registers. A block
+    of slots at a time, since a gathered row is 128 lanes whatever a
+    record needs, and no block past the ``n``-th slot."""
+    E, R = slots.shape[0], table.shape[1] // W
+    # whole tiles (admission traces a plan on a tape shorter than one)
+    slots = jnp.pad(slots, (0, -E % _LANES), mode="edge")
+    padded = slots.shape[0]
+    block = _PERKEY_BLOCK if padded % _PERKEY_BLOCK == 0 else padded
+
+    def read_block(i, words):
+        g = lax.dynamic_slice(slots, (i * block,), (block,))
+        rows = table.at[g // R].get(
+            indices_are_sorted=True, mode="promise_in_bounds"
+        )
+        # [tile, lane of the row, slot]
+        tiles = rows.reshape(-1, _LANES, table.shape[1]).transpose(0, 2, 1)
+        place = (g % R).reshape(-1, _LANES)
+        out = []
+        for w, column in enumerate(words):
+            word = tiles[:, w]
+            for at in range(1, R):
+                word = jnp.where(place == at, tiles[:, at * W + w], word)
+            out.append(lax.dynamic_update_slice(
+                column, word.reshape(-1), (i * block,)
+            ))
+        return out
+
+    words = lax.fori_loop(
+        0, -(-n // block), read_block,
+        [jnp.zeros(padded, jnp.int32) for _ in range(need)],
+    )
+    return [column[:E] for column in words]
+
+
 @dataclass
 class PerKeyWindowArtifact:
     """``partition with (k of S) ... #window.length(C)``: EVERY key has
@@ -2507,24 +2551,44 @@ class PerKeyWindowArtifact:
     TPU shape: one stable sort of the batch by slot code (the arguments
     ride along), segmented scans for each event's ordinal ``n`` in its
     key's stream, and per slot of a host-interned table
-    (``schema/encoders.py``):
+    (``schema/encoders.py``) ONE RECORD of ``W`` int32 words in the one
+    leaf ``rec``:
 
-    * ``cnt``: the key's arrivals so far; ``count()`` is
+    * word 0: the key's arrivals so far; ``count()`` is
       ``min(n + 1, C)``;
-    * sums (``sum`` / ``avg`` / ``stddev``): per-group LOCAL prefix
-      differences, windowed_g(n) = S_g(n) - S_g(n - C), where S_g is
-      the key's running (Neumaier-compensated) float32 sum: a [G]
-      running total and a [G, C] ring of the last C prefix CHECKPOINTS;
-    * ``min`` / ``max``: a ring of the key's last C RAW values in the
-      argument's own type (an int stays an int: no float32 round trip),
-      ``[C * G]`` flat, ring position major. An event reads the C - 1
-      values before it: those of its own batch from the sorted column
-      shifted (no gather), the older ones from the ring.
+    * then, for each argument under ``min`` / ``max``, C words: the
+      key's last C RAW values, that of ordinal ``m`` at ring position
+      ``m % C``, in the argument's own type (an int stays an int, a
+      float rides as its bits: no float32 round trip, no rounding).
+
+    ``W`` is ``1 + C * A`` padded to a power of two (past 128 to a
+    multiple of it), so that records pack whole into rows of 128 lanes:
+    ``rec`` is ``[G * W / 128, 128]``, ``128 / W`` records a row (a
+    ``[G, W]`` leaf would be tiled to 128 lanes, ``128 / W`` times the
+    memory). A gather on the TPU pays by the lookup and hardly by its
+    width, a scatter of rows ten times one of values (PERF.md §7 row
+    23). So the step READS a slot's state with one row gather by the
+    sorted slot codes and takes the record and its words from the row in
+    registers, and WRITES with value scatters into the table's flat view
+    at ``slot * W + word``: one for the count at a key's last event, one
+    an argument at the key's last ``min(C, its events)`` of the batch.
+    An event reduces over its own value, the C - 1 before it in its
+    batch (the sorted column shifted, no gather) and the record's ring
+    words whose ordinal still lies in its window.
+
+    Sums (``sum`` / ``avg`` / ``stddev``) keep leaves of their own and
+    read the count from the record: per-group LOCAL prefix differences,
+    windowed_g(n) = S_g(n) - S_g(n - C), where S_g is the key's running
+    (Neumaier-compensated) float32 sum: a [G] running total and a
+    [G, C] ring of the last C prefix CHECKPOINTS.
 
     Under ``@purge`` the slots expire (``purge_ticks``) and the encoder
     marks the rows of a key it gave a slot (``mark_new``: ``~slot``):
     the step counts such a key from zero, so nothing the slot's last
-    key left is read (every ring read is gated by the count)."""
+    key left is read (every ring word is gated by the count). A
+    snapshot taken while the state was ``cnt`` and ``vals<j>`` leaves
+    (before PR 45) does not restore into this layout: ``restore``
+    refuses it by its leaves."""
 
     name: str
     output_schema: OutputSchema
@@ -2576,11 +2640,33 @@ class PerKeyWindowArtifact:
         """What a drain delivered: the rows past ``having``."""
         return {"perkey.rows": len(payload)}
 
+    def _ring_args(self) -> List[int]:
+        """The arguments whose raw values a record holds."""
+        return sorted(
+            j for j, st in self._stats().items() if st & {"min", "max"}
+        )
+
+    def _record(self) -> Tuple[int, int]:
+        """(W, R): the words of a slot's record and the records a row of
+        the table holds. ``1 + C * A`` words padded so that
+        ``R * W`` is a whole number of vectors."""
+        need = 1 + self.capacity * len(self._ring_args())
+        if need > _LANES:
+            return -(-need // _LANES) * _LANES, 1
+        W = 1 << (need - 1).bit_length()
+        return W, _LANES // W
+
+    def _slots(self) -> int:
+        """The table's slots: whole rows of records."""
+        R = self._record()[1]
+        return -(-self._G() // R) * R
+
     def init_state(self) -> Dict:
-        G, C = self._G(), self.capacity
+        G, C = self._slots(), self.capacity
+        W, R = self._record()
         st = {
             "enabled": jnp.asarray(True),
-            "cnt": jnp.zeros(G, jnp.int32),  # arrivals ever, per key
+            "rec": jnp.zeros((G // R, R * W), jnp.int32),
         }
         for arg_idx, stats in self._stats().items():
             for s in sorted(stats & {"sum", "sumsq"}):
@@ -2589,29 +2675,22 @@ class PerKeyWindowArtifact:
                 st[f"ring_{s}{arg_idx}"] = jnp.zeros(
                     (G, C), jnp.float32
                 )
-            if stats & {"min", "max"}:
-                st[f"vals{arg_idx}"] = jnp.zeros(
-                    C * G, self.arg_types[arg_idx].device_dtype
-                )
         return st
 
     def grow_state(self, state: Dict) -> Dict:
-        G, C = state["cnt"].shape[0], self.capacity
-        need = self._G()
+        R = self._record()[1]
+        G, need = state["rec"].shape[0] * R, self._slots()
         if need <= G:
             return state
         out = {"enabled": state["enabled"]}
         for k, v in state.items():
             if k == "enabled":
                 continue
-            if k.startswith("vals"):  # [C * G], ring position major
-                v = v.reshape(C, G)
-                pad = jnp.zeros((C, need - G), v.dtype)
-                out[k] = jnp.concatenate([v, pad], axis=1).reshape(-1)
-                continue
-            pad_shape = (need - G,) + v.shape[1:]
+            # a slot's record and its sums lie side by side: new slots
+            # are new rows at the end of every leaf
+            more = (need - G) // R if k == "rec" else need - G
             out[k] = jnp.concatenate(
-                [v, jnp.zeros(pad_shape, v.dtype)]
+                [v, jnp.zeros((more,) + v.shape[1:], v.dtype)]
             )
         return out
 
@@ -2625,8 +2704,10 @@ class PerKeyWindowArtifact:
         mask = mask & state["enabled"]
         E = tape.capacity
         C = self.capacity
-        G = state["cnt"].shape[0]
+        W, R = self._record()
+        G = state["rec"].shape[0] * R
         stats = self._stats()
+        ring_args = self._ring_args()
 
         # a negative code is ``~slot``: a key this batch was given the
         # slot for, whose state starts anew (``mark_new``)
@@ -2659,14 +2740,20 @@ class PerKeyWindowArtifact:
         # min(C, all) are the ones the rings keep
         to_come = (_seg_scan(ends[::-1], ones, jnp.add) - 1)[::-1]
         is_tail = mask_s & (to_come < C)
-        local_n = (
-            jnp.where(fresh_s, 0, state["cnt"][gather_g]) + seg_rank
-        )  # per-key ordinal
+        # the one read of the table: the words of each event's slot
+        rec = _perkey_read(
+            state["rec"], gather_g, jnp.sum(mask_s, dtype=jnp.int32),
+            W, 1 + C * len(ring_args),
+        )
+        had = jnp.where(fresh_s, 0, rec[0])  # the key's arrivals before
+        local_n = had + seg_rank  # per-key ordinal
         pos = jnp.arange(E, dtype=jnp.int32)
 
+        # the writes are value scatters into the table's flat view, word
+        # ``slot * W + w``: here the count, at a key's last event
         new_state = dict(state)
-        new_state["cnt"] = state["cnt"].at[
-            jnp.where(mask_s & ends, g_s, G)
+        words = state["rec"].reshape(-1).at[
+            jnp.where(mask_s & ends, g_s * W, G * W)
         ].set(local_n + 1, mode="drop")
 
         # windowed count has a closed form: min(local_n + 1, C)
@@ -2674,6 +2761,12 @@ class PerKeyWindowArtifact:
             "cnt": jnp.minimum(local_n + 1, C)
         }
         fresh_slot = None
+        # a record's ring position p holds the key's last ordinal before
+        # this batch that is p mod C: it is in an event's window if the
+        # key wrote it (it is not negative) and it is less than C before
+        # the event's own
+        held = [had - 1 - (had - 1 - p) % C for p in range(C)]
+        ring_ok = [(m >= 0) & (m > local_n - C) for m in held]
 
         for arg_idx in arg_ids:
             sums = sorted(stats[arg_idx] & {"sum", "sumsq"})
@@ -2739,21 +2832,25 @@ class PerKeyWindowArtifact:
             if not kinds:
                 continue
             # the key's last C raw values: the event's own, the k-th
-            # before it from this batch where the key has that many
-            # here (the sorted column shifted by k), else from the ring
-            # at ordinal n - k, which the count says this key wrote
-            rkey = f"vals{arg_idx}"
-            ring, v_s = state[rkey], arg_s[arg_idx]
+            # before it from this batch where the key has that many here
+            # (the sorted column shifted by k), and the record's ring
+            # words that ``ring_ok`` says are still in the window
+            v_s = arg_s[arg_idx]
+            is_float = jnp.issubdtype(v_s.dtype, jnp.floating)
             members = [(v_s, mask_s)]
-            for k in range(1, C):
+            for k in range(1, C):  # C <= PERKEY_RING_MAX < a tape's rows
                 here = jnp.concatenate(
                     [jnp.zeros(k, v_s.dtype), v_s[:E - k]]
-                ) if k < E else jnp.zeros_like(v_s)
-                nth = local_n - k
-                old = ring[(nth % C) * G + gather_g]
-                members.append(
-                    (jnp.where(seg_rank >= k, here, old), nth >= 0)
                 )
+                members.append((here, seg_rank >= k))
+            first = 1 + ring_args.index(arg_idx) * C
+            for p in range(C):
+                old = rec[first + p]
+                members.append((
+                    lax.bitcast_convert_type(old, v_s.dtype)
+                    if is_float else old.astype(v_s.dtype),
+                    ring_ok[p],
+                ))
             for kind in kinds:
                 ident = _identity(kind, v_s.dtype)
                 red = jnp.minimum if kind == "min" else jnp.maximum
@@ -2761,9 +2858,14 @@ class PerKeyWindowArtifact:
                 for val, ok in members[1:]:
                     out = red(out, jnp.where(ok, val, ident))
                 stats_s[f"{kind}{arg_idx}"] = out
-            new_state[rkey] = ring.at[
-                jnp.where(is_tail, (local_n % C) * G + g_s, C * G)
-            ].set(v_s, mode="drop")
+            words = words.at[
+                jnp.where(is_tail, g_s * W + first + local_n % C, G * W)
+            ].set(
+                lax.bitcast_convert_type(v_s, jnp.int32)
+                if is_float else v_s.astype(jnp.int32),
+                mode="drop",
+            )
+        new_state["rec"] = words.reshape(state["rec"].shape)
 
         # back to tape order: a sort by the tape position
         names = sorted(stats_s)

@@ -8,10 +8,14 @@ whatever device JAX picked, at ``linear_road_rows4``'s sizes: a
 * ``sort_8``: the stable sort of the batch by slot code with seven
   operands riding along; ``sort_back_8``: the sort back by position;
 * ``seg_scan``: one segmented scan over the tape;
-* ``gather_1``: one gather of a value per tape row from a ``[G]`` table
-  (the count); ``gather_ring``: one from the ``[4 G]`` ring;
-* ``scatter_1``: one scatter of a value per tape row into ``[G]``;
-  ``scatter_ring``: into the ring;
+* ``read_records``: the step's one read of its state
+  (``_perkey_read``): a row of 128 lanes per tape row from the record
+  table ``[G W / 128, 128]`` by sorted slot codes, a block of 65,536 at
+  a time, the rows turned and of the row's ``128 / W`` records the
+  slot's own taken, for the 544,000 rows that hold an event; ``[whole
+  tape]``: for all 2^20; ``gather_rows``: the gather alone, a block's;
+* ``scatter_word``: one of the step's writes, a value per tape row into
+  the table's flat view at ``slot * W + word``;
 * ``step``: the artifact's whole step on that tape, from
   ``compile_plan`` of the configuration's query;
 * ``step_acc``: the step and the accumulator's append (the
@@ -27,9 +31,10 @@ Usage (the chip tool): python scripts/profile_perkey.py [dense]
 (``dense``: a batch's slots lie side by side, as a young table hands
 them out; without it anywhere among 3.6M, as after a few dozen rounds).
 ``compile`` as its argument compiles the step for a described v5e and
-prints its memory, without a chip. ``drift`` runs ``step_acc`` 160 times
-on one tape, the state carried along, and prints its time twenty steps
-at a time: the same slots every step, so what changes over those 40 s is
+prints its memory and its gathers, scatters, sorts and copies, without a
+chip (the programs' text goes to ``chiprun_out/``). ``drift`` runs
+``step_acc`` 160 times on one tape, the state carried along, and prints
+its time twenty steps at a time: the same slots every step, so what changes over those 40 s is
 the chip and not the table. One line per piece, ``<name> <ms>``,
 then one JSON line naming the device. A number from a CPU run is not a
 device number.
@@ -37,6 +42,7 @@ device number.
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import sys
@@ -127,7 +133,11 @@ def compile_only():
         print(compiled.memory_analysis())
         text = compiled.as_text()
         print({op: text.count(f" {op}(")
-               for op in ("gather", "scatter", "sort")})
+               for op in ("gather", "scatter", "sort", "copy")})
+        os.makedirs(os.path.join(REPO, "chiprun_out"), exist_ok=True)
+        with open(os.path.join(REPO, "chiprun_out",
+                               f"profile_perkey.{name}.hlo.txt"), "w") as f:
+            f.write(text)
 
 
 def main():
@@ -153,11 +163,12 @@ def main():
     g = jnp.where(code < 0, ~code, code)
     cols = [tape.cols[k] for k in sorted(tape.cols)][:6]
     iota = jnp.arange(E, dtype=jnp.int32)
-    table = jnp.zeros(G, jnp.int32)
-    ring = jnp.zeros(C * G, jnp.int32)
+    W, R = art._record()
+    table = jnp.zeros((G // R, R * W), jnp.int32)
+    g_sorted = jnp.sort(g)
     flags = jnp.asarray(rng.random(E) < 0.9)
 
-    from flink_siddhi_tpu.compiler.window import _seg_scan
+    from flink_siddhi_tpu.compiler.window import _perkey_read, _seg_scan
 
     timed("sort_8", jax.jit(lambda g, *cols: lax.sort(
         [g, iota, *cols], num_keys=1, is_stable=True)), g, *cols)
@@ -165,14 +176,16 @@ def main():
         [g, iota, *cols], num_keys=1)), g, *cols)
     timed("seg_scan", jax.jit(lambda f: _seg_scan(
         f, jnp.ones(E, jnp.int32), jnp.add)), flags)
-    timed("gather_1", jax.jit(lambda t, g: t[g]), table, g)
-    timed("gather_ring", jax.jit(
-        lambda r, g: r[(iota % C) * G + g]), ring, g)
-    timed("scatter_1", jax.jit(
-        lambda t, g: t.at[g].set(iota, mode="drop")), table, g)
-    timed("scatter_ring", jax.jit(
-        lambda r, g: r.at[(iota % C) * G + g].set(iota, mode="drop")),
-        ring, g)
+
+    read = functools.partial(_perkey_read, W=W, need=1 + C * 2)
+    timed("read_records", jax.jit(read), table, g_sorted, jnp.int32(N))
+    timed("read_records[whole tape]", jax.jit(read), table, g_sorted,
+          jnp.int32(E))
+    timed("gather_rows", jax.jit(lambda t, g: t.at[g[:1 << 16] // R].get(
+        indices_are_sorted=True, mode="promise_in_bounds")), table, g_sorted)
+    timed("scatter_word", jax.jit(
+        lambda t, g: t.reshape(-1).at[g * W + 1 + iota % C].set(
+            iota, mode="drop").reshape(t.shape)), table, g_sorted)
 
     append_pieces(rng)
     states, acc = jax.jit(plan.init_state)(), jax.jit(plan.init_acc)()

@@ -284,6 +284,227 @@ def test_a_checkpoint_with_purged_slots_restores_the_same_rows():
     assert rows(first) + rows(second) == whole
     assert whole == _interpreted(cql, cols, ts)
     assert len(plan2.spec.encoded[0].encoder) <= 512
+    leaves = snap["plans"]["stops"]["states"][plan.artifacts[0].name]
+    assert set(leaves) == {"enabled", "rec"}
+    assert np.asarray(leaves["rec"]).shape == (512 // 8, 128)
+
+
+def test_a_snapshot_of_the_leaves_before_the_record_is_refused():
+    """``cnt`` and ``vals<j>`` (the state until PR 45) do not restore
+    into the record table: ``restore`` says so, it does not guess."""
+    cql = _query(4, having="having n >= 1", purge=PURGE)
+    cols, ts = _churn(19, n_keys=5, span_s=60)
+
+    def job():
+        plan = compile_plan(cql, {"S": SCHEMA}, plan_id="stops",
+                            config=EngineConfig(hop_group_slots=512))
+        batches = iter([EventBatch("S", SCHEMA, cols, ts)])
+        return Job([plan], [BatchSource("S", SCHEMA, batches)],
+                   batch_size=len(ts), time_mode="processing"), plan
+
+    first, plan = job()
+    first.run()
+    snap = first.snapshot()
+    name = plan.artifacts[0].name
+    snap["plans"]["stops"]["states"][name] = {
+        "enabled": np.asarray(True), "cnt": np.zeros(512, np.int32),
+        "vals0": np.zeros(4 * 512, np.int32),
+        "vals1": np.zeros(4 * 512, np.int32)}
+    with pytest.raises(ValueError, match="does not match the running plan"):
+        job()[0].restore(snap)
+
+
+# -- the record a slot ---------------------------------------------------------
+def _artifact_and_state(job):
+    rt, = job._plans.values()
+    art, = rt.plan.artifacts
+    return art, rt.states[art.name]
+
+
+def _one_slot_cql(length, args=("v", "w")):
+    aggs = ", ".join(f"min({a}) as {a}lo, max({a}) as {a}hi" for a in args)
+    return (f"partition with (k of S) begin from S#window.length({length}) "
+            f"select k, count() as n, {aggs} insert into o; end")
+
+
+def _bursts(seed, n=1_500, batch=64):
+    """Key 1 comes in bursts longer than any window here within one
+    batch, key 2 exactly once a batch, the rest fill in."""
+    rng = np.random.default_rng(seed)
+    k = rng.integers(3, 12, n)
+    at = np.arange(n) % batch
+    k[(at >= 5) & (at < 5 + 11)] = 1
+    k[at == 40] = 2
+    quarter = rng.integers(-8, 9, n) / 4.0  # exact in float32
+    cols = {
+        "t": np.zeros(n, np.int32), "k": k.astype(np.int64),
+        "v": rng.integers(-50, 50, n).astype(np.int32),
+        "w": rng.integers(-3, 3, n).astype(np.int32),
+        "x": np.where(quarter == 0, -0.0, quarter),
+    }
+    return cols, 1_000 + np.arange(n, dtype=np.int64) * 100
+
+
+# 1 + C * A words padded to a power of two: (C, arguments) -> (W, R)
+WIDTHS = {
+    (1, ("v",)): (2, 64), (3, ("v",)): (4, 32), (7, ("v",)): (8, 16),
+    (2, ("v",)): (4, 32), (4, ("v",)): (8, 16), (5, ("v",)): (8, 16),
+    (2, ("v", "w")): (8, 16), (4, ("v", "w")): (16, 8),
+    (5, ("v", "w")): (16, 8), (5, ("v", "w", "x")): (16, 8),
+    (64, ("v", "x")): (256, 1),
+}
+
+
+@pytest.mark.parametrize(
+    "length, args", sorted(WIDTHS),
+    ids=[f"C{c}-{'+'.join(a)}" for c, a in sorted(WIDTHS)])
+def test_a_record_of_any_width_equals_the_interpreter(length, args):
+    """A key with more events in one batch than its window holds, one
+    with exactly one, widths that fill their padding and widths that do
+    not, a record wider than a row."""
+    cql = _one_slot_cql(length, args)
+    cols, ts = _bursts(length * 10 + len(args))
+    got, job = _run(cql, cols, ts, 64, cast=lambda x: x, hop_group_slots=100)
+    assert got == _interpreted(cql, cols, ts)
+    art, state = _artifact_and_state(job)
+    W, R = WIDTHS[length, args]
+    assert art._record() == (W, R) and W >= 1 + length * len(args)
+    assert set(state) == {"enabled", "rec"}
+    rows = -(-100 // R)  # whole rows for the 100 slots asked for
+    assert state["rec"].shape == (rows, R * W)
+    assert state["rec"].dtype == np.int32
+
+
+@pytest.mark.parametrize("path", ["per_batch", "fused"])
+def test_the_table_is_read_a_block_of_tape_rows_at_a_time(monkeypatch, path):
+    """Eight blocks of 128 rows a tape, the last ones without an event
+    (a batch is 600 events and the filter drops a third): those are not
+    read at all."""
+    from flink_siddhi_tpu.compiler import window
+
+    monkeypatch.setattr(window, "_PERKEY_BLOCK", 128)
+    cql = _query(4, filt="[t == 0]", having="having n >= 2")
+    cols, ts = _stream(29, 6_000, 300)
+    got, job = _run(cql, cols, ts, 600, path)
+    assert got == _interpreted(cql, cols, ts)
+    tape_rows = {rt.tape_capacity for rt in job._plans.values()}
+    assert tape_rows == {1_024}
+
+
+def test_a_float_rides_in_its_record_as_its_bits():
+    """``min`` / ``max`` of a double beside an int: negative values, and
+    a key that only ever sends -0.0 gets -0.0 back, sign and all."""
+    cql = _one_slot_cql(3, ("x", "v"))
+    cols, ts = _bursts(23)
+    cols["x"][cols["k"] == 2] = -0.0
+    got, job = _run(cql, cols, ts, 64, cast=lambda x: x)
+    want = _interpreted(cql, cols, ts)
+    assert got == want
+    zeros = [r for r in got if r[1] == 2]
+    assert len(zeros) > 20
+    assert all(np.signbit(r[3]) and np.signbit(r[4]) for r in zeros)
+    assert min(r[3] for r in got) < 0 < max(r[4] for r in got)
+    art, state = _artifact_and_state(job)
+    W, _R = art._record()
+    slot = int(art.encoder.intern_rows(
+        [np.asarray([2], np.int64)], np.ones(1, bool))[0])
+    record = np.asarray(state["rec"]).reshape(-1)[slot * W:(slot + 1) * W]
+    assert record[0] == len(zeros)
+    neg_zero = np.asarray([-0.0], np.float32).view(np.int32)[0]
+    assert record[1:4].tolist() == [neg_zero] * 3  # x's ring, bit for bit
+
+
+def _art_and_tape(cql, keys, v, fresh, slots=64, rows=128):
+    """The artifact of ``cql`` and one tape of ``rows`` rows (128: the
+    smallest bucket) with ``keys``' events, slot codes as the host would
+    intern them (``~slot`` where ``fresh``)."""
+    import jax.numpy as jnp
+
+    from flink_siddhi_tpu.runtime.tape import Tape
+
+    plan = compile_plan(cql, {"S": SCHEMA},
+                        config=EngineConfig(hop_group_slots=slots))
+    art = plan.artifacts[0]
+    n = len(keys)
+
+    def column(values, dtype=np.int32):
+        out = np.zeros(rows, dtype)
+        out[:n] = values
+        return jnp.asarray(out)
+
+    code = np.asarray(keys, np.int32)
+    cols = {f"S.{f}": column(0, SCHEMA.field_type(f).device_dtype)
+            for f in FIELDS}
+    cols["S.v"] = column(v)
+    cols[art.code_key] = column(np.where(fresh, ~code, code))
+    tape = Tape(ts=column(np.arange(n)), stream=column(0),
+                valid=column(True, bool), cols=cols)
+    return art, tape
+
+
+@pytest.mark.parametrize("fresh", [True, False], ids=["given_anew", "known"])
+def test_a_slot_given_anew_reads_nothing_its_record_held(fresh):
+    """A record full of another key's words (count 7, every ring word
+    -99): the key that is given the slot (``~slot``) starts at one and
+    sees its own values alone; a key the slot already knew goes on from
+    what is there."""
+    import jax.numpy as jnp
+
+    art, tape = _art_and_tape(
+        _one_slot_cql(4, ("v",)), [5, 9, 5], [10, 3, 20], fresh)
+    state = art.init_state()
+    W, _R = art._record()
+    words = np.zeros(state["rec"].size, np.int32)
+    words[5 * W:6 * W] = [7] + [-99] * (W - 1)
+    state["rec"] = jnp.asarray(words.reshape(state["rec"].shape))
+    new_state, (mask, _ts, out) = art.step(state, tape)
+    assert np.asarray(mask).tolist() == [True] * 3 + [False] * 125
+    rows = list(zip(*(np.asarray(c)[:3].tolist() for c in out)))
+    record = np.asarray(new_state["rec"]).reshape(-1)[5 * W:6 * W].tolist()
+    if fresh:
+        assert rows == [(0, 1, 10, 10), (0, 1, 3, 3), (0, 2, 10, 20)]
+        assert record[:3] == [2, 10, 20]
+    else:  # ordinals 7 and 8: ring positions 3 and 0
+        assert rows == [(0, 4, -99, 10), (0, 1, 3, 3), (0, 4, -99, 20)]
+        assert record[:5] == [9, 20, -99, -99, 10]
+
+
+@pytest.mark.parametrize("rows", [3, 64, 200])
+def test_a_tape_that_is_no_whole_tile_is_read_all_the_same(rows):
+    """Admission traces a plan on a tape of 64 rows; the read works in
+    tiles of 128."""
+    art, tape = _art_and_tape(
+        _one_slot_cql(2, ("v",)), [5, 9, 5], [10, 3, 20], True, rows=rows)
+    _state, (mask, _ts, out) = art.step(art.init_state(), tape)
+    assert int(np.asarray(mask).sum()) == 3
+    got = list(zip(*(np.asarray(c)[:3].tolist() for c in out)))
+    assert got == [(0, 1, 10, 10), (0, 1, 3, 3), (0, 2, 10, 20)]
+
+
+@pytest.mark.parametrize("slots", [64, 100, 1_000])
+def test_a_grown_table_keeps_every_record(slots):
+    """Re-bucketing appends whole rows: every slot's record stays at
+    ``slot * W`` of the flat view, the new slots read zero."""
+    import jax.numpy as jnp
+
+    art, _tape = _art_and_tape(_one_slot_cql(5), [0], [0], False, slots)
+    W, R = art._record()
+    state = art.init_state()
+    assert art.grow_state(state) is state
+    G = state["rec"].shape[0] * R
+    assert slots <= G < slots + R
+    marked = np.arange(G * W, dtype=np.int32).reshape(state["rec"].shape)
+    state["rec"] = jnp.asarray(marked)
+    art.encoder.intern_rows(
+        [np.arange(2 * slots + 1, dtype=np.int64)],
+        np.ones(2 * slots + 1, bool))
+    grown = art.grow_state(state)
+    flat = np.asarray(grown["rec"]).reshape(-1)
+    assert grown["rec"].shape[1] == R * W
+    assert grown["rec"].shape[0] * R >= 2 * slots + 1
+    assert (flat[:G * W] == np.arange(G * W)).all()
+    assert not flat[G * W:].any()
+    assert art.grow_state(grown) is grown
 
 
 # -- the encoder ---------------------------------------------------------------
