@@ -2,12 +2,12 @@
 
 PR 12's control plane made the engine genuinely concurrent — a REST
 service thread, the run-loop thread, the supervisor restart path, the
-prober's reader threads, the drain fetch worker and async staging all
-touch ``Job`` — but its core safety rule ("state mutates only via
-control events applied on the run-loop thread") was a convention. Two
-shipped bugs were exactly this class: the PR 7 ApiVersions backoff
-sleeping under the client lock, and the restore-aliasing race the
-fault tests caught. This pass makes the convention machine-checked.
+drain fetch worker and async staging all touch ``Job`` — but its core
+safety rule ("state mutates only via control events applied on the
+run-loop thread") was a convention. Two shipped bugs were exactly this
+class: the PR 7 ApiVersions backoff sleeping under the client lock, and
+the restore-aliasing race the fault tests caught. This pass makes the
+convention machine-checked.
 
 Four rules (registry: findings.py; reference: docs/static_analysis.md):
 

@@ -72,8 +72,6 @@ class CachedExecutables:
     trace-counter cell the retrace tests read — reuse means the counter
     does NOT advance."""
 
-    jitted: Callable
-    jitted_acc: Callable
     jitted_seg: Callable
     jitted_init_acc: Callable
     jitted_flush: Callable

@@ -18,9 +18,9 @@ execution model. Three pieces live here:
 * :class:`ControlPlane` — the programmatic facade over a running
   ``Job`` + ``ControlQueueSource``: ``admit`` / ``retire`` /
   ``set_enabled`` / ``status``. Mutations ride control events and take
-  effect at epoch boundaries (micro-batch in streaming, segment in
-  fused mode, replay-epoch in resident mode — docs/control_plane.md has
-  the exact contract per mode).
+  effect at epoch boundaries (micro-batch in streaming, on a dispatched
+  segment; replay-epoch in resident mode — docs/control_plane.md has the
+  exact contract per mode).
 * re-exports of the AOT executable cache (``aotcache.py``) the
   ``Job`` uses so a shape class's first-compile cost is paid once.
 

@@ -55,8 +55,6 @@ _LOG = logging.getLogger(__name__)
 # fields holding jit wrappers); drain pack programs ride separately as
 # pack@<width> slots
 SLOT_NAMES = (
-    "jitted",
-    "jitted_acc",
     "jitted_seg",
     "jitted_init_acc",
     "jitted_flush",

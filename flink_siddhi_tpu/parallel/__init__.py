@@ -19,12 +19,11 @@ real TPU chips; on one chip the same program runs with a 1-device mesh.
 
 from .mesh import make_cep_mesh, SHARD_AXIS
 from .router import Router
-from .sharded import ShardedJob, make_sharded_step
+from .sharded import ShardedJob
 
 __all__ = [
     "make_cep_mesh",
     "SHARD_AXIS",
     "Router",
     "ShardedJob",
-    "make_sharded_step",
 ]

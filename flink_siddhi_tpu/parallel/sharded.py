@@ -53,8 +53,14 @@ def _shapes(tree) -> List[Tuple]:
     return [np.shape(leaf) for leaf in jax.tree.leaves(tree)]
 
 
-def make_sharded_step(plan: CompiledPlan, mesh) -> callable:
-    """jit(shard_map(plan.step)) over the ``shards`` mesh axis.
+def make_sharded_step_acc(
+    plan: CompiledPlan, mesh, jitted: bool = True
+) -> callable:
+    """jit(shard_map(plan.step_acc)): each shard appends its emissions to
+    its own on-device accumulator — the hot loop never fetches (same
+    contract as the single-device executor). ``jitted=False`` returns
+    the bare shard_map'd callable for callers that embed it in a larger
+    program (the sharded bounded-replay scan).
 
     Inside the shard body every leaf carries a leading local shard dim of 1,
     stripped before the single-shard step and restored after, so the
@@ -65,36 +71,6 @@ def make_sharded_step(plan: CompiledPlan, mesh) -> callable:
     # uses the same fused kernel as the single-device step, and a kernel
     # that does not survive the shard_map lowering raises here
     pallas_ops.warmup_shard()
-
-    def local(states, tape):
-        states = jax.tree.map(lambda x: x[0], states)
-        tape = jax.tree.map(lambda x: x[0], tape)
-        new_states, outputs = plan.step(states, tape, SHARD_AXIS)
-        expand = lambda t: jax.tree.map(lambda x: jnp.asarray(x)[None], t)
-        return expand(new_states), expand(outputs)
-
-    smapped = jax.shard_map(
-        local,
-        mesh=mesh,
-        in_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
-        out_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
-        # no collectives in the per-shard body; vma checking would also
-        # reject the pallas kernel's un-annotated out_shape
-        check_vma=False,
-    )
-    return jax.jit(smapped)
-
-
-def make_sharded_step_acc(
-    plan: CompiledPlan, mesh, jitted: bool = True
-) -> callable:
-    """jit(shard_map(plan.step_acc)): each shard appends its emissions to
-    its own on-device accumulator — the hot loop never fetches (same
-    contract as the single-device executor). ``jitted=False`` returns
-    the bare shard_map'd callable for callers that embed it in a larger
-    program (the sharded bounded-replay scan)."""
-
-    pallas_ops.warmup_shard()  # as make_sharded_step
 
     def local(states, acc, tape):
         states = jax.tree.map(lambda x: x[0], states)
@@ -111,6 +87,8 @@ def make_sharded_step_acc(
         mesh=mesh,
         in_specs=(P(SHARD_AXIS), P(SHARD_AXIS), P(SHARD_AXIS)),
         out_specs=(P(SHARD_AXIS), P(SHARD_AXIS)),
+        # no collectives in the per-shard body; vma checking would also
+        # reject the pallas kernel's un-annotated out_shape
         check_vma=False,
     )
     if not jitted:
@@ -198,7 +176,6 @@ class ShardedJob(Job):
         self._plans[plan.plan_id] = _PlanRuntime(
             plan=plan,
             states=stacked,
-            jitted=make_sharded_step(plan, self.mesh),
             jitted_acc=make_sharded_step_acc(plan, self.mesh),
             jitted_init_acc=init_acc,
             acc=init_acc(),

@@ -15,6 +15,7 @@ AbstractSiddhiOperator.java:274-278,209-247) re-shaped for an accelerator:
 from __future__ import annotations
 
 import contextlib
+import ctypes
 import heapq
 import logging
 import time
@@ -45,8 +46,8 @@ from .tape import bucket_size, build_wire_tape
 # in the steady-state loop — a numpy array silently riding a jit call
 # where the design says "one explicit async device_put per segment" —
 # fails loudly instead of costing a synchronous round trip per batch.
-# The per-batch path's intended staging transfer is re-allowed at its
-# one call site via _staging_allow() (docs/static_analysis.md).
+# The host's re-bucketing after group growth is re-allowed at its one
+# call site via _staging_allow() (docs/static_analysis.md).
 HOTLOOP_TRANSFER_GUARD = False
 
 
@@ -56,11 +57,24 @@ def _hotloop_guard():
     return contextlib.nullcontext()
 
 
+def _keep_heap_warm() -> None:
+    """glibc serves the process's large temporaries (2-30 MB: a column
+    of a batch or a delivery) from a heap it keeps, not from a fresh
+    mapping each, faulted in a page per 4 KB and handed back at free:
+    3/4 of a delivery's emission tail, 1/4 of a tape's build (PERF.md
+    §6, PR 48). Process-wide, idempotent; without glibc, nothing."""
+    with contextlib.suppress(OSError, AttributeError):
+        mallopt = ctypes.CDLL("libc.so.6").mallopt
+        mallopt(-3, 32 << 20)  # M_MMAP_THRESHOLD: its maximum, and fixed
+        mallopt(-1, (1 << 31) - 1)  # M_TRIM_THRESHOLD: hand nothing back
+        mallopt(-2, 64 << 20)  # M_TOP_PAD: the heap grows 64 MB a time
+
+
 def _staging_allow():
-    """The legitimate staging transfers (per-batch wire tapes riding
-    the jit call, host re-bucketing after group growth) — explicitly
-    allowed inside the guarded hot loop, so the guard's findings are
-    always contract violations, never the design's own uploads."""
+    """The legitimate staging transfer (host re-bucketing after group
+    growth) — explicitly allowed inside the guarded hot loop, so the
+    guard's findings are always contract violations, never the
+    design's own uploads."""
     if HOTLOOP_TRANSFER_GUARD:
         return jax.transfer_guard("allow")
     return contextlib.nullcontext()
@@ -146,13 +160,12 @@ def _empty_wire_like(wire):
 class _PlanRuntime:
     plan: CompiledPlan
     states: Dict
-    jitted: Callable  # plan.step (kept for direct/step callers)
-    jitted_acc: Callable = None  # plan.step_acc — the hot loop entry
-    # fused streaming dispatch: a lax.scan of K stacked micro-batch
-    # tapes per device call (the replay's segment shape, fed live).
-    # seg_pending holds staged-but-undispatched device tapes; the scan
-    # keeps jitted_acc's donation semantics (states + acc donated, the
-    # scan carry updates them in place)
+    # the mesh's step alone: ShardedJob fills it with make_sharded_step_acc
+    # and calls it once a cycle (parallel/sharded.py); Job leaves it None
+    jitted_acc: Callable = None
+    # the hot loop's entry: a lax.scan of K stacked micro-batch tapes per
+    # device call (the replay's segment shape, fed live). seg_pending holds
+    # staged-but-undispatched tapes; states + acc are donated (the carry)
     jitted_seg: Callable = None
     seg_pending: List = field(default_factory=list)
     jitted_init_acc: Callable = None  # cached: zeroing program compiles once
@@ -558,6 +571,7 @@ class Job:
     ) -> None:
         if time_mode not in ("event", "processing"):
             raise ValueError(time_mode)
+        _keep_heap_warm()
         self.batch_size = batch_size
         self.time_mode = time_mode
         self.retain_results = retain_results
@@ -718,28 +732,17 @@ class Job:
         # bounded by ~max_inflight_cycles * device_cycle_time + drain
         # interval, and the device stays fed as long as it is >= 2.
         self.max_inflight_cycles = 6
-        # fused streaming dispatch: collapse the per-micro-batch
-        # dispatch chain into one lax.scan-of-K-tapes device call (the
-        # bounded replay's segment shape, fed live). None/1 = the
-        # historical one-dispatch-per-batch loop. Tapes stage host-side
-        # while a segment fills; at dispatch the stacked segment
-        # crosses H2D in ONE async jax.device_put, issued while the
-        # PREVIOUS segment's compute is still in flight (the ticket
-        # window keeps >= 2 segments outstanding) — double-buffered
-        # ingest; the fusion.* counters and the stage.h2d_overlap span
-        # prove the overlap. Drains fire between segments; checkpoints
-        # force-dispatch the pending partial segment first, so state
-        # capture always lands on a segment boundary.
+        # how many micro-batches make a dispatch (_fused_k is the one
+        # reader; None, 0 and 1: one): every batch leaves as an entry of
+        # a segment, one lax.scan-of-K-tapes device call (the bounded
+        # replay's segment shape, fed live). Tapes stage host-side while
+        # it fills; at dispatch the stacked segment crosses H2D in ONE
+        # async jax.device_put, issued while the PREVIOUS segment still
+        # computes (the ticket window keeps >= 2 outstanding; fusion.*
+        # counters and span stage.h2d_overlap prove the overlap). Drains
+        # fire between segments; checkpoints dispatch the pending partial
+        # segment first, so state capture lands on a segment boundary.
         self.fused_segment_len: Optional[int] = None
-        # adaptive depth: when set, max_inflight_cycles tracks the
-        # measured cycle pace so queued device work stays within about
-        # half the latency target (the other half is drain staleness +
-        # fetch time). None = fixed depth.
-        self.target_p99_ms: Optional[float] = None
-        # fst:ephemeral adaptive-depth pace estimate; re-measured from scratch after restore
-        self._cycle_ema: Optional[float] = None
-        # fst:ephemeral monotonic-clock stamp backing the pace estimate above
-        self._last_cycle_t: Optional[float] = None
         # per-plan capacity-check cadence (recomputed as plans come and go)
         self._drain_hints: Dict[str, int] = {}
         # telemetry: stage-attributed wall clock + latency histograms +
@@ -1265,17 +1268,12 @@ class Job:
             traces = {"n": 0}
 
             # fst:hotpath
-            def step_wire(states, acc, wire):
-                traces["n"] += 1  # python body runs only while TRACING
-                return plan.step_acc(states, acc, wire.expand())
-
-            # fst:hotpath
             def seg_scan(states, acc, seg):
-                # the fused streaming dispatch: ONE device call advances
-                # K stacked micro-batches — the exact scan body the
-                # bounded replay proves row-identical
-                # (runtime/replay.py), fed from live tapes instead of a
-                # pre-staged stream
+                # ONE device call advances K stacked micro-batches — the
+                # scan body the bounded replay proves row-identical
+                # (runtime/replay.py), fed from live tapes
+                traces["n"] += 1  # python body runs only while TRACING
+
                 def body(carry, wire):
                     s, a = plan.step_acc(
                         carry[0], carry[1], wire.expand()
@@ -1288,15 +1286,9 @@ class Job:
                 return states, acc
 
             entry = CachedExecutables(
-                jitted=jax.jit(plan.step),
-                # donate states + accumulator: XLA updates the
-                # (potentially 100s-of-MB) output buffer in place
-                # instead of copying it every micro-batch
-                jitted_acc=jax.jit(step_wire, donate_argnums=(0, 1)),
-                # donation survives the scan carry: states + acc thread
-                # through as the carry and come back as the only
-                # outputs, so XLA updates both in place across the
-                # whole segment
+                # states + accumulator are donated: the scan's carry and
+                # its only outputs, so XLA updates both (the output buffer
+                # may be 100s of MB) in place across the whole segment
                 jitted_seg=jax.jit(seg_scan, donate_argnums=(0, 1)),
                 jitted_init_acc=init_acc,
                 jitted_flush=jax.jit(plan.flush),
@@ -1319,8 +1311,6 @@ class Job:
         rt = _PlanRuntime(
             plan=plan,
             states=plan.init_state(),
-            jitted=entry.jitted,
-            jitted_acc=entry.jitted_acc,
             jitted_seg=entry.jitted_seg,
             jitted_init_acc=entry.jitted_init_acc,
             jitted_flush=entry.jitted_flush,
@@ -1387,8 +1377,8 @@ class Job:
         self, host_id: str, plan: CompiledPlan, slot: int, t
     ) -> None:
         rt = self._plans[host_id]
-        # fused mode: tapes staged before this add must step WITHOUT
-        # the new member (same boundary contract as set_plan_enabled)
+        # tapes staged before this add must step WITHOUT the new
+        # member (same boundary contract as set_plan_enabled)
         self._dispatch_segment(rt)
         group = rt.plan.artifacts[0]
         tpl, params, within = t
@@ -1892,10 +1882,9 @@ class Job:
             host_id, slot = folded
             rt = self._plans.get(host_id)
             if rt is not None:
-                # fused mode: events staged before this control event
-                # must step under the OLD member state (control takes
-                # effect at the next boundary, as in the per-batch
-                # loop) — dispatch the pending segment before mutating
+                # events staged before this control event must step under
+                # the OLD member state (control takes effect at the next
+                # boundary): dispatch the pending segment before mutating
                 self._dispatch_segment(rt)
                 group = rt.plan.artifacts[0]
                 states = dict(rt.states)
@@ -1908,8 +1897,7 @@ class Job:
         if rt is not None:
             if not enabled:
                 # events staged while the plan was enabled still step
-                # (control takes effect at the NEXT boundary, as in the
-                # per-batch loop)
+                # (control takes effect at the NEXT boundary)
                 self._dispatch_segment(rt)
             rt.enabled = enabled
 
@@ -2241,8 +2229,6 @@ class Job:
         # than the first run's (same contract as the limiter reset)
         self._cycles_since_drain = 0
         self._last_full_drain = time.monotonic()
-        self._last_cycle_t = None
-        self._cycle_ema = None
         # event-time gate phase: a rerun replays the SAME stream, so a
         # carried released horizon would classify every row late
         self._released_wm = MIN_WM
@@ -2390,10 +2376,10 @@ class Job:
         meta/data transfers overlap with subsequent device cycles, to be
         decoded by a later poll (run_cycle) or a waiting drain."""
         for rt in self._plans.values():
-            # fused mode: staged-but-undispatched tapes must reach the
-            # device before a drain whose caller will read state or
-            # rows (results/snapshot/checkpoint) — this is what makes
-            # every checkpoint land on a segment boundary
+            # staged-but-undispatched tapes must reach the device
+            # before a drain whose caller will read state or rows
+            # (results/snapshot/checkpoint) — this is what makes every
+            # checkpoint land on a segment boundary
             self._dispatch_segment(rt)
         with self.telemetry.span("drain"):
             for rt in list(self._plans.values()):
@@ -2402,7 +2388,7 @@ class Job:
         if self._loopback and wait:
             # shared-prefix fan-out: host drains above may have stepped
             # loopback rows into member suffixes AFTER those suffixes'
-            # own drain passed (and, fused, staged without dispatch) —
+            # own drain passed (and staged without dispatch) —
             # a synchronous drain must settle them too, or snapshot()/
             # results() would miss rows the host already produced.
             # Hosts precede members in insertion order, so one extra
@@ -3128,73 +3114,34 @@ class Job:
                 if rt.enabled:
                     self._step_plan(rt, ready)
             self._cycles_since_drain += 1
-            # adaptive in-flight depth: the wall time between working
-            # cycles tracks the device pace once the ticket window is
-            # full, so depth * pace ~= queued latency
-            t_now = time.monotonic()
-            if self._last_cycle_t is not None:
-                dt = t_now - self._last_cycle_t
-                self._cycle_ema = (
-                    dt
-                    if self._cycle_ema is None
-                    else 0.8 * self._cycle_ema + 0.2 * dt
-                )
-                if self.target_p99_ms:
-                    budget_s = self.target_p99_ms / 2000.0
-                    # depth 1 is legitimate under a latency target when
-                    # a single cycle already eats the budget (a paced
-                    # load doesn't need pipelining to stay fed). Under
-                    # fused dispatch each ticket holds a whole
-                    # K-batch segment while the EMA tracks per-CYCLE
-                    # (per-batch) pace, so the queued-work estimate
-                    # scales by K — without it the window admits ~K x
-                    # the intended device backlog
-                    k_seg = (
-                        self.fused_segment_len
-                        if self.fused_segment_len
-                        and self.fused_segment_len > 1
-                        else 1
-                    )
-                    self.max_inflight_cycles = max(
-                        1,
-                        min(
-                            8,
-                            int(
-                                budget_s
-                                / max(self._cycle_ema * k_seg, 1e-3)
-                            ),
-                        ),
-                    )
-            self._last_cycle_t = t_now
         # advance any in-flight drain fetches (never blocks the host)
         with tel.span("drain"):
             for rt in self._plans.values():
                 self._drain_poll(rt)
-        if self.fused_segment_len and self.fused_segment_len > 1:
-            # a partial segment must not wait forever for a slow source
-            # to fill it: once its oldest staged tape reaches the drain
-            # staleness budget, dispatch short — visibility latency
-            # stays bounded by ~interval + drain time, fused or not.
-            # (`is None` check, not `or`: drain_interval_ms=0 means
-            # "tightest visibility", which must not round up to 500ms).
-            # Not while the ticket window is full: the run loop would
-            # wait at that dispatch for the device, staging nothing, and
-            # the segment would go out a batch or two short for good (at
-            # 0.24 s a batch four tapes take 0.4-0.5 s to stage: three
-            # tapes and a padding tape cost the device 0.85 s, the
-            # fourth and three paddings 0.64 s more); left open it fills
-            # while the device works
-            age_s = (
-                500.0
-                if self.drain_interval_ms is None
-                else self.drain_interval_ms
-            ) / 1e3
-            now0 = time.monotonic()
-            for rt in self._plans.values():
-                if rt.seg_pending and (
-                    now0 - rt.seg_pending[0]["t"] >= age_s
-                ) and not self._window_full(rt):
-                    self._dispatch_segment(rt)
+        # a partial segment must not wait forever for a slow source to
+        # fill it: once its oldest staged tape reaches the drain
+        # staleness budget, dispatch short — visibility latency stays
+        # bounded by ~interval + drain time at any segment length (a
+        # segment of one leaves nothing staged). (`is None` check, not
+        # `or`: drain_interval_ms=0 means "tightest visibility", which
+        # must not round up to 500ms). Not while the ticket window is
+        # full: the run loop would wait at that dispatch for the device,
+        # staging nothing, and the segment would go out a batch or two
+        # short for good (at 0.24 s a batch four tapes take 0.4-0.5 s to
+        # stage: three tapes and a padding tape cost the device 0.85 s,
+        # the fourth and three paddings 0.64 s more); left open it fills
+        # while the device works
+        age_s = (
+            500.0
+            if self.drain_interval_ms is None
+            else self.drain_interval_ms
+        ) / 1e3
+        now0 = time.monotonic()
+        for rt in self._plans.values():
+            if rt.seg_pending and (
+                now0 - rt.seg_pending[0]["t"] >= age_s
+            ) and not self._window_full(rt):
+                self._dispatch_segment(rt)
         now = time.monotonic()
         if self.drain_interval_ms is not None:
             interval_s = self.drain_interval_ms / 1e3
@@ -3754,7 +3701,7 @@ class Job:
         self, rt: _PlanRuntime, ready: List[EventBatch]
     ) -> None:
         for involved in self._plan_windows(rt, ready):
-            self._step_plan_window(rt, involved)
+            self._stage_fused(rt, involved)
 
     def _stage_tape(
         self, rt: _PlanRuntime, involved: List[EventBatch]
@@ -3909,14 +3856,14 @@ class Job:
         if plan.grow_count != grown:
             self.telemetry.inc("groups.regrow", plan.grow_count - grown)
 
-    # -- fused streaming dispatch (scan-of-microbatches segments) ----------
+    # -- streaming dispatch (scan-of-microbatches segments) ----------------
     def _fused_k(self, rt: _PlanRuntime) -> int:
-        """Effective segment length for this plan: the configured K,
-        clamped so the accumulator can hold a whole segment's
-        emissions (there is no mid-segment drain — the same bound the
-        bounded replay applies via the drain hint)."""
+        """How many batches make a dispatch of this plan: the configured
+        K (None, 0 and 1: one), clamped so the accumulator can hold a
+        whole segment's emissions (there is no mid-segment drain — the
+        bound the bounded replay applies via the drain hint)."""
         k = self.fused_segment_len
-        if not k or k <= 1 or rt.acc is None or not rt.plan.artifacts:
+        if not k or k <= 1:
             return 1
         hint = self._drain_hints.get(rt.plan.plan_id)
         if hint:
@@ -4019,9 +3966,8 @@ class Job:
                 # histogram reports ~1x
                 rt.dirty_since = pending[0]["t"]
             if tel.enabled:
-                # per-segment enqueue time (host side of the dispatch;
-                # the device wall hides behind the ticket), booked
-                # by the per-batch path too (_step_plan_window)
+                # per-segment enqueue time, the dispatch's host side (the
+                # device wall hides behind the ticket: leg.device, legs.py)
                 tel.record_seconds(
                     "dispatch.enqueue", time.monotonic() - t0
                 )
@@ -4146,62 +4092,6 @@ class Job:
             # which the queue cannot run dry (starved.backpressure_wait
             # is 0 by construction)
             clock.watch(ticket, rec)
-
-    def _step_plan_window(
-        self, rt: _PlanRuntime, involved: List[EventBatch]
-    ) -> None:
-        if self.fused_segment_len and self.fused_segment_len > 1 and (
-            rt.acc is not None and rt.plan.artifacts
-        ):
-            self._stage_fused(rt, involved)
-            return
-        plan = rt.plan
-        tape = self._stage_tape(rt, involved)
-        tel = self.telemetry
-        # a segment of one batch: same record, same legs (fill ~ 0)
-        staged = time.monotonic()
-        rec, seg_id = self._open_segment(
-            rt, [self._arrival_of(involved, staged)], [staged],
-            [sum(len(b) for b in involved)],
-        )
-        # host interning may have discovered new group keys: re-bucket
-        # state tables before the jit call (shape change -> one-off
-        # retrace; host-driven re-bucketing = staging-class work)
-        with _staging_allow():
-            self._grow_states(rt)
-        with self._compile_scope(rt), tel.span("dispatch", seg=seg_id):
-            t0 = time.monotonic()
-            # NO device->host fetch here: emissions append to the
-            # on-device accumulator and are drained in bulk
-            # (flush/results/periodic check). The wire tape riding the
-            # jit call IS the per-batch path's staging upload — the one
-            # implicit H2D the hot-loop transfer guard permits
-            self._issue_step()
-            with _staging_allow():
-                rt.states, rt.acc = rt.jitted_acc(
-                    rt.states, rt.acc, tape
-                )
-            rt.acc_dirty = True
-            if rt.dirty_since is None:
-                rt.dirty_since = time.monotonic()
-            self._count_merges(rt)
-            if tel.enabled:
-                # host-side enqueue time of one dispatch (the device
-                # wall hides behind the ticket: leg.device carries
-                # it, telemetry/legs.py)
-                tel.record_seconds(
-                    "dispatch.enqueue", time.monotonic() - t0
-                )
-        # outside the compile-attribution scope (see _ticket_window)
-        self._ticket_window(rt, rec, seg_id)
-        self._update_drain_hint(
-            plan, tape.capacity, lambda name: rt.states.get(name)
-        )
-        if plan.has_flush and (
-            rt.flush_warm is None
-            or rt.flush_warm[0] != self._state_sig(rt.states)
-        ):
-            self._warm_flush(rt)
 
     def _update_drain_hint(self, plan, tape_capacity, state_of) -> None:
         """Capacity-bounding swap cadence: each artifact declares its

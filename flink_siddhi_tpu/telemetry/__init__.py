@@ -17,7 +17,6 @@ the measured overhead on headline replay throughput is <2%
 from .flightrec import FlightRecorder
 from .histogram import LatencyHistogram
 from .openmetrics import render_openmetrics
-from .prober import ProbeReport, SideChannelProber
 from .registry import Counter, MetricsRegistry
 from .slo import SLOPolicy, SLOWatchdog
 from .spans import NULL_SPAN, StageTimes
@@ -70,10 +69,8 @@ __all__ = [
     "LatencyHistogram",
     "MetricsRegistry",
     "NULL_SPAN",
-    "ProbeReport",
     "SLOPolicy",
     "SLOWatchdog",
-    "SideChannelProber",
     "StageTimes",
     "TOP_LEVEL_STAGES",
     "TraceSampler",

@@ -112,8 +112,8 @@ def _compile_events_surface():
 # guard (runtime/executor.py HOTLOOP_TRANSFER_GUARD): an IMPLICIT
 # host<->device transfer inside run_cycle — a numpy array silently
 # riding a jit call where the design says "one explicit async
-# device_put per segment" — fails loudly. The per-batch path's
-# intended staging upload is re-allowed at its one call site
+# device_put per segment" — fails loudly. The host's re-bucketing
+# after group growth is re-allowed at its one call site
 # (_staging_allow); everything else the guard catches is a regression
 # of the staging contract (docs/static_analysis.md). Scoped to the
 # hot loop, not the whole test: plan compilation legitimately builds
@@ -147,7 +147,6 @@ _OWNERSHIP_GUARD_FILES = {
     "test_control_e2e.py",
     "test_app.py",
     "test_faults.py",
-    "test_prober.py",
 }
 
 

@@ -50,6 +50,30 @@ from flink_siddhi_tpu.schema.types import AttributeType
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+
+# XLA's CPU backend cannot serialize again an executable that it loaded
+# from the persistent compilation cache: the copy loads and then fails
+# at its first result ("Function wrapped_iota not found"). The suite's
+# cache (tests/conftest.py) keeps what took two seconds to compile, so
+# under a loaded run a program of this file would cross that line, be
+# kept, and break the store's copy of it in the next process or test.
+# The store is the tier under test here: it gets compiled executables.
+_NO_XLA_CACHE = {"JAX_ENABLE_COMPILATION_CACHE": "0"}
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_compile_cache():
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
 SCHEMA = StreamSchema([
     ("id", AttributeType.INT),
     ("price", AttributeType.DOUBLE),
@@ -128,7 +152,7 @@ def test_warm_slot_falls_back_to_wrapper_on_broken_executable(
         def __call__(self, *a):
             raise TypeError("wrong aval")
 
-    slot = WarmSlot(wrapper, store, ("dyn", "sig"), "jitted")
+    slot = WarmSlot(wrapper, store, ("dyn", "sig"), "jitted_seg")
     sig = aval_signature((3,))
     slot.adopt(sig, Broken())
     assert slot(3) == 4
@@ -146,7 +170,7 @@ def _seed_store_entry(store, name, sig, nbytes, age_s):
 
     kd = os.path.join(store._dir, name)
     os.makedirs(kd, exist_ok=True)
-    path = os.path.join(kd, f"jitted@{sig}.exe")
+    path = os.path.join(kd, f"jitted_seg@{sig}.exe")
     with open(path, "wb") as f:
         pickle.dump((b"x" * nbytes, None, None), f)
     t = time.time() - age_s
@@ -189,7 +213,7 @@ def test_warm_store_gc_sweeps_corrupt_and_torn_entries(tmp_path):
     keep = _seed_store_entry(store, "k-good", "s1", 100, 100)
     bad = os.path.join(store._dir, "k-bad")
     os.makedirs(bad)
-    with open(os.path.join(bad, "jitted@sX.exe"), "wb") as f:
+    with open(os.path.join(bad, "jitted_seg@sX.exe"), "wb") as f:
         f.write(b"\x00not-a-pickle")
     with open(keep + ".tmp-99999", "wb") as f:
         f.write(b"torn write")
@@ -226,11 +250,11 @@ def test_warm_store_gc_evicted_key_recompiles_as_cold_miss(tmp_path):
 
     store = WarmStartStore(str(tmp_path))
     wrapper = jax.jit(lambda x: x + 1)
-    slot = WarmSlot(wrapper, store, ("dyn", "sig-gc"), "jitted")
+    slot = WarmSlot(wrapper, store, ("dyn", "sig-gc"), "jitted_seg")
     assert slot(3) == 4  # cold miss, compiles via wrapper
     out = store.gc(max_entries=0)
     assert store.stats()["evictions"] == out["evicted"]
-    slot2 = WarmSlot(wrapper, store, ("dyn", "sig-gc"), "jitted")
+    slot2 = WarmSlot(wrapper, store, ("dyn", "sig-gc"), "jitted_seg")
     assert slot2(3) == 4
     assert store.stats()["misses"] >= 2  # second cold miss, not a hit
 
@@ -448,6 +472,14 @@ def test_standalone_dynamic_plan_restores_warm_from_store(tmp_path, cql):
     serialize an executable whose sort has run, so the store serializes
     each as it is compiled (warmstore.py ``WarmSlot._compile``) and
     nothing here is missed."""
+    _persist_and_resume_warm(tmp_path, cql)
+
+
+def _persist_and_resume_warm(tmp_path, cql, between=lambda store: None):
+    """Admit ``cql``, persist its executables and a checkpoint, let
+    ``between`` at the store as process A left it, then restore a
+    replica from both: no miss, no error, the cold replica's rows.
+    Returns the replica's job and store."""
     store_dir = str(tmp_path / "store")
     src, ctrl = CallbackSource("S", SCHEMA), ControlQueueSource()
     job = _make_job(src, ctrl, WarmStartStore(store_dir))
@@ -477,6 +509,7 @@ def test_standalone_dynamic_plan_restores_warm_from_store(tmp_path, cql):
     want = [tuple(r) for r in job_cold.results("out")]
     assert want
 
+    between(job.warm_store)
     store2 = WarmStartStore(store_dir)
     src2, job2 = resume(store2)
     rt = job2._plans["flt0"]
@@ -492,6 +525,40 @@ def test_standalone_dynamic_plan_restores_warm_from_store(tmp_path, cql):
     # counted here and served by the jit wrapper instead
     assert store2.stats()["errors"] == 0
     assert [tuple(r) for r in job2.results("out")] == want
+    return job2, store2
+
+
+def test_a_store_with_the_retired_slots_files_loads_the_rest(tmp_path):
+    """A store written before ``jitted`` and ``jitted_acc`` left
+    ``SLOT_NAMES`` holds files of those names beside the rest. Nothing
+    opens them again (these two could not be unpickled: an error would
+    be counted) and the slots that stay load as before."""
+    from flink_siddhi_tpu.fleet.warmstore import SLOT_NAMES
+
+    strays = []
+
+    def parent_left_these(store):
+        (key_dir,) = [
+            os.path.join(store._dir, d) for d in os.listdir(store._dir)
+        ]
+        for slot in ("jitted", "jitted_acc"):
+            strays.append(os.path.join(key_dir, f"{slot}@0123abcd.exe"))
+            with open(strays[-1], "wb") as f:
+                f.write(b"not an executable")
+
+    job2, store2 = _persist_and_resume_warm(
+        tmp_path, "from S[id == 0] select id, price insert into out",
+        between=parent_left_these,
+    )
+    assert strays and all(os.path.exists(p) for p in strays)
+    assert SLOT_NAMES == ("jitted_seg", "jitted_init_acc", "jitted_flush")
+    # no miss (checked above), so what ran was loaded: the slots the
+    # replica's one cycle and drain call
+    entry = job2._plans["flt0"].warm_entry
+    loaded = {n for n in SLOT_NAMES if getattr(entry, n)._exes}
+    assert {"jitted_seg", "jitted_init_acc"} <= loaded
+    assert store2.stats()["hits"] >= len(loaded)
+    assert not hasattr(entry, "jitted") and not hasattr(entry, "jitted_acc")
 
 
 # -- the headline: cross-process zero-lowering warm start --------------------
@@ -581,7 +648,7 @@ print(json.dumps({{
 
 
 def _run_ab(tmp_path, mode):
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", **_NO_XLA_CACHE)
     out = subprocess.run(
         [sys.executable, "-c", _AB_SCRIPT.format(repo=REPO),
          str(tmp_path / "store"), str(tmp_path / "ckpt"), mode],
@@ -639,7 +706,7 @@ def _spawn_replica(root, slot, rid):
     path = os.path.join(root, f"spec-{rid}.json")
     with open(path, "w") as f:
         json.dump(spec, f)
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", **_NO_XLA_CACHE)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     proc = subprocess.Popen(
         [sys.executable, "-m", "flink_siddhi_tpu.fleet.replica",

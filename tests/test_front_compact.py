@@ -268,7 +268,7 @@ WINDOW_INT_MINMAX = (
 )
 
 
-@pytest.mark.parametrize("fused", [0, 3], ids=["unfused", "fused"])
+@pytest.mark.parametrize("fused", [0, 3], ids=["seg_of_1", "seg_of_3"])
 @pytest.mark.parametrize(
     "cql", [WINDOW, WINDOW_FILTERED, WINDOW_LONG, WINDOW_INT_MINMAX],
     ids=["unfiltered", "filtered", "longer_than_a_batch", "int_sum_min_max"],

@@ -216,7 +216,8 @@ def test_hotpath_allowlist_still_annotated():
     ):
         with open(os.path.join(REPO_ROOT, rel)) as fh:
             marked[rel] = fh.read().count("fst:hotpath")
-    assert marked["flink_siddhi_tpu/runtime/executor.py"] >= 3
+    # seg_scan and the drain's pack
+    assert marked["flink_siddhi_tpu/runtime/executor.py"] >= 2
     assert marked["flink_siddhi_tpu/runtime/replay.py"] >= 1
     assert marked["flink_siddhi_tpu/compiler/plan.py"] >= 4
     assert marked["flink_siddhi_tpu/compiler/nfa.py"] >= 5

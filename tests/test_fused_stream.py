@@ -1,16 +1,18 @@
-"""Fused scan-of-microbatches streaming dispatch: row-exact equivalence.
+"""Scan-of-microbatches streaming dispatch: row-exact equivalence.
 
-The fused streaming step (``Job.fused_segment_len``,
-runtime/executor.py ``_stage_fused``/``_dispatch_segment``) collapses
-K per-micro-batch device dispatches into one lax.scan segment call —
-the bounded replay's proven shape (runtime/replay.py), fed from live
-tapes. These tests pin the contract:
+Every batch leaves ``Job`` as an entry of a segment (runtime/executor.py
+``_stage_fused``/``_dispatch_segment``): K micro-batches advance in one
+lax.scan device call — the bounded replay's proven shape
+(runtime/replay.py), fed from live tapes — and ``Job.fused_segment_len``
+says how many (None, 0 and 1: one). These tests pin the contract:
 
-* fused-scan streaming == per-batch streaming, ROW-EXACT, across the
+* a segment of K == a segment of one, ROW-EXACT, across the
   window zoo (length / timeBatch / unique / sort), pattern chains, and
   multiquery stacks, at segment lengths {1, 3, 16} — 10 micro-batches
   per run, so 3 ends on a partial trailing segment (3+3+3+1) and 16
   never fills a whole one (pure partial, padded with empty tapes);
+  a job that never sets the attribute, and None, 0 and 1, are one
+  path with one set of counters and one program;
 * fused streaming == the per-event reference interpreter
   (``baseline/interp.py``) on its supported surface — row contents at
   f32 tolerance, the ``vs_baseline`` honesty check;
@@ -25,6 +27,9 @@ All tier-1, CPU lane; on this lane reverse cummins run in their XLA
 form (the kernel-vs-XLA equivalence runs under the Pallas interpreter
 in tests/test_pallas_ops.py subprocesses).
 """
+
+import logging
+import re
 
 import numpy as np
 import pytest
@@ -92,7 +97,10 @@ CASES = {
 }
 
 
-def _run(cql, n_ids, seg, n=N, batch=BATCH):
+_UNSET = object()  # leave Job.fused_segment_len as Job made it
+
+
+def _run(cql, n_ids, seg=_UNSET, n=N, batch=BATCH):
     schema = _schema()
     plan = compile_plan(
         cql, {"inputStream": schema},
@@ -107,7 +115,8 @@ def _run(cql, n_ids, seg, n=N, batch=BATCH):
         )],
         batch_size=batch, time_mode="processing",
     )
-    job.fused_segment_len = seg
+    if seg is not _UNSET:
+        job.fused_segment_len = seg
     job.run()
     out = {
         sid: sorted(job.results_with_ts(sid)) for sid in job.collected
@@ -117,6 +126,9 @@ def _run(cql, n_ids, seg, n=N, batch=BATCH):
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_fused_matches_per_batch_rowexact(case):
+    """The base is the segment of one batch (the attribute unset); the
+    oracle independent of both is the interpreter
+    (test_fused_matches_baseline_interpreter)."""
     cql, n_ids = CASES[case]
     base, _ = _run(cql, n_ids, None)
     assert base and any(rows for rows in base.values()), case
@@ -128,12 +140,105 @@ def test_fused_matches_per_batch_rowexact(case):
                 case, seg, len(fused[sid]), len(base[sid])
             )
         counters = job.telemetry.snapshot()["counters"]
-        if seg > 1:
-            # the fused path actually ran AND collapsed dispatches
-            assert counters.get("fusion.batches", 0) >= 10
-            assert 0 < counters.get("fusion.dispatches", 0) < (
-                counters["fusion.batches"]
-            )
+        batches = counters.get("fusion.batches", 0)
+        dispatches = counters.get("fusion.dispatches", 0)
+        assert batches >= 10
+        assert 0 < dispatches <= batches
+        # a longer segment collapses dispatches; one batch is one
+        assert (dispatches < batches) if seg > 1 else (
+            dispatches == batches
+        )
+
+
+@pytest.fixture
+def lowered(caplog):
+    """The names of the programs lowered while a test runs, from the
+    line jax logs as it lowers one (before the persistent cache is
+    asked, like telemetry/compile_events.py's event)."""
+    def names():
+        found = (
+            re.match(r"Compiling (\S+) with global shapes", r.getMessage())
+            for r in caplog.records
+        )
+        # jit(seg_scan), as the module is named: jit_seg_scan
+        return [
+            m.group(1).replace("(", "_").rstrip(")") for m in found if m
+        ]
+
+    with caplog.at_level(logging.DEBUG, logger="jax._src.interpreters.pxla"):
+        yield names
+
+
+def test_a_default_job_dispatches_segments_of_one(lowered):
+    """A ``Job`` whose ``fused_segment_len`` nobody set: every batch is
+    one ``jit_seg_scan`` dispatch with its own explicit upload, and the
+    ``fusion.*`` counters the benchmark's dispatch layer reads are
+    booked (``dispatches_per_kbatch`` reads 1,000)."""
+    out, job = _run(*CASES["window_groupby"])
+    assert out["out"]
+    snap = job.telemetry.snapshot()
+    counters = snap["counters"]
+    assert counters["fusion.dispatches"] == counters["fusion.batches"] == 10
+    assert counters["fusion.h2d_uploads"] == 10
+    assert snap["stages"]["dispatch"]["count"] == 10
+    assert job.metrics()["compiles"]["total_lowerings"] > 0
+    assert "jit_seg_scan" in lowered()
+    assert "jit_step_wire" not in lowered()
+
+
+def test_a_job_keeps_the_heap_its_large_temporaries_come_from():
+    """Once a ``Job`` exists, glibc serves a 16 MB block (a column of
+    a delivery) from the heap it keeps, where by default it maps every
+    such block anew and hands it back at ``free``; a second ``Job``
+    changes nothing. Without glibc's ``mallinfo2`` nothing is held."""
+    import ctypes
+
+    class Info(ctypes.Structure):
+        _fields_ = [(n, ctypes.c_size_t) for n in (
+            "arena", "ordblks", "smblks", "hblks", "hblkhd", "usmblks",
+            "fsmblks", "uordblks", "fordblks", "keepcost")]
+
+    try:
+        libc = ctypes.CDLL("libc.so.6")
+        libc.mallinfo2.restype = Info
+    except (OSError, AttributeError):
+        return
+    libc.malloc.restype = ctypes.c_void_p
+    libc.free.argtypes = [ctypes.c_void_p]
+    for _ in range(2):
+        _run(*CASES["window_groupby"])
+        mapped = libc.mallinfo2().hblks
+        for _ in range(3):
+            block = libc.malloc(ctypes.c_size_t(16 << 20))
+            assert libc.mallinfo2().hblks == mapped
+            libc.free(block)
+
+
+@pytest.mark.parametrize("seg", [None, 0, 1])
+def test_none_zero_and_one_are_one_segment_of_one(seg, lowered):
+    """``fused_segment_len`` None, 0 and 1: the rows and counters of
+    the job that never set it, and one lowering of ``jit_seg_scan``."""
+
+    def run(*seg):
+        out, job = _run(*CASES["pattern3_within"], *seg)
+        snap = job.telemetry.snapshot()
+        # what does not depend on the device's pace (h2d_overlapped and
+        # the ticket window's depth do)
+        counted = {k: snap["counters"].get(k) for k in (
+            "fusion.batches", "fusion.dispatches", "fusion.h2d_uploads",
+            "acc.compactions", "acc.compactions_identity",
+        )}
+        counted.update({k: snap["stages"][k]["count"] for k in (
+            "stage.h2d_overlap", "dispatch",
+        )})
+        return out, counted
+
+    rows, counted = run(seg)
+    assert rows["out"] and counted["fusion.dispatches"] == 10
+    assert lowered().count("jit_seg_scan") == 1
+    assert (rows, counted) == run()
+    assert lowered().count("jit_seg_scan") == 2
+    assert "jit_step_wire" not in lowered()
 
 
 def test_fused_multiquery_stack_rowexact():

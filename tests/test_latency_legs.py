@@ -63,7 +63,7 @@ def _run(job):
     job.flush()
 
 
-@pytest.mark.parametrize("fused", [0, 3], ids=["unfused", "fused"])
+@pytest.mark.parametrize("fused", [0, 3], ids=["seg_of_1", "seg_of_3"])
 def test_the_five_legs_sum_to_the_total(fused, monkeypatch):
     closed = []
     record_legs = executor.record_legs
@@ -85,7 +85,10 @@ def test_the_five_legs_sum_to_the_total(fused, monkeypatch):
     # one record a dispatched segment, each closed once, stamps in order
     segs = [r.seg for records, _q, _d in closed for r in records]
     assert segs == list(range(1, len(segs) + 1))
-    assert len(segs) == (4 if fused else 10)
+    # ten batches: ten segments of one, or 3 + 3 + 3 + 1
+    assert [len(r.staged) for records, _q, _d in closed for r in records] == (
+        [3, 3, 3, 1] if fused else [1] * 10
+    )
     for records, requested, delivered in closed:
         assert requested <= delivered
         for r in records:
@@ -205,18 +208,19 @@ def test_a_profiler_trace_holds_the_programs_spans(tmp_path):
 )
 def test_the_step_programs_text_holds_scopes_and_names(cql, scope):
     """The names a trace reduction finds the programs and their parts
-    by: six program names, three scopes (operation metadata only)."""
+    by: five program names, three scopes (operation metadata only). A
+    segment of one batch and a segment of three are one program name."""
     texts = {}
-    for fused, attr in ((0, "jitted_acc"), (3, "jitted_seg")):
+    for fused in (0, 3):
         job = _job(cql, n_events=8_192, batch=1_024, fused=fused)
         rt = next(iter(job._plans.values()))
-        fn = getattr(rt, attr)
+        fn = rt.jitted_seg
 
-        def spy(states, acc, tape, fn=fn, attr=attr):
-            texts[attr] = fn.lower(states, acc, tape).as_text(debug_info=True)
-            return fn(states, acc, tape)
+        def spy(states, acc, seg, fn=fn, key=f"seg_of_{fused or 1}"):
+            texts[key] = fn.lower(states, acc, seg).as_text(debug_info=True)
+            return fn(states, acc, seg)
 
-        setattr(rt, attr, spy)
+        rt.jitted_seg = spy
         job.prewarm_drains([64])
         _run(job)
     rt = next(iter(job._plans.values()))
@@ -227,12 +231,12 @@ def test_the_step_programs_text_holds_scopes_and_names(cql, scope):
     module = {k: re.search(r"module @(\w+)", t).group(1)
               for k, t in texts.items()}
     assert module == {
-        "jitted_acc": "jit_step_wire", "jitted_seg": "jit_seg_scan",
+        "seg_of_1": "jit_seg_scan", "seg_of_3": "jit_seg_scan",
         "init_acc": "jit_init_acc", "flush": "jit_flush",
         "pack": "jit_pack", "ticket": "jit_ticket",
     }
-    for attr in ("jitted_acc", "jitted_seg"):
-        assert scope in texts[attr] and "fst.acc_append" in texts[attr]
+    for key in ("seg_of_1", "seg_of_3"):
+        assert scope in texts[key] and "fst.acc_append" in texts[key]
 
 
 def test_the_drop_counter_rises_by_the_number_in_the_warning(caplog):

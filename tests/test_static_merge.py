@@ -209,7 +209,7 @@ def _merge_counters(job):
             tel.counter_value("window.merge_steps_static"))
 
 
-@pytest.mark.parametrize("fused", [0, 3], ids=["unfused", "fused"])
+@pytest.mark.parametrize("fused", [0, 3], ids=["seg_of_1", "seg_of_3"])
 def test_a_processing_time_window_keeps_the_ranked_merge(fused):
     plan, job = _job(TIME, sharded=False, fused=fused)
     art = plan.artifacts[0]
