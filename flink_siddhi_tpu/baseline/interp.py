@@ -347,7 +347,104 @@ def _window_aggregate(kind: str, vals: list):
     raise SiddhiQLError(f"baseline interpreter: unsupported {kind}()")
 
 
-class _PerKeyLengthWindow:
+def _span_ms(a) -> int:
+    """A window's duration argument in ms."""
+    return a.ms if isinstance(a, ast.TimeLiteral) else int(a.value)
+
+
+class _AggLift:
+    """What the windows that recompute their aggregates per arrival
+    share: the select items and ``having`` with each aggregate call
+    lifted to a slot of the event's env, and the row of one arrival
+    over its window's members."""
+
+    def _lift_query(self, q: ast.Query) -> None:
+        self.aggs = []  # (slot, kind, argument fn or None)
+        self.items = [
+            (it.output_name(), _compile_scalar(self._lift(it.expr)))
+            for it in q.selector.items
+        ]
+        self.having = (
+            _compile_scalar(self._lift(q.selector.having))
+            if q.selector.having is not None else None
+        )
+        self.out = q.output_stream
+
+    def _lift(self, e):
+        """Aggregate calls -> slots of the event's env."""
+        if ast.is_aggregate_call(e):
+            slot = f"@agg{len(self.aggs)}"
+            self.aggs.append((
+                slot, e.name.lower(),
+                _compile_scalar(e.args[0]) if e.args else None,
+            ))
+            return ast.Attr(slot)
+        if isinstance(e, ast.Unary):
+            return ast.Unary(e.op, self._lift(e.operand))
+        if isinstance(e, ast.Binary):
+            return ast.Binary(e.op, self._lift(e.left), self._lift(e.right))
+        return e
+
+    def _emit_row(self, ev, rows, ts, emit) -> None:
+        """``ev``'s row over ``rows``, its window's members, oldest
+        first, where it passes ``having``."""
+        env = dict(ev)
+        for slot, kind, fn in self.aggs:
+            env[slot] = _window_aggregate(
+                kind, [fn(r) if fn is not None else 1 for r in rows])
+        row = []
+        for alias, fn in self.items:
+            env[alias] = fn(env)
+            row.append(env[alias])
+        if self.having is None or self.having(env):
+            emit(self.out, ts, tuple(row))
+
+
+class _TimeWindowGroupBy(_AggLift):
+    """``S[f]#window.time(t) select ... group by k [having ...]``
+    (``docs/time_window.md``): the members in a deque in arrival
+    order, on the stream's clock (the timestamps, as a running
+    maximum). At an arrival stamped T every member stamped
+    ``<= T - t`` leaves first, then the arrival joins, then its row
+    carries its group's aggregates, recomputed from the group's live
+    members, itself among them. ``capacity``: the ring of the engine
+    (``EngineConfig.time_ring_capacity``), where a test wants its
+    answer when it is full: the oldest member is lost to the newest."""
+
+    def __init__(self, q: ast.Query, span_ms: int,
+                 capacity: Optional[int] = None):
+        inp = q.input
+        self.filters = [_compile_scalar(f) for f in inp.filters]
+        self.span, self.cap = span_ms, capacity
+        self.group_keys = [
+            k.split(".", 1)[-1] for k in q.selector.group_by
+        ]
+        self._lift_query(q)
+        self.members: deque = deque()  # (stamp, group, event)
+        self.groups: Dict[Any, deque] = {}
+        self.clock = None
+        self.evicted = 0
+
+    def on_event(self, ev, ts, emit):
+        for f in self.filters:
+            if not f(ev):
+                return
+        self.clock = ts if self.clock is None else max(self.clock, ts)
+        while self.members and (
+            self.members[0][0] <= self.clock - self.span
+        ):
+            self.groups[self.members.popleft()[1]].popleft()
+        key = tuple(ev[k] for k in self.group_keys)
+        if self.cap is not None and len(self.members) == self.cap:
+            self.groups[self.members.popleft()[1]].popleft()
+            self.evicted += 1
+        self.members.append((self.clock, key, ev))
+        rows = self.groups.setdefault(key, deque())
+        rows.append(ev)
+        self._emit_row(ev, rows, ts, emit)
+
+
+class _PerKeyLengthWindow(_AggLift):
     """``partition with (k of S) begin from S[f]#window.length(C) select
     ... [having ...] end``: per key a deque of that key's last C rows
     (siddhi-core runs one LengthWindowProcessor per partition
@@ -367,33 +464,9 @@ class _PerKeyLengthWindow:
         self.key = dict(q.partition_with)[inp.stream_id]
         purge = q.partition_purge
         self.forget_after = sum(purge) if purge else None
-        self.aggs = []  # (slot, kind, argument fn or None)
-        self.items = [
-            (it.output_name(), _compile_scalar(self._lift(it.expr)))
-            for it in q.selector.items
-        ]
-        self.having = (
-            _compile_scalar(self._lift(q.selector.having))
-            if q.selector.having is not None else None
-        )
-        self.out = q.output_stream
+        self._lift_query(q)
         self.rows: Dict[Any, deque] = {}
         self.last: Dict[Any, int] = {}
-
-    def _lift(self, e):
-        """Aggregate calls -> slots of the event's env."""
-        if ast.is_aggregate_call(e):
-            slot = f"@agg{len(self.aggs)}"
-            self.aggs.append((
-                slot, e.name.lower(),
-                _compile_scalar(e.args[0]) if e.args else None,
-            ))
-            return ast.Attr(slot)
-        if isinstance(e, ast.Unary):
-            return ast.Unary(e.op, self._lift(e.operand))
-        if isinstance(e, ast.Binary):
-            return ast.Binary(e.op, self._lift(e.left), self._lift(e.right))
-        return e
 
     def on_event(self, ev, ts, emit):
         for f in self.filters:
@@ -408,16 +481,7 @@ class _PerKeyLengthWindow:
         self.last[key] = ts
         rows = self.rows.setdefault(key, deque(maxlen=self.cap))
         rows.append(ev)
-        env = dict(ev)
-        for slot, kind, fn in self.aggs:
-            env[slot] = _window_aggregate(
-                kind, [fn(r) if fn is not None else 1 for r in rows])
-        row = []
-        for alias, fn in self.items:
-            env[alias] = fn(env)
-            row.append(env[alias])
-        if self.having is None or self.having(env):
-            emit(self.out, ts, tuple(row))
+        self._emit_row(ev, rows, ts, emit)
 
 
 class _HopWindowGroupBy:
@@ -676,7 +740,9 @@ class BaselineEngine:
     """Per-event interpreter for the benchmark CQL surface: stateless
     filters, every-chains with within, strict sequences (quantifiers +
     absence), sliding length-window group-by aggregation, the per-key
-    length window of a partition (with ``@purge``), the hop
+    length window of a partition (with ``@purge``), the processing-time
+    window (``#window.time`` with group-by; ``time_ring_capacity``
+    gives it the engine's ring where a test fills it), the hop
     window with its per-window maximum, the session window (both
     spellings and ``partition with``; ``flush()`` closes what is open)
     and the tumbling-window join
@@ -684,7 +750,8 @@ class BaselineEngine:
     Multi-query plans fan each event through every query, one runtime
     per query (the reference's operator design)."""
 
-    def __init__(self, cql: str, field_names: List[str]):
+    def __init__(self, cql: str, field_names: List[str],
+                 time_ring_capacity: Optional[int] = None):
         plan = parse_plan(cql)
         self.field_names = list(field_names)
         self.handlers = []
@@ -704,10 +771,14 @@ class BaselineEngine:
                     if win.name == "session":
                         self.handlers.append(_SessionWindow(q, win))
                         continue
+                    if win.name == "time":
+                        self.handlers.append(_TimeWindowGroupBy(
+                            q, _span_ms(win.args[0]), time_ring_capacity))
+                        continue
                     if win.name != "length":
                         raise SiddhiQLError(
-                            "baseline interpreter: only length, hop and "
-                            "session windows"
+                            "baseline interpreter: only length, time, "
+                            "hop and session windows"
                         )
                     cap = win.args[0]
                     assert isinstance(cap, ast.Literal)

@@ -24,8 +24,19 @@ class EngineConfig:
     pattern_pool: int = 1024
     # slot NFA: concurrent partial-match slots per query
     pattern_slots: int = 64
-    # max events concurrently inside a #window.time / join time window
+    # max events concurrently inside a time join's side, an
+    # #window.externalTime and a #window.time under min / max /
+    # distinctCount: paths whose step costs by this capacity (an
+    # (events x capacity) matrix: refused over 65,536)
     time_window_capacity: int = 512
+    # slots of the ring a #window.time with count / sum / avg / stddev
+    # keeps its members in (compiler/time_window.py): the events the
+    # span holds at the stream's peak rate, with headroom. It costs
+    # memory alone (12 bytes a slot and 4 an argument more): the step
+    # reads and writes a tape's width of it. A member lost because the
+    # ring was full is a wrong answer, counted (state leaf `overflow`,
+    # counter window.ring_evicted)
+    time_ring_capacity: int = 512
     # max distinct timeBatch windows touched per micro-batch
     time_batch_slots: int = 64
     # #window.hop, the window join, #window.session and the per-key

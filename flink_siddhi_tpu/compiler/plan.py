@@ -268,8 +268,11 @@ class CompiledPlan:
             # meta[0] = per-artifact emission counts, meta[1] = overflow,
             # meta[2] = aligned appends (front-compactions), meta[3] = of
             # them, those whose mask was a prefix and scattered nothing
+            # meta[4], meta[5] = what an artifact's steps counted (its
+            # state leaf ``stepped``, named by its ``step_counters``: a
+            # time window's members that left, and those it lost)
             # (single array so a host drain-check costs ONE fetch)
-            "meta": jnp.zeros((4, a_count), dtype=jnp.int32),
+            "meta": jnp.zeros((6, a_count), dtype=jnp.int32),
             "buf": jnp.zeros((total_rows, self.acc_capacity()),
                              dtype=jnp.int32),
         }
@@ -289,12 +292,15 @@ class CompiledPlan:
         appended to ``acc`` on the device."""
         buf = acc["buf"]
         cap = buf.shape[1]
-        ns, over, compactions, identity = acc["meta"]
+        ns, over, compactions, identity = acc["meta"][:4]
+        stepped = acc["meta"][4:]
         new_n, new_over = [], []
         for ai, (a, (row0, _r)) in enumerate(
             zip(self.artifacts, self.acc_layout())
         ):
             out = outputs[a.name]
+            if getattr(a, "step_counters", None):
+                stepped = stepped.at[:, ai].add(new_states[a.name]["stepped"])
             if a.output_mode == "packed":
                 # artifact already emits the accumulator block layout;
                 # an optional third element counts matches it had to drop
@@ -355,10 +361,12 @@ class CompiledPlan:
         if not self.artifacts:
             return new_states, acc
         return new_states, {
-            "meta": jnp.stack(
-                [jnp.stack(new_n), jnp.stack(new_over),
-                 compactions, identity]
-            ),
+            "meta": jnp.concatenate([
+                jnp.stack(
+                    [jnp.stack(new_n), jnp.stack(new_over),
+                     compactions, identity]),
+                stepped,
+            ]),
             "buf": buf,
         }
 

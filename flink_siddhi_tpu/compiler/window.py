@@ -49,16 +49,14 @@ from .expr import (
     promote,
 )
 from .output import OutputField, OutputSchema
-from .window_merge import (
-    blocked_tiling,
-    ranked_merge,
-    static_merge,
-    tile_fold,
-)
+from .window_merge import blocked_tiling, static_merge, tile_fold
 
 # Bounded slot counts for data-dependent structures (documented limits; a
 # production config system can raise them per plan).
 TIME_WINDOW_CAPACITY = 512  # max events concurrently inside a #window.time
+# the (events x capacity) window matrix of min / max / distinctCount over
+# time and of externalTime: beyond this many slots the plan is refused
+MATRIX_WINDOW_MAX = 1 << 16
 TIME_BATCH_SLOTS = 64  # max distinct timeBatch windows touched per micro-batch
 MIN_GROUP_CAPACITY = 64
 
@@ -275,6 +273,23 @@ def _acc_stats_for(aggs: Sequence[_Agg]) -> Dict[int, set]:
     return need
 
 
+class AlignedBlocks:
+    """An aligned artifact whose rows the accumulator takes a whole
+    tape at a time (the per-key length window, the processing-time
+    window): it says itself how many cycles fit."""
+
+    def safe_cycles(self, tape_capacity: int, state: Dict, cap: int) -> int:
+        """Cycles the accumulator of ``cap`` rows holds without a swap.
+        An aligned block is as wide as the tape whatever ``having``
+        keeps, and ``k`` cycles leave at most ``k`` tapes of rows, so
+        the next block finds room while ``k * tape_capacity <= cap``:
+        the worst case itself, so it takes the whole accumulator;
+        rounded down to a power of two, a swap falls on a segment's
+        end."""
+        k = cap // max(tape_capacity, 1)
+        return 1 << (max(k, 1).bit_length() - 1)
+
+
 # --------------------------------------------------------------------------
 # Sliding windows (length / time / externalTime): (E, C) window-matrix plan
 # --------------------------------------------------------------------------
@@ -367,49 +382,42 @@ class SlidingWindowArtifact:
         return info
 
     def _blocked(self) -> bool:
-        """Sort-free tiled path: per-group running sums over the merged
-        arrival/expiry sequence via one-hot / same-group matmuls (MXU
-        work) instead of multi-key argsorts (the slow op class on TPU —
-        ~5 sorts of 2(C+E) elements dominated this step). The merge is
-        static for a length window and ranked for a processing-time
-        window (``merge_form``, window_merge.py).
+        """Sort-free tiled path of a LENGTH window: per-group running
+        sums over the merged arrival/expiry sequence via one-hot /
+        same-group matmuls (MXU work) instead of multi-key argsorts
+        (the slow op class on TPU — ~5 sorts of 2(C+E) elements
+        dominated this step). The merge is static (``merge_form``,
+        window_merge.py).
 
         Integer sum/avg arguments run EXACTLY through the same matmuls
         by base-2^11 digit decomposition (each digit plane's tile sum
         stays < 2^21, f32-exact; across-tile accumulation is modular
         int32, so the recombined sum wraps exactly like native int32).
-        min/max (length windows only — FIFO expiry makes a window's
+        min/max (FIFO expiry makes a window's
         live members the LAST cnt same-group arrivals, a suffix
         property) ride a sparse-table range query over ONE composite-
-        key argsort. Time windows exclude min/max: the cross-batch
-        straggler defense can early-evict, making the live set
-        non-contiguous. externalTime keeps the matrix path (user
-        timestamps have no ordering guarantee at all)."""
-        if not (
-            self.window_mode == "length"
-            or (self.window_mode == "time" and self.ts_key is None)
-        ):
+        key argsort. A processing-time window with count / sum / avg /
+        stddev is a ``TimeWindowArtifact`` (time_window.py); min / max
+        over time, ``timeLength`` and externalTime (user timestamps
+        have no ordering guarantee at all) keep the matrix path."""
+        if self.window_mode != "length":
             return False
         if self.group_fns and self.code_key is None:
             return False
-        for a in self.aggs:
-            if a.kind in ("min", "max"):
-                if self.window_mode != "length":
-                    return False
-            elif a.kind not in ("count", "sum", "avg", "stddev"):
-                return False
-        return True
+        return all(
+            a.kind in ("count", "sum", "avg", "stddev", "min", "max")
+            for a in self.aggs
+        )
 
     @property
     def merge_form(self) -> Optional[str]:
-        """Which merge of arrivals and expiries the step compiles
-        (window_merge.py): ``'static'`` for a length window on the
-        blocked path, ``'ranked'`` for a processing-time window there,
-        None off it. The query fixes it; the run loop books it per
+        """Which merge of arrivals and expiries the step compiles:
+        ``'static'`` for a length window on the blocked path
+        (window_merge.py), ``'ring'`` for a processing-time window
+        (time_window.py: ranked on the device), None for the matrix
+        path. The query fixes it; the run loop books it per
         dispatched batch (``window.merge_steps``, ``..._static``)."""
-        if not self._blocked():
-            return None
-        return "static" if self.window_mode == "length" else "ranked"
+        return "static" if self._blocked() else None
 
     @jax.named_scope("fst.window_fold")
     # fst:hotpath device=state,tape
@@ -509,9 +517,7 @@ class SlidingWindowArtifact:
         merged with its own expiries (window_merge.py): a length
         window's order is fixed by C and E, so ``static_merge`` cuts
         the tiles from the sequence with slices and no index array
-        exists; a processing-time window's comes from one
-        ``searchsorted`` (``_expiry_ranks``) and ``ranked_merge``
-        scatters and gathers through it. ``tile_fold`` computes the
+        exists. ``tile_fold`` computes the
         per-group running sum of the merged sequence in tiles: a [t,G]
         one-hot matmul gives per-tile group totals whose exclusive scan
         is the across-tile carry (read back with the one gather a
@@ -634,14 +640,9 @@ class SlidingWindowArtifact:
         )  # [N, K]
 
         # the merge of arrivals and expiries: a length window's order is
-        # a fact of C and E, a time window's is ranked from the data
+        # a fact of C and E
         tile, chunk = blocked_tiling()
-        if self.window_mode == "length":
-            merged = static_merge(codes, live, V_n, C, tile, chunk)
-        else:
-            merged = ranked_merge(
-                codes, live, V_n, self._expiry_ranks(ts_n), tile, chunk
-            )
+        merged = static_merge(codes, live, V_n, C, tile, chunk)
         planes = tile_fold(merged, G, int_planes, chunk)
 
         def wcol(name):
@@ -707,22 +708,7 @@ class SlidingWindowArtifact:
                 agg.out_type.device_dtype
             )
 
-        gcp = self.group_code_proj or (None,) * len(self.proj_fns)
-        cols = tuple(
-            jnp.broadcast_to(
-                jnp.asarray(
-                    env[self.code_key] if gi is not None else p(env)
-                ),
-                (E,),
-            )
-            for p, gi in zip(self.proj_fns, gcp)
-        )
-        out_mask = mask
-        if self.having_fn is not None:
-            henv = dict(env)
-            for f, c_ in zip(self.output_schema.fields, cols):
-                henv[f"@out:{f.name}"] = c_
-            out_mask = out_mask & self.having_fn(henv)
+        out_mask, cols = self._project(env, mask, E)
 
         # FIFO ring: last C live entries of [ring ++ arrivals]
         new_ring = {
@@ -744,20 +730,28 @@ class SlidingWindowArtifact:
         }
         return new_state, (out_mask, tape.ts, cols)
 
-    def _expiry_ranks(self, ts_n):
-        """Per concat row of a processing-time window, the arrival it
-        expires ahead of: the first whose time is ``time_ms`` later."""
-        pos = jnp.arange(ts_n.shape[0], dtype=jnp.int32)
-        ts_c = ts_n.astype(jnp.int32)
-        mono = lax.cummax(ts_c)
-        tgt = ts_c + jnp.int32(self.time_ms)
-        tgt = jnp.where(tgt < ts_c, jnp.int32(2 ** 31 - 1), tgt)
-        # 'sort' lowers to ONE sort; the default 'scan' method costs
-        # ~100ms at this width on TPU
-        exp_rank = jnp.searchsorted(
-            mono, tgt, side="left", method="sort"
-        ).astype(jnp.int32)
-        return jnp.maximum(exp_rank, pos + 1)
+    def _project(self, env, mask, E: int):
+        """``(out_mask, cols)`` of a step whose aggregates are in
+        ``env``: the select items over the tape's width (a group-by
+        column that travels as its code, ``group_code_proj``, is the
+        code) and ``having`` over them."""
+        gcp = self.group_code_proj or (None,) * len(self.proj_fns)
+        cols = tuple(
+            jnp.broadcast_to(
+                jnp.asarray(
+                    env[self.code_key] if gi is not None else p(env)
+                ),
+                (E,),
+            )
+            for p, gi in zip(self.proj_fns, gcp)
+        )
+        out_mask = mask
+        if self.having_fn is not None:
+            henv = dict(env)
+            for f, c_ in zip(self.output_schema.fields, cols):
+                henv[f"@out:{f.name}"] = c_
+            out_mask = out_mask & self.having_fn(henv)
+        return out_mask, cols
 
     def _blocked_extrema(
         self, minmax, ring, codes, live, arrivals, cnt, N
@@ -1744,6 +1738,21 @@ def _window_of(inp: ast.StreamInput):
     raise SiddhiQLError(f"unsupported window #window.{w.name}")
 
 
+def _matrix_capacity(config, what: str) -> int:
+    """``time_window_capacity`` for a window on the matrix path, which
+    builds an (events x capacity) matrix a step."""
+    cap = int(config.time_window_capacity)
+    if cap > MATRIX_WINDOW_MAX:
+        raise SiddhiQLError(
+            f"{what} holds its members in an (events x capacity) "
+            f"matrix: time_window_capacity {cap} is over the "
+            f"{MATRIX_WINDOW_MAX} it can take (count / sum / avg / "
+            "stddev over #window.time run in a ring of "
+            "time_ring_capacity slots, of any size)"
+        )
+    return cap
+
+
 def _time_arg(a: ast.Expr) -> int:
     if isinstance(a, ast.TimeLiteral):
         return a.ms
@@ -1946,9 +1955,20 @@ def compile_window_query(
         elif window[0] == "length":
             mode, cap, time_ms, ts_key = "length", window[1], None, None
         elif window[0] == "time":
-            mode, cap, time_ms, ts_key = (
-                "time", config.time_window_capacity, window[1], None,
+            # count / sum / avg / stddev over the stream's clock: the
+            # ring of time_window.py, as large as the deployment says
+            in_ring = all(
+                a.kind in ("count", "sum", "avg", "stddev")
+                for a in collector.aggs
             )
+            mode, time_ms, ts_key = "time", window[1], None
+            cap = (
+                config.time_ring_capacity if in_ring
+                else _matrix_capacity(config, "min() / max() / "
+                                      "distinctCount() over #window.time")
+            )
+            if time_ms < 1:
+                raise SiddhiQLError("#window.time needs a positive span")
         elif window[0] == "timeLength":
             # last-n AND within-t: the window matrix bounds membership
             # to the most recent `count` matching events and the member
@@ -1958,7 +1978,8 @@ def compile_window_query(
         else:  # externalTime
             ts_attr, dur = window[1]
             ts_key, time_columns = time_read(resolver.resolve(ts_attr))
-            mode, cap, time_ms = "time", config.time_window_capacity, dur
+            mode, time_ms = "time", dur
+            cap = _matrix_capacity(config, "#window.externalTime")
         if mode == "cumulative":
             code_key, encoder, encoded = _group_encoding(
                 name, group_resolved, sc, filter_fns,
@@ -2012,7 +2033,10 @@ def compile_window_query(
             for f in inp.filters
             for a in ast.iter_attrs(f)
         )
-        art = SlidingWindowArtifact(
+        cls = SlidingWindowArtifact
+        if window[0] == "time" and in_ring:
+            from .time_window import TimeWindowArtifact as cls
+        art = cls(
             name=name,
             output_schema=out_schema,
             stream_code=sc,
@@ -2541,7 +2565,7 @@ def _perkey_read(table, slots, n, W: int, need: int):
 
 
 @dataclass
-class PerKeyWindowArtifact:
+class PerKeyWindowArtifact(AlignedBlocks):
     """``partition with (k of S) ... #window.length(C)``: EVERY key has
     its own window of its own last C matching events (Siddhi partition
     semantics — NOT a group-by over one shared window; the round-3
@@ -2624,17 +2648,6 @@ class PerKeyWindowArtifact:
             "residency_ms": None,
             "grows_with": "keys",
         }
-
-    def safe_cycles(self, tape_capacity: int, state: Dict, cap: int) -> int:
-        """Cycles the accumulator of ``cap`` rows holds without a swap.
-        An aligned block is as wide as the tape whatever ``having``
-        keeps, and ``k`` cycles leave at most ``k`` tapes of rows, so
-        the next block finds room while ``k * tape_capacity <= cap``:
-        the worst case itself, so it takes the whole accumulator;
-        rounded down to a power of two, a swap falls on a segment's
-        end."""
-        k = cap // max(tape_capacity, 1)
-        return 1 << (max(k, 1).bit_length() - 1)
 
     def drain_counters(self, payload) -> Dict[str, int]:
         """What a drain delivered: the rows past ``having``."""

@@ -6,29 +6,28 @@ The fold sees the concat sequence: the ring's C rows, then the batch's E
 arrivals, N = C + E rows with a group code, a live flag and K value
 planes each. Row ``p`` arrives (+v) and, once it leaves the window,
 expires (-v); the windowed sums of an arrival are the running per-group
-sums of the merged sequence of arrivals and expiries up to it. The merge
-has two forms, and the compiled query picks one:
+sums of the merged sequence of arrivals and expiries up to it.
 
-* ``static_merge`` (``#window.length``): the expiry of row ``p`` comes
-  right before the arrival of row ``p + C``, whatever the data hold, so
-  the order is known when the step is traced. Nothing is ranked,
-  scattered or gathered: tile ``j`` holds pairs ``j*h .. (j+1)*h - 1`` of
-  (expiry of ``i - C``, arrival of ``i``) as two halves of h rows, cut
-  from the concat sequence with static slices, and a constant precedence
-  matrix stands where the interleave would be (an arrival comes after
-  the expiries and the arrivals of its own and of earlier pairs). The
-  ring's C rows have no expiry to pair with and get a dead one; the
-  expiries after the last arrival reach no sum and are left out.
-* ``ranked_merge`` (processing-time windows): a row expires ahead of the
-  first arrival ``time_ms`` later, which a ``searchsorted`` over the
-  data finds; the merge order is computed on the device from those
-  ranks and the rows are gathered through it.
+``static_merge`` (``#window.length``): the expiry of row ``p`` comes
+right before the arrival of row ``p + C``, whatever the data hold, so
+the order is known when the step is traced. Nothing is ranked,
+scattered or gathered: tile ``j`` holds pairs ``j*h .. (j+1)*h - 1`` of
+(expiry of ``i - C``, arrival of ``i``) as two halves of h rows, cut
+from the concat sequence with slices, and a constant precedence
+matrix stands where the interleave would be (an arrival comes after
+the expiries and the arrivals of its own and of earlier pairs). The
+ring's C rows have no expiry to pair with and get a dead one; the
+expiries after the last arrival reach no sum and are left out. (A
+processing-time window's order depends on the data: it does not merge
+ring ++ arrivals at all but reads the ring's oldest stretch,
+time_window.py. The merge by rank that it used until PR 50 lives on as
+the oracle of tests/test_static_merge.py.)
 
-``tile_fold`` is what they share: per tile a one-hot matmul gives the
+``tile_fold``: per tile a one-hot matmul gives the
 groups' totals and a same-group matmul under the precedence matrix the
 running sums inside the tile; a ``cumsum`` across tiles carries the
 totals forward, one gather by (tile, group code) reads the carry for
-each wanted row, and the form's ``back`` puts the rows in concat order.
+each wanted row, and the merge's ``back`` puts the rows in concat order.
 """
 
 from __future__ import annotations
@@ -88,69 +87,6 @@ def static_merge(codes, live, V_n, C: int, tile: int, chunk: int) -> Merged:
         rows=slice(h, 2 * h),
         prec=jnp.concatenate([tril, tril], axis=1),
         back=lambda R: R[:N],
-    )
-
-
-# fst:hotpath device=exp_rank
-def merge_order(exp_rank):
-    """``(m_arr, src)`` of N and 2N entries: where each arrival lands in
-    the merged sequence, and which row each merged position holds
-    (``p`` for the arrival of row ``p``, ``p + N`` for its expiry)."""
-    N = exp_rank.shape[0]
-    pos = jnp.arange(N, dtype=jnp.int32)
-    # merge two sorted streams without sorting or searching: arrival
-    # p has key 2p+1, expiry of p has key 2*exp_rank[p] (ties:
-    # expiry first). Both key sequences are nondecreasing, so merge
-    # ranks are direct counts: an expiry at rank r precedes arrivals
-    # p >= r (histogram + cumsum), and arrivals q < exp_rank[p]
-    # precede expiry p (clip).
-    exp_clip = jnp.clip(exp_rank, 0, N)
-    hist = (
-        jnp.zeros(N + 1, jnp.int32).at[exp_clip].add(1, mode="drop")
-    )
-    cum = jnp.cumsum(hist)
-    m_arr = pos + cum[pos]
-    m_exp = pos + exp_clip
-    src = (
-        jnp.zeros(2 * N, jnp.int32)
-        .at[m_arr]
-        .set(pos)
-        .at[m_exp]
-        .set(pos + N)
-    )
-    return m_arr, src
-
-
-# fst:hotpath device=codes,live,V_n,exp_rank
-def ranked_merge(codes, live, V_n, exp_rank, tile: int, chunk: int) -> Merged:
-    """The merge by rank: row ``p`` expires ahead of arrival
-    ``exp_rank[p]`` (nondecreasing in ``p``)."""
-    N, K = V_n.shape
-    N2 = 2 * N
-    m_arr, src = merge_order(exp_rank)
-    is_arr = src < N
-    idx = jnp.where(is_arr, src, src - N)
-    m_code = codes[idx]
-    m_live = live[idx]
-    sign = jnp.where(is_arr, 1.0, -1.0).astype(jnp.float32)
-    V2 = jnp.where(
-        m_live[:, None], V_n[idx] * sign[:, None], 0.0
-    )  # [2N, K]
-    pad = (-N2) % (tile * chunk)
-    if pad:
-        m_code = jnp.concatenate(
-            [m_code, jnp.zeros(pad, jnp.int32)]
-        )
-        V2 = jnp.concatenate(
-            [V2, jnp.zeros((pad, K), jnp.float32)]
-        )
-    T = (N2 + pad) // tile
-    return Merged(
-        codes_t=m_code.reshape(T, tile),
-        V_t=V2.reshape(T, tile, K),
-        rows=slice(0, tile),
-        prec=jnp.tril(jnp.ones((tile, tile), jnp.float32)),
-        back=lambda R: R[m_arr],
     )
 
 

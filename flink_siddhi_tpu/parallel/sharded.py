@@ -355,7 +355,7 @@ class ShardedJob(Job):
             stages["t_fetch0"] = time.monotonic()
             meta = np.asarray(acc["meta"])  # phase one, every shard's
             counts, overflow = meta[:, 0], meta[:, 1]
-            Job._book_compactions(tel, meta[:, 2], meta[:, 3])
+            Job._book_prefix(tel, rt.plan, meta[:, 2:].sum(axis=0))
             max_n = int(counts.max()) if counts.size else 0
             stages["t_meta"] = time.monotonic()
             data = None

@@ -578,6 +578,13 @@ class Job:
         self._sources = list(sources)
         self._source_wm: List[int] = [MIN_WM] * len(self._sources)
         self._source_done: List[bool] = [False] * len(self._sources)
+        # the polls in flight on the poll thread, and the length of each
+        # source's run of slow polls that brought a batch (``_poll``):
+        # source index -> (source, future), -> polls
+        # fst:ephemeral a poll in flight belongs to a source no checkpoint records (_poll)
+        self._polled_ahead: Dict[int, Tuple[Source, object]] = {}
+        # fst:ephemeral the run of polls a restored job counts anew
+        self._poll_runs: Dict[int, int] = {}
         self._control = list(control_sources)
         self._control_wm: List[int] = [MIN_WM] * len(self._control)
         self._control_done: List[bool] = [False] * len(self._control)
@@ -2237,6 +2244,9 @@ class Job:
         self._pending_t.clear()
         self._source_idle = [False] * len(self._sources)
         self._source_last_t = [None] * len(self._sources)
+        # a poll in flight is of the run before
+        self._polled_ahead.clear()
+        self._poll_runs.clear()
 
     # -- run loop ------------------------------------------------------------
     # fst:thread-root name=run-loop
@@ -2664,14 +2674,20 @@ class Job:
         return pool
 
     @staticmethod
-    def _book_compactions(tel: MetricsRegistry, aligned, identity) -> None:
-        """Rows 2 and 3 of the count prefix (plan.py ``init_acc``), booked
-        at each drain: the accumulator's aligned appends since the last
-        one and, of them, those whose mask was a prefix already, so that
-        the front-compaction moved nothing (compiler/compact.py).
-        The step decides on the device, so the host learns it here."""
-        tel.inc("acc.compactions", int(aligned.sum()))
-        tel.inc("acc.compactions_identity", int(identity.sum()))
+    def _book_prefix(tel: MetricsRegistry, plan, rows) -> None:
+        """Rows 2 onward of the count prefix (plan.py ``init_acc``),
+        summed over shards and booked at each drain. The step decides
+        on the device, so the host learns it here: the accumulator's
+        aligned appends since the last drain and, of them, those whose
+        mask was a prefix already, so that the front-compaction moved
+        nothing (compiler/compact.py); then what each artifact's steps
+        counted, under the names its ``step_counters`` gives (a time
+        window's ``window.time_expired`` and ``window.ring_evicted``)."""
+        tel.inc("acc.compactions", int(rows[0].sum()))
+        tel.inc("acc.compactions_identity", int(rows[1].sum()))
+        for ai, a in enumerate(plan.artifacts):
+            for row, name in zip(rows[2:], getattr(a, "step_counters", ())):
+                tel.inc(name, int(row[ai]))
 
     @staticmethod
     # fst:thread-root name=drain-fetch
@@ -2706,7 +2722,7 @@ class Job:
             stages["t_fetch0"] = time.monotonic()
             meta = np.asarray(acc["meta"])  # phase one: the count prefix
             counts, overflow = meta[0], meta[1]
-            Job._book_compactions(tel, meta[2], meta[3])
+            Job._book_prefix(tel, rt.plan, meta[2:])
             max_n = int(counts.max()) if counts.size else 0
             stages["t_meta"] = time.monotonic()
             data = None
@@ -3330,7 +3346,7 @@ class Job:
                 continue
             # the call into the source is the user's code
             with self.telemetry.span("source_pull"):
-                batch, swm, done = src.poll(self.batch_size)
+                batch, swm, done = self._poll(i, src)
             if batch is not None and len(batch):
                 sid = src.stream_id
                 self._pending.setdefault(sid, []).append(batch)
@@ -3386,6 +3402,73 @@ class Job:
             and self.shed_policy == "drop_oldest"
         ):
             self._shed_pending()
+
+    # a source whose polls cost the run loop time is polled one batch
+    # ahead once this many polls in a row have each brought a batch ...
+    POLL_AHEAD_AFTER = 16
+    # ... and each cost at least this many seconds
+    POLL_AHEAD_MIN_S = 0.004
+
+    def _poll(self, i: int, src: Source):
+        """One poll of source ``i``, what ``src.poll`` returns. A
+        source that hands over a batch at every poll and takes its time
+        over each (a replay's or a backfill's: a file's parse, a
+        generator; ``POLL_AHEAD_*``: one quick poll starts the count
+        anew) is polled ONE batch ahead on the
+        poll thread from then on, so that its work overlaps the run
+        loop's: the run loop takes the poll in flight, waiting for it
+        as it would have waited in ``src.poll``, and starts the next.
+        The first poll that brings no batch ends it (a live source
+        between events is asked when the run loop asks, as ever).
+        Never a source with a ``state_dict``: a checkpoint records its
+        position, and a batch polled ahead would lie past it and in no
+        checkpoint. The source sees one ``poll`` at a time, in order,
+        from one thread or the other."""
+        ahead = self._polled_ahead.pop(i, None)
+        if ahead is not None and ahead[0] is not src:
+            ahead = None  # the source was replaced: its batch goes too
+        if ahead is not None:
+            res = ahead[1].result()
+        else:
+            t0 = time.perf_counter()
+            res = src.poll(self.batch_size)
+            cost = time.perf_counter() - t0
+        batch, _swm, done = res
+        if done or batch is None or not len(batch):
+            self._poll_runs.pop(i, None)
+            return res
+        if ahead is None:
+            polls = (
+                self._poll_runs.get(i, 0) + 1
+                if cost >= self.POLL_AHEAD_MIN_S else 0
+            )
+            self._poll_runs[i] = polls
+            if polls < self.POLL_AHEAD_AFTER or hasattr(src, "state_dict"):
+                return res
+        self._polled_ahead[i] = (
+            src, self._poll_pool.submit(self._poll_ahead, src)
+        )
+        return res
+
+    # fst:thread-root name=poll-ahead
+    def _poll_ahead(self, src: Source):
+        with self.telemetry.span("source_poll_ahead"):
+            return src.poll(self.batch_size)
+
+    @property
+    def _poll_pool(self):
+        """One poll thread per job (``_poll``): polls in the order the
+        run loop asked for them."""
+        import concurrent.futures
+
+        pool = getattr(self, "_poll_pool_", None)
+        if pool is None:
+            pool = concurrent.futures.ThreadPoolExecutor(
+                max_workers=1, thread_name_prefix="fst-poll"
+            )
+            # fst:ephemeral lazily-created poll-thread pool; a fresh process rebuilds it
+            self._poll_pool_ = pool
+        return pool
 
     def _shed_pending(self) -> None:
         """'drop_oldest' enforcement: shed whole pending batches,

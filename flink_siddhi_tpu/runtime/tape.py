@@ -418,8 +418,8 @@ def build_wire_tape(
                 "d0", ts_base, ts_arr,
             ), prov
     if ts_kind != "i32" and total:
-        deltas = np.diff(tape.ts.astype(np.int64), prepend=tape.ts[0])
-        vd = deltas[1:total]  # valid-region deltas (padding repeats)
+        # valid-region deltas (the padding repeats the last stamp: 0)
+        vd = np.diff(tape.ts[:total].astype(np.int64))
         dmax = int(vd.max()) if len(vd) else 0
         dmin = int(vd.min()) if len(vd) else 0
         # d0 needs EVIDENCE of a regular cadence: a small batch is
@@ -444,9 +444,10 @@ def build_wire_tape(
             ts_arr = np.zeros(0, dtype=np.int8)
         elif ts_kind != "i32":
             ts_base = np.asarray([tape.ts[0]], dtype=np.int32)
-            ts_arr = deltas.astype(
-                np.int8 if ts_kind == "d8" else np.int16
+            ts_arr = np.zeros(
+                len(tape.ts), np.int8 if ts_kind == "d8" else np.int16
             )
+            ts_arr[1:total] = vd
     else:
         ts_kind = "i32"
     sticky_kinds["__ts__"] = ts_kind

@@ -51,8 +51,18 @@ class GroupEncoder:
     call per micro-batch) is interned with numpy alone: the live keys
     are kept sorted beside their slots, a batch's distinct values are
     looked up with one ``searchsorted``, and new keys are merged in.
-    Anything else (several columns, object values) goes through a dict,
-    one Python step per selected row."""
+    Several integer columns go the same way as ONE packed key (a
+    field of bits a column, as wide as the values seen need: ``_pack``),
+    with the slots handed out in the order the keys first appear, as
+    the per-row path does, so that both give the same codes; a packed
+    key of at most ``DENSE_BITS`` bits finds its slot in a table kept
+    from batch to batch (``_intern_dense``), one look-up a row. Anything
+    else (object values, integers that 62 bits do not hold side by
+    side) goes through a dict, one Python step per selected row."""
+
+    # packed keys up to this many bits take their slots from a dense
+    # table (``_intern_dense``: 4 MB at the most)
+    DENSE_BITS = 20
 
     def __init__(self, retain_ticks: Optional[int] = None,
                  mark_new: bool = False) -> None:
@@ -63,6 +73,12 @@ class GroupEncoder:
         self._slot_key: Optional[np.ndarray] = None
         self._skeys: Optional[np.ndarray] = None
         self._sslots: Optional[np.ndarray] = None
+        # several columns in array mode: (lowest value, bits) a column
+        self._packing: Optional[List[Tuple[int, int]]] = None
+        # packed keys of at most DENSE_BITS bits: key -> slot (-1: none),
+        # and the sorted keys it was laid out from
+        self._dense: Optional[np.ndarray] = None
+        self._dense_of: Optional[np.ndarray] = None
         # dict mode: key tuple -> slot, slot -> key tuple (None: free)
         self._codes: Dict[Tuple, int] = {}
         self._values: List[Optional[Tuple]] = []
@@ -109,30 +125,41 @@ class GroupEncoder:
                 # never behind an earlier batch's (a late last row)
                 tick = max(int(tick_col[pos[-1]]) // tick_ms,
                            self._tick or 0)
+        vals, rows = None, None
         if len(cols) == 1 and cols[0].dtype != object:
             # vectorized single-column path: unique once (distinct group
             # count, not row count), nothing per row or per key in Python
-            sel_vals = cols[0][select]
-            if not len(sel_vals):
+            vals = cols[0][select]
+        elif len(cols) > 1:
+            # several integer columns: the same path over one packed key
+            # a row, the slots in the per-row path's order
+            vals = rows = self._pack(cols, select)
+        if vals is not None:
+            if not len(vals):
                 return out
-            lo, span = _dense_span(sel_vals)
-            if span is not None:
+            # few bits a key (``_pack``): one table look-up a row
+            narrow = rows is not None and rows.dtype == np.int32
+            lo, span = (None, None) if narrow else _dense_span(vals)
+            if narrow:
+                slots, codes = self._intern_dense(rows)
+                out[select] = codes
+            elif span is not None:
                 # ids that lie close together (a stream's newest keys, a
                 # small key set): mark and look up, no sort and no search
-                rel = sel_vals - lo
+                rel = vals - lo
                 seen = np.zeros(span, dtype=np.bool_)
                 seen[rel] = True
                 present = np.flatnonzero(seen)
                 slots, codes = self._intern_unique(
-                    (present + lo).astype(sel_vals.dtype)
+                    (present + lo).astype(vals.dtype), rows
                 )
                 lut = np.zeros(span, dtype=np.int32)
                 lut[present] = codes
                 out[select] = lut[rel]
             else:
-                uniq = np.unique(sel_vals)
-                slots, codes = self._intern_unique(uniq)
-                out[select] = codes[np.searchsorted(uniq, sel_vals)]
+                uniq = np.unique(vals)
+                slots, codes = self._intern_unique(uniq, rows)
+                out[select] = codes[np.searchsorted(uniq, vals)]
         else:
             idx = np.nonzero(select)[0]
             slots = np.empty(len(idx), dtype=np.int32)
@@ -179,11 +206,93 @@ class GroupEncoder:
         out[np.concatenate(at)] = codes
         return out
 
-    def _take_slots(self, n_new: int) -> np.ndarray:
-        """``n_new`` slots: freed ones first, then fresh ones."""
+    def _pack(self, cols, select) -> Optional[np.ndarray]:
+        """The selected rows of several integer columns as one key a
+        row, or None where they are not integers or do not fit: a
+        column's field is its value less the lowest seen, in as many
+        bits as the span seen needs. A batch outside the fields widens
+        them, and the live keys are packed anew (through the dict).
+        Keys of at most ``DENSE_BITS`` bits come as int32 (and go
+        through ``_intern_dense``), wider ones as int64."""
+        if any(c.dtype.kind not in "iu" for c in cols):
+            return None
+        sel = [c[select] for c in cols]
+        if not len(sel[0]):
+            return sel[0].astype(np.int64)
+        pk = self._packing
+        seen = [(int(c.min()), int(c.max())) for c in sel]
+        if pk is None or any(
+            lo < plo or hi >= plo + (1 << bits)
+            for (lo, hi), (plo, bits) in zip(seen, pk)
+        ):
+            values = [v for v in self._value_list() if v is not None]
+            for j, (lo, hi) in enumerate(seen):
+                known = [v[j] for v in values]
+                lo, hi = min([lo] + known), max([hi] + known)
+                seen[j] = (lo, max(1, (hi - lo).bit_length()))
+            if sum(bits for _, bits in seen) > 62:
+                return None
+            if self._skeys is not None:
+                self._to_dict()
+            # fst:ephemeral derived from the keys: a restored table (dict mode, its keys whole) packs anew at its first call
+            pk = self._packing = seen
+        # a field's values less its lowest fit the key's type, whatever
+        # the column's own (an int8 column may span 255)
+        kt = (np.int32 if sum(bits for _, bits in pk) <= self.DENSE_BITS
+              else np.int64)
+        key, shift = None, 0
+        for c, (lo, bits) in zip(sel, pk):
+            if c.dtype.itemsize < 4 or kt is np.int64:
+                c = c.astype(kt, copy=False)
+            field = (c - lo).astype(kt, copy=False) << shift
+            key = field if key is None else key | field
+            shift += bits
+        return key
+
+    def _unpack(self, key: int) -> Tuple:
+        if self._packing is None:
+            return (key,)
+        out = []
+        for lo, bits in self._packing:
+            out.append(lo + (key & ((1 << bits) - 1)))
+            key >>= bits
+        return tuple(out)
+
+    def _intern_dense(self, keys: np.ndarray):
+        """(slot a row, code a row) of packed int32 ``keys``: a look-up
+        in a table of ``1 << bits`` slots, -1 where the key has none.
+        The keys it lacks (few, once a job's first batches have run) go
+        through ``_intern_unique`` in the order they first appear; under
+        ``mark_new`` all their rows carry ``~slot``."""
+        if self._skeys is None:
+            self._to_arrays(np.int64)
+        if self._dense_of is not self._skeys:
+            # the sorted keys are replaced, never written in place
+            bits = sum(b for _, b in self._packing)
+            # fst:ephemeral laid out from the sorted keys, which a restored table makes anew
+            self._dense = np.full(1 << bits, -1, dtype=np.int32)
+            self._dense[self._skeys] = self._sslots
+            # fst:ephemeral the sorted keys the table above was laid out from
+            self._dense_of = self._skeys
+        slots = codes = self._dense[keys]
+        if slots.min() < 0:
+            miss = np.flatnonzero(slots < 0)
+            new = keys[miss].astype(np.int64)
+            uniq = np.unique(new)
+            got, _ = self._intern_unique(uniq, new)
+            slots[miss] = got[np.searchsorted(uniq, new)]
+            if self.mark_new:
+                codes = slots.copy()
+                codes[miss] = ~slots[miss]
+        return slots, codes
+
+    def _take_slots(self, n_new: int, rowwise: bool = False) -> np.ndarray:
+        """``n_new`` slots: freed ones first, then fresh ones;
+        ``rowwise`` in the order that many calls for one slot give."""
         take = min(n_new, len(self._free))
         fresh = np.arange(self._n, self._n + n_new - take, dtype=np.int32)
-        slots = np.concatenate([self._free[len(self._free) - take:], fresh])
+        freed = self._free[len(self._free) - take:]
+        slots = np.concatenate([freed[::-1] if rowwise else freed, fresh])
         self._free = self._free[: len(self._free) - take]
         self._n += n_new - take
         self.stats["interned"] += n_new
@@ -196,10 +305,13 @@ class GroupEncoder:
             ])
         return slots
 
-    def _intern_unique(self, uniq: np.ndarray):
+    def _intern_unique(self, uniq: np.ndarray,
+                       rows: Optional[np.ndarray] = None):
         """(slots, codes) of the sorted distinct values ``uniq`` (array
         mode): the codes are the slots, under ``mark_new`` ``~slot`` for
-        the values interned here."""
+        the values interned here. With ``rows``, the batch's values in
+        row order, new values take their slots in the order they first
+        appear there (the per-row path's), else in sorted order."""
         if self._skeys is None:
             self._to_arrays(uniq.dtype)
         sk, ss = self._skeys, self._sslots
@@ -211,7 +323,11 @@ class GroupEncoder:
         new = ~hit
         n_new = int(new.sum())
         if n_new:
-            got = self._take_slots(n_new)
+            got = self._take_slots(n_new, rowwise=rows is not None)
+            if rows is not None and n_new > 1:
+                at = np.flatnonzero(np.isin(rows, uniq[new]))
+                _, first = np.unique(rows[at], return_index=True)
+                got = got[np.argsort(np.argsort(at[first]))]
             if self._n > len(self._slot_key):
                 grown = np.zeros(
                     max(self._n, 2 * len(self._slot_key), 64), dtype=sk.dtype
@@ -268,13 +384,13 @@ class GroupEncoder:
 
     def value(self, code: int) -> Tuple:
         if self._skeys is not None:
-            return (self._slot_key[code].item(),)
+            return self._unpack(self._slot_key[code].item())
         return self._values[code]
 
     # -- the two representations ----------------------------------------------
     def _to_arrays(self, dtype) -> None:
         """Dict mode (or a fresh table) -> array mode."""
-        live = [(v[0], s) for s, v in enumerate(self._values)
+        live = [(self._key_of(v), s) for s, v in enumerate(self._values)
                 if v is not None]
         self._slot_key = np.zeros(max(self._n, 64), dtype=dtype)
         keys = np.asarray([k for k, _ in live], dtype=dtype)
@@ -283,6 +399,17 @@ class GroupEncoder:
         order = np.argsort(keys, kind="stable")
         self._skeys, self._sslots = keys[order], slots[order]
         self._codes, self._values = {}, []
+
+    def _key_of(self, value: Tuple) -> int:
+        """A key tuple as array mode holds it: its one value, or its
+        columns packed (``_pack``'s fields)."""
+        if self._packing is None:
+            return value[0]
+        key, shift = 0, 0
+        for v, (lo, bits) in zip(value, self._packing):
+            key |= (v - lo) << shift
+            shift += bits
+        return key
 
     def _to_dict(self) -> None:
         self._values = self._value_list()
@@ -297,7 +424,7 @@ class GroupEncoder:
             return list(self._values)
         values: List[Optional[Tuple]] = [None] * self._n
         for s, k in zip(self._sslots.tolist(), self._skeys.tolist()):
-            values[s] = (k,)
+            values[s] = self._unpack(k)
         return values
 
     # -- checkpoint support -------------------------------------------------

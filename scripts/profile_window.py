@@ -26,20 +26,16 @@ that scatter, which the helper's ``[hole]`` lines and
   aggregates back from concat order to tape order;
 * ``fold_batch_rows[prefix|hole]``: the helper for that way back
   (``batch_rows``: one gather of rows where the mask is no prefix);
-* the fold's merge of arrivals and expiries in its two forms
+* the fold's static merge of arrivals and expiries
   (compiler/window_merge.py; PR 34), on the cell's concat sequence
   (ring 1,000 + tape 524,288 rows, two value planes, 1,024 group slots):
-  ``merge_order_scatter`` (the ranked form's order: a histogram
-  scatter-add, a cumsum, a gather, two scatters over 2N; the static form
-  builds no order, ``merge_order_static`` is a line that says so),
-  ``merge_read_gather`` (codes, live flags and value rows gathered
-  through that order) against ``merge_read_halves`` (the static form's
-  tiles: slices and concatenations) and ``merge_read_interleave`` (the
-  layout not taken: the same rows interleaved in memory with
-  ``stack(...).reshape``), ``merge_back_gather`` (``R[m_arr]``) against
+  ``merge_read_halves`` (the tiles: slices and concatenations) against
+  ``merge_read_interleave`` (the layout not taken: the same rows
+  interleaved in memory with ``stack(...).reshape``),
   ``merge_back_slice`` (``R[:N]``; for the interleave the odd rows of
-  ``R[C : C + 2E]``), and ``tile_fold[ranked|static]``, the tiled sums
-  both share, on each form's tiles;
+  ``R[C : C + 2E]``), and ``tile_fold[static]``, the tiled sums (the
+  merge by rank that processing-time windows took, and its lines here,
+  went in PR 50);
 * ``step_acc[prefix|hole]``: the whole step of ``window1k``'s query on a
   tape whose mask is a prefix (the cell's) and on the same tape with one
   row invalid, which takes the sorts and the row gathers; under
@@ -47,6 +43,16 @@ that scatter, which the helper's ``[hole]`` lines and
   trace of five more calls, the device time of its costliest XLA
   operations, each beside the scope the compiled program names for it
   (``fst.window_fold``, ``fst.acc_append``, ``cond/branch_...``).
+
+* ``time [log2 of the ring ...]`` (PR 50): the processing-time window's
+  step (compiler/time_window.py) at ``linear_road_lav5m``'s shapes: a
+  tape of 2^20 lanes with 544,000 events (five ticks of 108,800, 99% of
+  them reports of 12,800 segments), for each ring size (default 20 and
+  25) a span that fills 96% of it (a ring of 2^25 holds the cell's five
+  minutes), stepped until the window is full and expiring, then
+  ``step_acc[ring 2^k]`` over ten more batches and the device time of
+  its XLA operations by scope; ``time compile`` compiles that step for
+  a described v5e at 2^25, no chip.
 
 Usage (the chip tool): python scripts/profile_window.py
 (a number cuts the tape for a rehearsal on the CPU; ``--step-only``
@@ -201,8 +207,7 @@ def pieces(rng):
 def merge_pieces(rng):
     try:
         from flink_siddhi_tpu.compiler.window_merge import (
-            blocked_tiling, merge_order, ranked_merge, static_merge,
-            tile_fold)
+            blocked_tiling, static_merge, tile_fold)
     except ImportError:
         print("merge: this checkout has no compiler/window_merge.py")
         return
@@ -212,16 +217,6 @@ def merge_pieces(rng):
     live = jnp.asarray(np.arange(N) < N - 37)
     V_n = jnp.asarray(
         np.stack([rng.random(N) * 100.0, np.ones(N)], 1).astype(np.float32))
-    exp_rank = jnp.arange(N, dtype=jnp.int32) + C  # a length window's
-    m_arr, src = jax.jit(merge_order)(exp_rank)
-
-    @jax.jit
-    def read_gather(codes, live, V_n, src):
-        is_arr = src < N
-        idx = jnp.where(is_arr, src, src - N)
-        sign = jnp.where(is_arr, 1.0, -1.0).astype(jnp.float32)
-        return codes[idx], jnp.where(
-            live[idx][:, None], V_n[idx] * sign[:, None], 0.0)
 
     def weave(x, neg):
         # head ++ interleave(expiry of p, arrival of p + C) ++ tail
@@ -239,10 +234,6 @@ def merge_pieces(rng):
         return static_merge(codes, live, V_n, C, tile, chunk)[:2]
 
     @jax.jit
-    def back_gather(R, m_arr):
-        return R[m_arr]
-
-    @jax.jit
     def back_weave(R):
         return jnp.concatenate([R[:C], R[C + 1:C + 2 * E:2]])
 
@@ -251,40 +242,18 @@ def merge_pieces(rng):
         return R[:N]
 
     @jax.jit
-    def fold_ranked(codes, live, V_n, exp_rank):
-        return tile_fold(ranked_merge(codes, live, V_n, exp_rank, tile,
-                                      chunk), G, (), chunk)
-
-    @jax.jit
     def fold_static(codes, live, V_n):
         return tile_fold(static_merge(codes, live, V_n, C, tile, chunk),
                          G, (), chunk)
 
-    timed("merge_order_scatter", jax.jit(merge_order), exp_rank)
     print("merge_order_static 0.000 ms (no device work: the order is the "
           "tiles' constant precedence matrix)")
-    timed("merge_read_gather", read_gather, codes, live, V_n, src)
     timed("merge_read_halves", read_halves, codes, live, V_n)
     timed("merge_read_interleave", read_interleave, codes, live, V_n)
-    got, want = read_interleave(codes, live, V_n), read_gather(
-        codes, live, V_n, src)
-    dead = np.asarray(want[1] == 0).all(axis=1)  # a dead row's code is free
-    print("  interleave == gather:", bool(
-        (np.asarray(got[0]) == np.asarray(want[0]))[~dead].all()
-        and (np.asarray(got[1]) == np.asarray(want[1])).all()))
     R2 = jnp.asarray(rng.random((2 * N, K)).astype(np.float32))
-    timed("merge_back_gather", back_gather, R2, m_arr)
     timed("merge_back_slice[interleave]", back_weave, R2)
     timed("merge_back_slice[halves]", back_slice, R2)
-    timed("tile_fold[ranked]", fold_ranked, codes, live, V_n, exp_rank)
     timed("tile_fold[static]", fold_static, codes, live, V_n)
-    a, b = fold_ranked(codes, live, V_n, exp_rank), fold_static(
-        codes, live, V_n)
-    keep = np.asarray(live)
-    print("  static == ranked: counts", bool(
-        (np.asarray(a[1])[keep] == np.asarray(b[1])[keep]).all()),
-        "sums within", float(np.abs(
-            np.asarray(a[0])[keep] - np.asarray(b[0])[keep]).max()))
 
 
 def plan_and_tape(rng):
@@ -311,6 +280,17 @@ def plan_and_tape(rng):
     return plan, tape
 
 
+def list_moves(compiled):
+    """The gathers, scatters and sorts a compiled step holds, by scope."""
+    seen = collections.Counter(
+        (kind, shape.split("{")[0], scope.split("step_acc)/")[-1])
+        for shape, kind, scope in re.findall(
+            r'= (\S+) (gather|scatter|sort)\([^\n]*?op_name="([^"]*)"',
+            compiled.as_text()))
+    for (kind, shape, scope), n in sorted(seen.items()):
+        print(f"  {n:3d} x {kind:8s}{shape:24s} {scope}")
+
+
 def compile_only(rng):
     """The step compiled for a described v5e (no chip, nothing runs):
     which gathers, scatters and sorts the program holds, by scope."""
@@ -332,13 +312,7 @@ def compile_only(rng):
         *shaped).compile()
     print(f"compiled in {time.perf_counter() - t0:.1f} s")
     print(compiled.memory_analysis())
-    seen = collections.Counter(
-        (kind, shape.split("{")[0], scope.split("step_acc)/")[-1])
-        for shape, kind, scope in re.findall(
-            r'= (\S+) (gather|scatter|sort)\([^\n]*?op_name="([^"]*)"',
-            compiled.as_text()))
-    for (kind, shape, scope), n in sorted(seen.items()):
-        print(f"  {n:3d} x {kind:8s}{shape:24s} {scope}")
+    list_moves(compiled)
 
 
 def whole_step(rng):
@@ -360,6 +334,124 @@ def whole_step(rng):
         meta = np.asarray(acc["meta"])
         print(f"  meta rows after {REPEATS + 1} appends: {meta.tolist()}")
         device_ops(f"step_acc[{name}]", step_acc, states, acc, t, scope_of)
+
+
+TIME_CQL = ("from PositionReport[type == 0]#window.time({span}) "
+            "select vid, xway, dir, seg, avg(spd) as lav, count() as n "
+            "group by xway, dir, seg insert into SegSpeed")
+TIME_BATCH, TIME_TICK, TIME_TAPE = 544_000, 108_800, 1 << 20
+
+
+def time_plan(ring, span_ms, tape=TIME_TAPE):
+    from flink_siddhi_tpu.compiler.config import EngineConfig
+    from flink_siddhi_tpu.compiler.plan import compile_plan
+    from flink_siddhi_tpu.schema.stream_schema import StreamSchema
+    from flink_siddhi_tpu.schema.types import AttributeType
+
+    schema = StreamSchema([
+        ("type", AttributeType.INT), ("vid", AttributeType.LONG),
+        ("spd", AttributeType.INT), ("xway", AttributeType.INT),
+        ("dir", AttributeType.INT), ("seg", AttributeType.INT),
+    ])
+    plan = compile_plan(
+        TIME_CQL.format(span=span_ms), {"PositionReport": schema},
+        plan_id="lav", config=EngineConfig(
+            time_ring_capacity=ring, acc_budget_bytes=128 << 20))
+    return plan, schema
+
+
+def time_tape(plan, schema, rng, j, batch=TIME_BATCH, tape=TIME_TAPE):
+    """Batch ``j`` of the stream: five ticks of a second."""
+    from flink_siddhi_tpu.runtime.tape import build_tape
+    from flink_siddhi_tpu.schema.batch import EventBatch
+
+    ts = 1_000 * ((j * batch + np.arange(batch, dtype=np.int64))
+                  // (batch // 5))
+    cols = {
+        "type": (rng.random(batch) < 0.01).astype(np.int32) * 2,
+        "vid": rng.integers(0, 1 << 40, batch),
+        "spd": rng.integers(10, 76, batch).astype(np.int32),
+        "xway": rng.integers(0, 64, batch).astype(np.int32),
+        "dir": rng.integers(0, 2, batch).astype(np.int32),
+        "seg": rng.integers(0, 100, batch).astype(np.int32),
+    }
+    ev = EventBatch("PositionReport", schema, cols, ts)
+    out, _ = build_tape(plan.spec, [ev], 0, capacity=tape, want_prov=False)
+    return out
+
+
+def time_compile_only():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    one = SingleDeviceSharding(topo.devices[0])
+    plan, schema = time_plan(1 << 25, 300_000)
+    tape = time_tape(plan, schema, np.random.default_rng(50), 0)
+    states = plan.grow_state(jax.eval_shape(plan.init_state))
+    args = (states, jax.eval_shape(plan.init_acc), tape)
+    shaped = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype, sharding=one),
+        args)
+    t0 = time.perf_counter()
+    compiled = jax.jit(plan.step_acc, donate_argnums=(0, 1)).lower(
+        *shaped).compile()
+    print(f"compiled in {time.perf_counter() - t0:.1f} s")
+    print(compiled.memory_analysis())
+    list_moves(compiled)
+
+
+def time_steps(logs, batch=TIME_BATCH, tape=TIME_TAPE):
+    rng = np.random.default_rng(50)
+    for k in logs:
+        ring = 1 << k
+        # ticks the ring holds at 96%: a ring of 2^25 holds 300
+        ticks = max(1, int(ring * 0.963 / (batch // 5 * 0.99)))
+        plan, schema = time_plan(ring, ticks * 1_000, tape)
+        step_acc = jax.jit(plan.step_acc, donate_argnums=(0, 1))
+        init_acc = jax.jit(plan.init_acc)
+        tapes = [time_tape(plan, schema, rng, 0, batch, tape)]
+        states = plan.grow_state(plan.init_state())
+        t0 = time.perf_counter()
+        hlo = step_acc.lower(states, init_acc(), tapes[0]).compile().as_text()
+        print(f"ring 2^{k}: span {ticks} s, compiled in "
+              f"{time.perf_counter() - t0:.1f} s", flush=True)
+        scope_of = dict(re.findall(
+            r'%([\w.\-]+) = [^\n]*?op_name="([^"]*)"', hlo))
+        acc, j = init_acc(), 0
+        fill = -(-ticks // 5) + 2
+        for j in range(fill):
+            if j:
+                tapes = [time_tape(plan, schema, rng, j, batch, tape)]
+            states, acc = step_acc(plan.grow_state(states), init_acc(),
+                                   jax.device_put(tapes[0]))
+        win = states[plan.artifacts[0].name]
+        print(f"  filled after {fill} batches: live {int(win['count'])}, "
+              f"overflow {int(win['overflow'])}, stepped "
+              f"{np.asarray(win['stepped']).tolist()}", flush=True)
+        todo = [jax.device_put(time_tape(plan, schema, rng, fill + i, batch,
+                                         tape)) for i in range(10)]
+        jax.block_until_ready(todo)
+        t0 = time.perf_counter()
+        for t in todo:
+            states, acc = step_acc(states, init_acc(), t)
+        jax.block_until_ready(acc)
+        print(f"step_acc[ring 2^{k}] "
+              f"{(time.perf_counter() - t0) / len(todo) * 1e3:.3f} ms "
+              "(with an accumulator zeroed a step)", flush=True)
+        win = states[plan.artifacts[0].name]
+        print(f"  after: live {int(win['count'])}, overflow "
+              f"{int(win['overflow'])}, stepped "
+              f"{np.asarray(win['stepped']).tolist()}", flush=True)
+        # the same tape again and again: its stamps lie behind the clock,
+        # so its reports join at the clock and nothing more expires
+        device_ops(f"step_acc[ring 2^{k}]", step_acc, states, init_acc(),
+                   jax.device_put(time_tape(plan, schema, rng, fill + 10,
+                                            batch, tape)), scope_of, steps=2)
+        del states, acc, todo
 
 
 def device_ops(name, fn, states, acc, tape, scope_of, steps=5, top=24):
@@ -402,6 +494,15 @@ def main():
     global E
     if sys.argv[1:] == ["compile"]:
         return compile_only(np.random.default_rng(32))
+    if sys.argv[1:2] == ["time"]:
+        if sys.argv[2:] == ["compile"]:
+            return time_compile_only()
+        if sys.argv[2:3] == ["tiny"]:  # the CPU's rehearsal
+            time_steps([10, 13], batch=500, tape=1 << 10)
+        else:
+            time_steps([int(a) for a in sys.argv[2:]] or [20, 25])
+        print(json.dumps({"device": str(jax.devices()[0]), "root": ROOT}))
+        return None
     args = [a for a in sys.argv[1:] if a != "--step-only"]
     if args:
         E = int(args[0])

@@ -46,10 +46,16 @@ TINY_LR = {"expressways": 1, "reports_per_s_per_xway": 16,
            "trip_reports_min": 3, "trip_reports_max": 9,
            "accident_every_s": 120, "accident_reports": 6, "batch": 80,
            "engine_config": {"hop_group_slots": 1_024}}
+# (linear_road_lav5m.replay: the same stream; 300 ticks x 16 reports are
+# 4,800 members, in a ring of 8,192; the window is full from batch 60 on)
+TINY_LAV = {**TINY_LR, "engine_config": {"time_ring_capacity": 8_192,
+                                         "acc_budget_bytes": 1 << 20}}
 POOL_BATCHES = {"nexmark_q5.replay": 20, "nexmark_q8.replay": 20,
-                "nexmark_q11.replay": 20, "linear_road_rows4.replay": 48}
+                "nexmark_q11.replay": 20, "linear_road_rows4.replay": 48,
+                "linear_road_lav5m.replay": 48}
 WARM_BATCHES, RUN_BATCHES = 8, 40
-LONGER_RUNS = {"linear_road_rows4.replay": 120}
+LONGER_RUNS = {"linear_road_rows4.replay": 120,
+               "linear_road_lav5m.replay": 120}
 
 # Names a sound tiny run does not book, or books only when the timing
 # falls so, and why. Their cases stay: they assert that the package still
@@ -88,6 +94,7 @@ class _TinyRun:
 
     def __init__(self, workload):
         over = dict(TINY_Q5 if workload.startswith("nexmark")
+                    else TINY_LAV if workload.startswith("linear_road_lav")
                     else TINY_LR if workload.startswith("linear_road")
                     else TINY)
         self.cell, self.cfg, params = bmcell.load_cell(workload, over)

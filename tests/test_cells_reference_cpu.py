@@ -79,6 +79,18 @@ TINY = {
         "fused_segment_len": 4,
         "engine_config": {"hop_group_slots": 1_024},
     },
+    # the same stream through the five-minute window: 300 ticks x 16
+    # reports are 4,800 members at most, in a ring of 8,192; the 150
+    # batches are 750 s, so the window fills and slides for 450 s
+    "linear_road_lav5m": {
+        "expressways": 1, "reports_per_s_per_xway": 16,
+        "trip_reports_min": 3, "trip_reports_max": 9,
+        "accident_every_s": 120, "accident_reports": 6,
+        "batch": 80, "pool": 3_840, "whole_batches": 150,
+        "fused_segment_len": 4,
+        "engine_config": {"time_ring_capacity": 8_192,
+                          "acc_budget_bytes": 1 << 20},
+    },
 }
 
 

@@ -213,9 +213,9 @@ def test_two_appends_across_the_fits_boundary(prefix):
             want_over += n
     assert want_over  # the second append straddled the boundary
     meta = np.asarray(acc["meta"])
-    assert meta.shape == (4, 1)
+    assert meta.shape == (6, 1)  # rows 4 and 5: an artifact's own counts
     assert meta[:2, 0].tolist() == [want_n, want_over]
-    assert meta[2:, 0].tolist() == [2, 2 if prefix else 0]
+    assert meta[2:, 0].tolist() == [2, 2 if prefix else 0, 0, 0]
     # beyond the count the buffer holds what the block's tail left there
     # (zeros): the whole of it is compared
     np.testing.assert_array_equal(np.asarray(acc["buf"]), want_buf)

@@ -243,3 +243,35 @@ def test_small_batches_never_pick_d0():
         capacity=64,
     )
     assert sticky["__ts__"] != "d0"
+
+
+@pytest.mark.parametrize("gaps, kind", [
+    ((0, 1, 100), "d8"), ((0, 3, 30_000), "d16"), ((0, 5, 40_000), "i32"),
+    ((0,), "d8"),
+], ids=["d8", "d16", "i32", "one_tick"])
+@pytest.mark.parametrize("n", [1, 2, 700], ids=["n1", "n2", "n700"])
+def test_delta_stamps_expand_to_the_tapes(gaps, kind, n):
+    """Irregular stamps travel as deltas in the narrowest type that
+    holds the widest gap; a tape shorter than its capacity repeats its
+    last stamp in the padding."""
+    import jax
+
+    plan = compile_plan(
+        "from S[id == 2] select name, price insert into out",
+        {"S": SCHEMA}, config=PUSH_LAZY,
+    )
+    rng = np.random.default_rng(n)
+    b = make_batches(n=n, batch=n)[0]
+    ts = 1000 + np.cumsum(rng.choice(gaps, n)).astype(np.int64)
+    if n > 2:
+        ts[1:3] = ts[0] + np.asarray([gaps[-1], gaps[-1]])
+        ts = np.sort(ts)
+    b.columns["timestamp"][:], b.timestamps[:] = ts, ts
+    sticky = {}
+    wire, _ = build_wire_tape(plan.spec, [b], 1000, sticky, capacity=1024)
+    if n > 2:
+        assert wire.ts_kind == kind and sticky["__ts__"] == kind
+    got = np.asarray(jax.jit(lambda w: w.expand().ts)(wire))
+    want = np.full(1024, ts[-1] - 1000)
+    want[:n] = ts - 1000
+    np.testing.assert_array_equal(got, want)

@@ -1,24 +1,19 @@
-"""The blocked sliding-window fold's merge of arrivals and expiries in its
-two forms (compiler/window_merge.py): a length window's order is a fact
-of C and E, so its tiles are cut from the concat sequence with slices;
-a processing-time window's is ranked on the device. Here: the static
-tiles hold, row for row, what the ranked merge gathers when it is handed
-a length window's ranks; both forms' windowed sums equal a numpy count
-over the window; a processing-time window still gives its rows; and the
-run loop books which form each dispatched batch compiled. Nothing here
-is a rate or a time."""
+"""The blocked sliding-window fold's merge of arrivals and expiries
+(compiler/window_merge.py): a length window's order is a fact of C and
+E, so its tiles are cut from the concat sequence with slices. Here: the
+static tiles hold, row for row, each arrival beside the expiry that
+leaves ahead of it; their windowed sums equal a numpy count over the
+window; a processing-time window, in its ring since PR 50
+(compiler/time_window.py), gives its rows; and the run loop books which
+form each dispatched batch compiled. Nothing here is a rate or a
+time."""
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from flink_siddhi_tpu.compiler.plan import compile_plan
-from flink_siddhi_tpu.compiler.window_merge import (
-    merge_order,
-    ranked_merge,
-    static_merge,
-    tile_fold,
-)
+from flink_siddhi_tpu.compiler.window_merge import static_merge, tile_fold
 from flink_siddhi_tpu.parallel import ShardedJob, make_cep_mesh
 from flink_siddhi_tpu.runtime.executor import Job
 from flink_siddhi_tpu.runtime.sources import BatchSource
@@ -27,6 +22,8 @@ from flink_siddhi_tpu.schema.stream_schema import StreamSchema
 from flink_siddhi_tpu.schema.types import AttributeType
 
 G = 8
+
+
 # (C, E, tile, chunk): a window shorter than, as long as and longer than
 # the batch; the cell's proportions; a tile that does not divide 2N
 SHAPES = [
@@ -59,10 +56,6 @@ def _concat_sequence(C, E, seed, contiguous):
     return codes, live, V
 
 
-def _length_ranks(C, E):
-    return jnp.arange(C + E, dtype=jnp.int32) + C
-
-
 def _numpy_window_sums(codes, live, V, C):
     """Per concat row j: the sums over the live rows of j's group among
     rows j - C + 1 .. j."""
@@ -76,50 +69,32 @@ def _numpy_window_sums(codes, live, V, C):
 
 
 @pytest.mark.parametrize("C, E, tile, chunk", SHAPES, ids=IDS)
-def test_the_static_tiles_hold_the_rows_the_ranked_merge_gathers(
+def test_the_static_tiles_hold_each_arrival_beside_its_expiry(
     C, E, tile, chunk
 ):
-    """Element for element: the ranked merge of a length window's ranks
-    is [the ring's C arrivals] ++ interleave(expiry of p, arrival of
-    p + C) ++ [the expiries nothing follows]; the static tiles hold the
-    same codes and signed values as halves, a dead expiry beside each of
-    the ring's rows, and leave the tail out."""
+    """Element for element: pair i of the tiles is concat row i as an
+    arrival (+v) and, ahead of it, row i - C as an expiry (-v): none
+    beside the ring's own C rows, and the expiries nothing follows are
+    left out. The halves of a tile are its expiries and its arrivals."""
     codes, live, V = _concat_sequence(C, E, seed=C * 31 + E, contiguous=False)
     N, h = C + E, tile // 2
-    m_arr, src = (np.asarray(x) for x in merge_order(_length_ranks(C, E)))
-    # the order itself, as the issue derives it
-    pos = np.arange(N)
-    np.testing.assert_array_equal(
-        m_arr, np.where(pos < C, pos, 2 * pos - C + 1))
-    want_src = np.concatenate([
-        pos[:C],
-        np.stack([pos[:E] + N, pos[C:]], axis=1).reshape(-1),
-        pos[E:] + N,
-    ])
-    np.testing.assert_array_equal(src, want_src)
-
-    ranked = ranked_merge(
-        jnp.asarray(codes), jnp.asarray(live), jnp.asarray(V),
-        _length_ranks(C, E), tile, chunk)
     static = static_merge(
         jnp.asarray(codes), jnp.asarray(live), jnp.asarray(V), C, tile,
         chunk)
-    r_code = np.asarray(ranked.codes_t).reshape(-1)
-    r_val = np.asarray(ranked.V_t).reshape(-1, 2)
     s_code = np.asarray(static.codes_t)
     s_val = np.asarray(static.V_t)
     exp_code, arr_code = (s_code[:, :h].reshape(-1), s_code[:, h:].reshape(-1))
     exp_val, arr_val = (s_val[:, :h].reshape(-1, 2), s_val[:, h:].reshape(-1, 2))
-    # arrivals: pair i's arrival is concat row i, at m_arr[i] of the merge
-    np.testing.assert_array_equal(arr_val[:N], r_val[m_arr])
+    held = np.where(live[:, None], V, 0.0)
+    # arrivals: pair i's arrival is concat row i
+    np.testing.assert_array_equal(arr_val[:N], held)
     # (a dead row's code reaches no sum and is not compared)
-    np.testing.assert_array_equal(arr_code[:N][live], r_code[m_arr][live])
+    np.testing.assert_array_equal(arr_code[:N][live], codes[live])
     # expiries: pair i's is row i - C's, right ahead of arrival i
     np.testing.assert_array_equal(exp_val[:C], 0.0)
-    np.testing.assert_array_equal(exp_val[C:N], r_val[m_arr[C:] - 1])
-    np.testing.assert_array_equal(exp_val[C:N], -arr_val[:E])
+    np.testing.assert_array_equal(exp_val[C:N], -held[:E])
     np.testing.assert_array_equal(
-        exp_code[C:N][live[:E]], r_code[m_arr[C:] - 1][live[:E]])
+        exp_code[C:N][live[:E]], codes[:E][live[:E]])
     # padding pairs are dead, and the arrival half is what is read back
     assert not exp_val[N:].any() and not arr_val[N:].any()
     assert static.rows == slice(h, tile)
@@ -131,19 +106,16 @@ def test_the_static_tiles_hold_the_rows_the_ranked_merge_gathers(
 
 @pytest.mark.parametrize("contiguous", [True, False], ids=["fifo", "any_dead"])
 @pytest.mark.parametrize("C, E, tile, chunk", SHAPES, ids=IDS)
-def test_both_merges_give_the_windows_count_and_sums(
+def test_the_static_merge_gives_the_windows_count_and_sums(
     C, E, tile, chunk, contiguous
 ):
     codes, live, V = _concat_sequence(C, E, seed=C + E, contiguous=contiguous)
-    args = (jnp.asarray(codes), jnp.asarray(live), jnp.asarray(V))
-    got_static = tile_fold(
-        static_merge(*args, C, tile, chunk), G, (), chunk)
-    got_ranked = tile_fold(
-        ranked_merge(*args, _length_ranks(C, E), tile, chunk), G, (), chunk)
-    want = _numpy_window_sums(codes, live, V, C)
+    got = tile_fold(
+        static_merge(jnp.asarray(codes), jnp.asarray(live), jnp.asarray(V),
+                     C, tile, chunk), G, (), chunk)
     # whole numbers under 2^24: float32 sums are exact in any order
-    np.testing.assert_array_equal(np.stack(got_static, axis=1), want)
-    np.testing.assert_array_equal(np.stack(got_ranked, axis=1), want)
+    np.testing.assert_array_equal(
+        np.stack(got, axis=1), _numpy_window_sums(codes, live, V, C))
 
 
 def test_int_planes_carry_in_int32_through_the_static_merge():
@@ -210,10 +182,10 @@ def _merge_counters(job):
 
 
 @pytest.mark.parametrize("fused", [0, 3], ids=["seg_of_1", "seg_of_3"])
-def test_a_processing_time_window_keeps_the_ranked_merge(fused):
+def test_a_processing_time_window_gives_its_rows_from_its_ring(fused):
     plan, job = _job(TIME, sharded=False, fused=fused)
     art = plan.artifacts[0]
-    assert art._blocked() and art.merge_form == "ranked"
+    assert art._blocked() and art.merge_form == "ring"
     job.run()
     got = [(ts, tuple(row)) for ts, row in job.results_with_ts("out")]
     ids = np.concatenate([b.columns["id"] for b in _batches()])
