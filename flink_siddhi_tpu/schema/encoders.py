@@ -48,9 +48,22 @@ class GroupEncoder:
     """Intern table over tuples of column values -> dense slots.
 
     A single numeric column (the common group-by, and the hot path: one
-    call per micro-batch) is interned with numpy alone: the live keys
-    are kept sorted beside their slots, a batch's distinct values are
-    looked up with one ``searchsorted``, and new keys are merged in.
+    call per micro-batch) is interned with numpy alone. The live keys
+    are kept sorted beside their slots in TWO tables: the main one
+    (``_skeys`` / ``_sslots``, as large as the key set: 3.6M vehicles)
+    and a short side table (``_nkeys`` / ``_nslots``) of the keys
+    interned since the main one was last written. A batch's distinct
+    values are searched in the main table, a block of them at a time
+    in the stretch of keys between the block's ends (``_search``), and
+    the misses alone in the side table (``_find``); a new key costs an
+    insert into the side table, never a copy of the main one. The side
+    table is merged in once it holds a thirty-second as many keys as
+    the main one (``_add``: every eighth batch at 3.6M keys and 13,000
+    new ones a batch), and the sweep takes the dead keys out of both.
+    A batch's distinct values come from a mark in a table where they
+    lie close together (``_dense_span``) and else from ONE stable sort
+    of the batch (``_sorted_runs``), which also gives each row's key
+    and the way back to stream order (``_intern_sparse``).
     Several integer columns go the same way as ONE packed key (a
     field of bits a column, as wide as the values seen need: ``_pack``),
     with the slots handed out in the order the keys first appear, as
@@ -63,16 +76,21 @@ class GroupEncoder:
     # packed keys up to this many bits take their slots from a dense
     # table (``_intern_dense``: 4 MB at the most)
     DENSE_BITS = 20
+    # sorted values are looked up this many at a time (``_search``)
+    SEARCH_BLOCK = 4096
 
     def __init__(self, retain_ticks: Optional[int] = None,
                  mark_new: bool = False) -> None:
         self.retain_ticks = retain_ticks
         self.mark_new = mark_new
         self._n = 0  # slots ever handed out (the table's high-water mark)
-        # array mode: slot -> key, and the live keys sorted
+        # array mode: slot -> key, and the live keys sorted: the main
+        # table, and the keys interned since it was last written
         self._slot_key: Optional[np.ndarray] = None
         self._skeys: Optional[np.ndarray] = None
         self._sslots: Optional[np.ndarray] = None
+        self._nkeys: Optional[np.ndarray] = None
+        self._nslots: Optional[np.ndarray] = None
         # several columns in array mode: (lowest value, bits) a column
         self._packing: Optional[List[Tuple[int, int]]] = None
         # packed keys of at most DENSE_BITS bits: key -> slot (-1: none),
@@ -127,8 +145,8 @@ class GroupEncoder:
                            self._tick or 0)
         vals, rows = None, None
         if len(cols) == 1 and cols[0].dtype != object:
-            # vectorized single-column path: unique once (distinct group
-            # count, not row count), nothing per row or per key in Python
+            # vectorized single-column path: the distinct values once,
+            # nothing per row or per key in Python
             vals = cols[0][select]
         elif len(cols) > 1:
             # several integer columns: the same path over one packed key
@@ -157,9 +175,8 @@ class GroupEncoder:
                 lut[present] = codes
                 out[select] = lut[rel]
             else:
-                uniq = np.unique(vals)
-                slots, codes = self._intern_unique(uniq, rows)
-                out[select] = codes[np.searchsorted(uniq, vals)]
+                slots, codes = self._intern_sparse(vals, rows is not None)
+                out[select] = codes
         else:
             idx = np.nonzero(select)[0]
             slots = np.empty(len(idx), dtype=np.int32)
@@ -272,6 +289,7 @@ class GroupEncoder:
             # fst:ephemeral laid out from the sorted keys, which a restored table makes anew
             self._dense = np.full(1 << bits, -1, dtype=np.int32)
             self._dense[self._skeys] = self._sslots
+            self._dense[self._nkeys] = self._nslots
             # fst:ephemeral the sorted keys the table above was laid out from
             self._dense_of = self._skeys
         slots = codes = self._dense[keys]
@@ -280,6 +298,7 @@ class GroupEncoder:
             new = keys[miss].astype(np.int64)
             uniq = np.unique(new)
             got, _ = self._intern_unique(uniq, new)
+            self._dense[uniq] = got
             slots[miss] = got[np.searchsorted(uniq, new)]
             if self.mark_new:
                 codes = slots.copy()
@@ -305,42 +324,148 @@ class GroupEncoder:
             ])
         return slots
 
+    def _intern_sparse(self, vals: np.ndarray, rowwise: bool):
+        """(slot a distinct key, code a row) of ``vals`` that lie far
+        apart: one sort of the batch gives its distinct keys, each
+        row's key and the way back to stream order. ``rowwise`` (a
+        packed key): new keys take their slots in the order they first
+        appear, which a stable sort puts at the head of a key's run."""
+        order, ranked, head = self._sorted_runs(vals)
+        first = None
+        if rowwise:
+            first = order if head is None else order[head]
+        slots, codes = self._intern_unique(
+            ranked if head is None else ranked[head], first=first)
+        if head is not None:
+            codes = np.repeat(codes, np.diff(head, append=len(ranked)))
+        rows = np.empty(len(vals), dtype=np.int32)
+        rows[order] = codes
+        return slots, rows
+
+    @staticmethod
+    def _sorted_runs(vals: np.ndarray):
+        """(order, ``vals`` in that order, head): the stable sort of
+        ``vals`` and where each run of equal values starts in it, None
+        where no value repeats (each row is its own run). Integers
+        whose span leaves room for the row number ride with it in one
+        word: a plain sort of the words is the stable sort of the
+        values, at one price whatever order the rows came in (a merge
+        sort of 538,560 values costs from one to three times that as
+        the stream's keys interleave)."""
+        n, bits = len(vals), (len(vals) - 1).bit_length()
+        lo, hi = vals.min().item(), vals.max().item()
+        if (vals.dtype.kind in "iu" and hi < 1 << 63
+                and (hi - lo).bit_length() + bits < 63):
+            word = vals.astype(np.int64)
+            word -= lo
+            word <<= bits
+            word |= np.arange(n)
+            word.sort()
+            order = word & ((1 << bits) - 1)
+            word >>= bits
+            word += lo
+            ranked = word.astype(vals.dtype, copy=False)
+        else:
+            order = np.argsort(vals, kind="stable")
+            ranked = vals[order]
+        step = ranked[1:] != ranked[:-1]
+        if step.all():
+            return order, ranked, None
+        return order, ranked, np.concatenate(
+            [[0], np.flatnonzero(step) + 1])
+
     def _intern_unique(self, uniq: np.ndarray,
-                       rows: Optional[np.ndarray] = None):
+                       rows: Optional[np.ndarray] = None,
+                       first: Optional[np.ndarray] = None):
         """(slots, codes) of the sorted distinct values ``uniq`` (array
         mode): the codes are the slots, under ``mark_new`` ``~slot`` for
-        the values interned here. With ``rows``, the batch's values in
-        row order, new values take their slots in the order they first
-        appear there (the per-row path's), else in sorted order."""
+        the values interned here. New values take their slots in sorted
+        order, or in the order they first appear (the per-row path's):
+        ``first`` has the row where each of ``uniq`` first appears, or
+        ``rows`` the batch's values in row order."""
         if self._skeys is None:
             self._to_arrays(uniq.dtype)
-        sk, ss = self._skeys, self._sslots
-        pos = np.searchsorted(sk, uniq)
-        hit = pos < len(sk)
-        hit[hit] = sk[pos[hit]] == uniq[hit]
-        slots = np.empty(len(uniq), dtype=np.int32)
-        slots[hit] = ss[pos[hit]]
-        new = ~hit
-        n_new = int(new.sum())
-        if n_new:
-            got = self._take_slots(n_new, rowwise=rows is not None)
-            if rows is not None and n_new > 1:
-                at = np.flatnonzero(np.isin(rows, uniq[new]))
-                _, first = np.unique(rows[at], return_index=True)
-                got = got[np.argsort(np.argsort(at[first]))]
-            if self._n > len(self._slot_key):
-                grown = np.zeros(
-                    max(self._n, 2 * len(self._slot_key), 64), dtype=sk.dtype
-                )
-                grown[: len(self._slot_key)] = self._slot_key
-                self._slot_key = grown
-            self._slot_key[got] = uniq[new]
-            slots[new] = got
-            self._skeys = np.insert(sk, pos[new], uniq[new])
-            self._sslots = np.insert(ss, pos[new], got)
-            if self.mark_new:
-                return slots, np.where(new, ~slots, slots)
-        return slots, slots
+        slots = self._find(uniq)
+        new = np.flatnonzero(slots < 0)
+        if not len(new):
+            return slots, slots
+        keys = uniq[new]
+        got = self._take_slots(
+            len(new), rowwise=rows is not None or first is not None)
+        if len(new) > 1:
+            if rows is not None:
+                at = np.flatnonzero(np.isin(rows, keys))
+                _, i = np.unique(rows[at], return_index=True)
+                got = got[np.argsort(np.argsort(at[i]))]
+            elif first is not None:
+                got = got[np.argsort(np.argsort(first[new]))]
+        self._add(keys, got)
+        slots[new] = got
+        if not self.mark_new:
+            return slots, slots
+        codes = slots.copy()
+        codes[new] = ~got
+        return slots, codes
+
+    def _find(self, uniq: np.ndarray) -> np.ndarray:
+        """Slot of each of the sorted values ``uniq``, -1 where it has
+        none: the main table, and for its misses the side table."""
+        slots = self._search(self._skeys, self._sslots, uniq)
+        if len(self._nkeys):
+            miss = np.flatnonzero(slots < 0)
+            slots[miss] = self._search(
+                self._nkeys, self._nslots, uniq[miss])
+        return slots
+
+    @staticmethod
+    def _search(keys: np.ndarray, slots: np.ndarray, uniq: np.ndarray):
+        """``slots`` where the sorted ``keys`` hold each of the sorted
+        ``uniq``, -1 where they do not."""
+        if not len(keys):
+            return np.full(len(uniq), -1, dtype=np.int32)
+        # a block of values at a time, in the stretch of keys between
+        # the block's first value and the next block's: a search of a
+        # few thousand keys that stay in the cache, not of millions
+        block = GroupEncoder.SEARCH_BLOCK
+        ends = np.searchsorted(keys, uniq[::block]).tolist() + [len(keys)]
+        pos = np.empty(len(uniq), dtype=np.intp)
+        for i, (lo, hi) in enumerate(zip(ends, ends[1:])):
+            part = pos[i * block: (i + 1) * block]
+            part[:] = np.searchsorted(
+                keys[lo:hi], uniq[i * block: (i + 1) * block])
+            part += lo
+        np.minimum(pos, len(keys) - 1, out=pos)
+        return np.where(keys[pos] == uniq, slots[pos], np.int32(-1))
+
+    def _add(self, keys: np.ndarray, slots: np.ndarray) -> None:
+        """New sorted ``keys`` at ``slots``, into the side table; once
+        that holds a thirty-second as many keys as the main one it is
+        merged in (both sorted arrays written anew, once), so a key is
+        copied a bounded number of times whatever the table's size."""
+        if self._n > len(self._slot_key):
+            grown = np.zeros(
+                max(self._n, 2 * len(self._slot_key), 64),
+                dtype=self._slot_key.dtype,
+            )
+            grown[: len(self._slot_key)] = self._slot_key
+            self._slot_key = grown
+        self._slot_key[slots] = keys
+        nk, ns = self._merged(self._nkeys, self._nslots, keys, slots)
+        if len(nk) > len(self._skeys) >> 5:
+            self._skeys, self._sslots = self._merged(
+                self._skeys, self._sslots, nk, ns)
+            nk, ns = nk[:0], ns[:0]
+        self._nkeys, self._nslots = nk, ns
+
+    @staticmethod
+    def _merged(keys: np.ndarray, slots: np.ndarray,
+                more_keys: np.ndarray, more_slots: np.ndarray):
+        """The sorted table ``keys`` / ``slots`` with the sorted
+        ``more_keys`` / ``more_slots`` in their places."""
+        if not len(keys):
+            return more_keys, more_slots
+        at = np.searchsorted(keys, more_keys)
+        return np.insert(keys, at, more_keys), np.insert(slots, at, more_slots)
 
     def _intern_key(self, key: Tuple) -> int:
         if self._skeys is not None:
@@ -362,25 +487,22 @@ class GroupEncoder:
         if self._tick is None or self._tick == self._swept:
             return
         self._swept = self._tick
-        stamp = self._last_tick[: self._n]
-        dead = stamp + self.retain_ticks <= self._tick
+        dead = self._last_tick[: self._n] <= self._tick - self.retain_ticks
+        dead[self._free] = False  # a free slot keeps its last stamp
+        freed = np.flatnonzero(dead).astype(np.int32)  # in slot order
+        if not len(freed):
+            return
         if self._skeys is not None:
-            live = self._sslots
-            keep = ~dead[live]
-            freed = np.sort(live[~keep])  # slot order, as the dict's
-            self._skeys, self._sslots = self._skeys[keep], live[keep]
+            keep = ~dead[self._sslots]
+            self._skeys, self._sslots = self._skeys[keep], self._sslots[keep]
+            keep = ~dead[self._nslots]
+            self._nkeys, self._nslots = self._nkeys[keep], self._nslots[keep]
         else:
-            freed = np.asarray(
-                [s for s in np.flatnonzero(dead)
-                 if self._values[s] is not None],
-                dtype=np.int32,
-            )
             for s in freed:
                 del self._codes[self._values[s]]
                 self._values[s] = None
-        if len(freed):
-            self._free = np.concatenate([self._free, freed.astype(np.int32)])
-            self.stats["expired"] += len(freed)
+        self._free = np.concatenate([self._free, freed])
+        self.stats["expired"] += len(freed)
 
     def value(self, code: int) -> Tuple:
         if self._skeys is not None:
@@ -398,6 +520,7 @@ class GroupEncoder:
         self._slot_key[slots] = keys
         order = np.argsort(keys, kind="stable")
         self._skeys, self._sslots = keys[order], slots[order]
+        self._nkeys, self._nslots = keys[:0], slots[:0]
         self._codes, self._values = {}, []
 
     def _key_of(self, value: Tuple) -> int:
@@ -417,14 +540,17 @@ class GroupEncoder:
             v: s for s, v in enumerate(self._values) if v is not None
         }
         self._slot_key = self._skeys = self._sslots = None
+        self._nkeys = self._nslots = None
 
     def _value_list(self) -> List[Optional[Tuple]]:
         """slot -> key tuple, None for a free slot."""
         if self._skeys is None:
             return list(self._values)
-        values: List[Optional[Tuple]] = [None] * self._n
-        for s, k in zip(self._sslots.tolist(), self._skeys.tolist()):
-            values[s] = self._unpack(k)
+        values: List[Optional[Tuple]] = [
+            self._unpack(k) for k in self._slot_key[: self._n].tolist()
+        ]
+        for s in self._free.tolist():
+            values[s] = None
         return values
 
     # -- checkpoint support -------------------------------------------------
@@ -444,6 +570,7 @@ class GroupEncoder:
 
     def load_state_dict(self, d: dict) -> None:
         self._slot_key = self._skeys = self._sslots = None
+        self._nkeys = self._nslots = None
         self._values = [
             None if v is None else tuple(v) for v in d["values"]
         ]
